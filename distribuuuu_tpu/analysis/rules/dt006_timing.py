@@ -2,8 +2,8 @@
 
 JAX dispatch is asynchronous: ``t0 = time.perf_counter(); step(...); dt =
 time.perf_counter() - t0`` measures *enqueue* latency, not execution — on
-one transport in this repo's history it over-reported throughput ~100x
-(docs/BENCH_NOTES.md). The honest pattern closes the timed span with a real
+one transport in this repo's history it over-reported throughput ~100x.
+The honest pattern closes the timed span with a real
 fetch: ``jax.device_get`` on a value that depends on the work (or
 ``block_until_ready``) before the second timestamp — see
 ``bench._timed_cadence_loop`` for the canonical gated loop.

@@ -87,7 +87,9 @@ class ElasticResumeError(RuntimeError):
 
 
 def get_checkpoint_dir(out_dir: str) -> str:
-    return pathio.join(out_dir, _DIR_NAME)
+    # Orbax refuses a relative directory ("Checkpoint path should be
+    # absolute"), and every shipped config has one (OUT_DIR: ./resnet50)
+    return pathio.join(pathio.absolute(out_dir), _DIR_NAME)
 
 
 def get_checkpoint_path(out_dir: str, epoch: int) -> str:
@@ -664,6 +666,7 @@ def _restore(path: str, template: dict):
     and raises (callers that can fall back catch it — see restore_latest)."""
     ckptr = _checkpointer()
     tic = time.time()
+    path = pathio.absolute(path)  # Orbax reads nothing through a relative path
     with restore_guard(path):
         restored = resilience.retry(
             ckptr.restore,
@@ -684,7 +687,7 @@ def _payload_names(path: str) -> set[str]:
     """Top-level payload key names of a checkpoint, across orbax metadata
     generations: the modern CheckpointMetadata wrapper, the bare tree
     object, or (oldest) a plain dict tree."""
-    meta = _checkpointer().metadata(path)
+    meta = _checkpointer().metadata(pathio.absolute(path))
     if hasattr(meta, "item_metadata"):
         return set(meta.item_metadata.tree.keys())
     if hasattr(meta, "tree"):

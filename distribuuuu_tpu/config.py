@@ -80,7 +80,7 @@ _C.MODEL.MAE_DECODER_DIM = 512
 # BatchNorm boundary dtype: what dtype BN *emits* between conv stages.
 # Statistics are always computed in float32 and running stats/affine params
 # always stored float32; "bfloat16" halves inter-stage HBM traffic (the
-# MLPerf-era TPU recipe: +20% measured on resnet50/v5e, docs/BENCH_NOTES.md),
+# MLPerf-era TPU recipe: +20% measured on resnet50/v5e),
 # "float32" keeps full-precision boundaries. "auto" (default) tracks
 # MODEL.DTYPE — bf16 training gets bf16 boundaries, f32 exact-parity runs
 # stay f32 end-to-end.
@@ -123,6 +123,8 @@ _C.TRAIN.ACCUM_STEPS = 1
 _C.TRAIN.COMPILE_CACHE = True
 # Cache directory ("" = the repo-local default next to the package checkout;
 # set to a shared path, e.g. a persistent volume, for fleet-wide reuse).
+# JAX_COMPILATION_CACHE_DIR in the environment beats both: the launcher
+# places the cache, the program does not move it.
 _C.TRAIN.COMPILE_CACHE_DIR = ""
 # jax.profiler trace of a few steady-state steps (epoch 0) → OUT_DIR/profile.
 # The reference has no profiler (SURVEY §5); this is the idiomatic upgrade.

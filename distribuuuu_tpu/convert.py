@@ -777,10 +777,12 @@ def synthetic_variables(
     """Deterministic seeded-init variables for ``arch`` as host numpy.
 
     The weights side of a *synthetic* golden fixture: `(arch, init_seed,
-    im_size, num_classes)` fully determines the model (threefry init is
-    platform-stable), so a CPU-sized fixture checked into the repo can be
-    re-derived — and served — anywhere without torch, network, or large
-    checked-in weight files.
+    im_size, num_classes)` determines the model for one JAX version and one
+    ``jax_threefry_partitionable`` setting (the same seed draws other bits
+    when either changes — the checked-in fixtures are regenerated with
+    `scripts/validate_pretrained.py --synthetic-init` when the installed JAX
+    does), so a CPU-sized fixture checked into the repo can be re-derived —
+    and served — without torch, network, or large checked-in weight files.
     """
     import jax
     import jax.numpy as jnp

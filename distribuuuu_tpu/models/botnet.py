@@ -24,7 +24,6 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from distribuuuu_tpu.models.layers import batch_norm, classifier_head, conv, maybe_remat
@@ -142,19 +141,20 @@ class MHSA(nn.Module):
             # The 2026-07-31 on-chip A/B measured the Pallas kernel LOSING
             # to XLA's fused attention at BoTNet shapes — abs-fused 0.77x in
             # the soak, botnet50 end-to-end 1545 vs 1834 img/s
-            # (docs/BENCH_NOTES.md round-5 session #2); that verdict is
+            # (round-5 session #2); that verdict is
             # seeded in the perfdb registry as flip=False for the L~196
             # class. `switch_attention` resolves DTPU_FUSED_ATTN env > the
             # registry's per-shape-class verdict > off, so a large-L soak
             # win flips only its own shapes while L~196 stays on XLA.
             from distribuuuu_tpu.ops.attention import switch_attention
 
-            fuse = jax.default_backend() == "tpu" and switch_attention(
-                h * w, dqk, dv
-            )
-        # off-TPU a forced fuse runs the Pallas interpreter (tests; a user
-        # setting fuse=True on CPU gets slow-but-correct instead of a crash)
-        interpret = jax.default_backend() != "tpu"
+            fuse = switch_attention(h * w, dqk, dv)
+        # the interpreter is something a process asks for (ops/interpret.py:
+        # the CPU test suite does), never a consequence of the platform — a
+        # fused route on a machine without its chip fails in the compiler
+        from distribuuuu_tpu.ops.interpret import pallas_interpret
+
+        interpret = pallas_interpret()
         if fuse and not self.rel_pos_emb:
             # abs-bias fast path: hand the kernel the [L, dqk] table and let
             # it form q·embᵀ in VMEM — skips writing+reading the [B,N,L,L]

@@ -37,7 +37,7 @@ linear_uniform = nn.initializers.variance_scaling(1.0 / 3.0, "fan_in", "uniform"
 # recipe (statistics are STILL computed in float32 — flax upcasts half dtypes
 # inside `_compute_stats` — and running stats/affine params stay float32;
 # only the normalized activations are emitted in bf16). bf16 boundaries are
-# +20% measured on resnet50/v5e (docs/BENCH_NOTES.md). The trainer derives
+# +20% measured on resnet50/v5e. The trainer derives
 # it from cfg.MODEL.BN_DTYPE ("auto" tracks MODEL.DTYPE) for the duration of
 # train_model()/test_model() and restores the previous value on return, so
 # direct build_model() calls outside a run keep the float32 default.

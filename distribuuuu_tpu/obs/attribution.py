@@ -1,6 +1,6 @@
 """Step-time attribution: the per-op trace folded into roofline buckets.
 
-BENCH_NOTES round 5 measured 45% of the resnet50 step outside the matmuls —
+Round 5 measured 45% of the resnet50 step outside the matmuls —
 a number produced once, by hand, from a profile export. This module makes it
 standing telemetry: the profiler's per-op table (`obs/traceparse.py`) is
 folded into five buckets —

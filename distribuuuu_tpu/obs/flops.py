@@ -77,14 +77,7 @@ def peak_flops_per_device(device=None, override_tflops: float = 0.0) -> float | 
 
 
 def _normalize_cost(costs: Any) -> dict[str, float] | None:
-    """XLA cost_analysis output → ``{"flops", "bytes_accessed"}`` floats.
-
-    Older jax returns one dict per device program; take the first (SPMD
-    programs are identical per device)."""
-    if isinstance(costs, (list, tuple)):
-        if not costs:
-            return None
-        costs = costs[0]
+    """XLA cost_analysis output → ``{"flops", "bytes_accessed"}`` floats."""
     if not isinstance(costs, dict):
         return None
     flops = costs.get("flops")

@@ -5,8 +5,7 @@ is ``softmax(q·kᵀ + pos_bias)·v`` over L = H·W ≈ 196 tokens. The kernel k
 the whole per-(batch, head) tile resident in VMEM — one HBM read of
 q/k/v/bias, one write of the output.
 
-MEASURED VERDICT (on-chip, 2026-07-31, docs/BENCH_NOTES.md round-5 session
-#2): XLA's own fusion WINS at these shapes — abs-fused 0.77x vs abs-xla in
+MEASURED VERDICT (on-chip, 2026-07-31): XLA's own fusion WINS at these shapes — abs-fused 0.77x vs abs-xla in
 the fwd+bwd soak, and botnet50 end-to-end 1545 vs 1834 img/s. At L~196 the
 L×L intermediates are small enough that XLA's emitter already keeps them
 close to the MXU; the hand kernel's per-tile grid overhead costs more than

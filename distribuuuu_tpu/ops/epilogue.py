@@ -1,6 +1,6 @@
 """Fused conv-epilogue — Pallas TPU kernels for the resnet hot blocks.
 
-Round-5 on-chip isolation (docs/BENCH_NOTES.md) put the whole train step at
+Round-5 on-chip isolation put the whole train step at
 58.2 true TFLOPs = 55% of the measured 107 TF matmul ceiling, with the
 remaining 45% smeared across the BN/ReLU/residual/data-movement edges — not
 concentrated in any single op. v5e resnet training is HBM-bound, and every
@@ -34,8 +34,9 @@ opinion — the perfdb verdict registry): interpret-verified
 (tests/test_epilogue.py), **off by default** until a >1× on-chip verdict
 from ``scripts/soak_fused_attn.py --epilogue`` lands in the registry and
 flips it — the attention row in docs/PERFORMANCE.md is the cautionary
-precedent. Off-TPU the kernels run in the Pallas interpreter
-automatically, so the routing is testable on CPU.
+precedent. The kernels compile through Mosaic unless a caller asks for the
+Pallas interpreter (``interpret=True``, or `ops.interpret.set_pallas_interpret`
+for the CPU test suite); the platform never picks it.
 """
 
 from __future__ import annotations
@@ -47,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from distribuuuu_tpu.ops.interpret import pallas_interpret
 from distribuuuu_tpu.ops.vmem_guard import VmemBudgetGuard
 
 # VMEM-budget guard (the ops/vmem_guard.py convention): each grid step holds
@@ -109,13 +111,6 @@ def switch_epilogue(
         default=False,
     )
     return decision
-
-
-def _interpret_default() -> bool:
-    """Off-TPU (CPU tests, interpreter soaks) the kernels self-select the
-    Pallas interpreter — the epilogue is traced from inside model code,
-    where no caller can thread an ``interpret=`` flag through flax."""
-    return jax.devices()[0].platform != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -327,7 +322,10 @@ def fused_conv_epilogue(
     measured, else 256.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        # traced from inside model code, where no caller can thread the flag
+        # through flax: the process-wide request (ops/interpret.py), never
+        # the platform
+        interpret = pallas_interpret()
     c = int(x.shape[-1])
     r = int(np.prod(x.shape[:-1]))
     if block_rows is None:

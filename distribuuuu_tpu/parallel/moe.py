@@ -158,9 +158,11 @@ def switch_moe(
             fused_moe_combine,
         )
 
-        # off-TPU a fused path runs the Pallas interpreter (the botnet
-        # DTPU_FUSED_ATTN convention: slow-but-correct instead of a crash)
-        interpret = interpret or jax.default_backend() != "tpu"
+        from distribuuuu_tpu.ops.interpret import pallas_interpret
+
+        # asked for by the caller or process-wide (ops/interpret.py), never
+        # picked from the platform
+        interpret = interpret or pallas_interpret()
 
         send, top, pos, w, fp_sum = fused_moe_dispatch(
             x, gate_kernel, capacity=capacity, interpret=interpret

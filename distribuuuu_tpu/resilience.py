@@ -270,7 +270,7 @@ def preemption_stop_requested(step: int) -> bool:
     preemption sync point (the scheduler's SIGTERM reaches the coordinator,
     which fans the notice out so `reached_preemption_sync_point` flips True
     on all hosts at the same ``step``). When the sync manager isn't available
-    (older runtime, no distributed init) we fall back to the local flag —
+    (no distributed init) we fall back to the local flag —
     schedulers deliver SIGTERM to every host, so same-cadence polling aligns
     the stop step in the common case.
 

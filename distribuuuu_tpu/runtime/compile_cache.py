@@ -21,20 +21,24 @@ import os
 
 
 def enable_persistent_cache(cache_dir: str | None = None) -> str:
-    """Point jax at a persistent on-disk compile cache and return its path.
+    """Turn on jax's persistent on-disk compile cache and return its path.
 
-    ``cache_dir`` default (None/"") is repo-local — next to the checkout
-    this package was imported from — which keeps dev/test runs hermetic.
-    Production runs point it somewhere durable via
-    ``cfg.TRAIN.COMPILE_CACHE_DIR`` (e.g. a persistent volume shared by a
-    host's workers). Idempotent: re-enabling with the same dir is a no-op
-    config update.
+    Whoever launches the process places the cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and no
+    directory is set in code — ``cache_dir`` (``cfg.TRAIN.COMPILE_CACHE_DIR``)
+    does not override it. Where it is not set, ``cache_dir`` if given, else
+    the fixed ``<checkout>/.cache/jax_compile`` next to the checkout this
+    package was imported from (the path is part of the cache key, so it
+    never moves). Idempotent.
     """
     import jax
 
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
     if not cache_dir:
         root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         cache_dir = os.path.join(root, ".cache", "jax_compile")
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     return cache_dir

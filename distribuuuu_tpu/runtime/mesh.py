@@ -31,7 +31,9 @@ def create_mesh(axes: dict[str, int], devices=None) -> Mesh:
 
     -1 is inferred from the remaining device count (like a reshape wildcard).
     Uses `mesh_utils.create_device_mesh` for ICI-aware device ordering on real
-    TPU topologies, falling back to the flat device list (CPU meshes).
+    TPU topologies; there its errors propagate (a topology it cannot lay out
+    is not silently flattened). Non-TPU devices (CPU meshes) have no
+    topology to honour and take the flat device list when it refuses.
 
     ``devices`` (default: all of `jax.devices()`) lets callers build a mesh
     over an explicit subset — how `data_mesh` realizes an undersized
@@ -53,12 +55,14 @@ def create_mesh(axes: dict[str, int], devices=None) -> Mesh:
     if total != n:
         raise ValueError(f"Mesh {sizes} needs {total} devices, have {n}")
 
+    from jax.experimental import mesh_utils
+
     shape = tuple(sizes.values())
     try:
-        from jax.experimental import mesh_utils
-
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     except Exception:
+        if devices[0].platform == "tpu":
+            raise
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, tuple(sizes.keys()))
 

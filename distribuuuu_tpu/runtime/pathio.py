@@ -15,6 +15,7 @@ does too), and the hot decode loop must not pay a VFS indirection.
 
 from __future__ import annotations
 
+import os
 from typing import IO
 
 from etils import epath
@@ -23,6 +24,12 @@ from etils import epath
 def is_remote(path: str) -> bool:
     """True for URL-style paths (gs://, s3://, ...) that bare ``os`` breaks on."""
     return "://" in str(path)
+
+
+def absolute(path: str) -> str:
+    """A local path made absolute against the working directory; URL-style
+    paths pass through unchanged."""
+    return str(path) if is_remote(path) else os.path.abspath(path)
 
 
 def makedirs(path: str) -> None:

@@ -515,9 +515,7 @@ def serve_main(argv: list[str] | None = None) -> int:
     load_cfg_fom_args("dtpu-serve: batched inference engine.", argv=argv)
     cfg.freeze()
     from distribuuuu_tpu.runtime import data_mesh, setup_distributed
-    from distribuuuu_tpu.runtime.compat import ensure_jax_compat
 
-    ensure_jax_compat()
     if cfg.TRAIN.COMPILE_CACHE:
         from distribuuuu_tpu.runtime.compile_cache import enable_persistent_cache
 

@@ -54,10 +54,7 @@ from distribuuuu_tpu.models import build_model
 from distribuuuu_tpu.parallel import fsdp
 from distribuuuu_tpu.parallel import seq as seqpar
 from distribuuuu_tpu.runtime import data_mesh, setup_distributed, setup_seed
-from distribuuuu_tpu.runtime.compat import ensure_jax_compat
 from distribuuuu_tpu.runtime.seeding import configure_determinism
-
-ensure_jax_compat()  # older runtimes: alias jax.shard_map (check_vma→check_rep)
 
 
 @flax.struct.dataclass

@@ -2,7 +2,7 @@
 
 Prints the compiler's own cost model for the full SPMD train step (flops,
 bytes accessed, arithmetic intensity) plus the model-math FLOPs estimate, so
-BENCH_NOTES can state measured img/s against the step's actual FLOP count
+a report can state measured img/s against the step's actual FLOP count
 rather than a hand-wave. Runs on any backend (CPU gives the same HLO-level
 counts; run on TPU for the emitter's real numbers).
 

@@ -65,7 +65,7 @@ def main() -> None:
     args = ap.parse_args()
 
     # Completion marker, written LAST: a .tar existing is not "done" — a run
-    # killed mid-write (tpu_session.sh's timeout) would otherwise poison
+    # killed mid-write (an outer timeout) would otherwise poison
     # every later session with a truncated shard that "already exists".
     # The marker records the generation parameters, so a rerun with different
     # sizes regenerates instead of silently reusing a mismatched dataset.

@@ -6,7 +6,7 @@ Measures the full SPMD train step with bench.py's methodology (3 warmup
 steps for compile+autotune, then timing gated by a device_get metric fetch
 every FETCH_EVERY steps — the production PRINT_FREQ cadence; steps chain
 through `state`, so the final fetch bounds all device work) and prints a
-markdown table for docs/BENCH_NOTES.md.
+markdown table.
 """
 
 import os

@@ -26,8 +26,7 @@ dir to start over (the script prints which).
 
 Recorded run 2026-07-31 (8-dev CPU mesh, seed 1): best val Acc@1 96.0 at
 epoch 60, 95.7 at epoch 100; warmup LR 0.005->0.0497 then cosine->1.2e-5;
-87 min wall. Trajectory and analysis: docs/BENCH_NOTES.md ("Recipe-scale
-convergence"). The band below is calibrated from that run with an 11-point
+87 min wall. The band below is calibrated from that run with an 11-point
 margin.
 """
 

@@ -4,7 +4,7 @@ Validates ops/attention.py against the XLA path on real hardware at BoTNet
 shapes (fwd values, gradients, and speed), then prints the verdict. PASS
 means the numerics hold; the speedup line is the flip/keep signal for
 DTPU_FUSED_ATTN. 2026-07-31 measured verdict: 0.771x — XLA wins at these
-shapes, default stays off (docs/BENCH_NOTES.md round-5 session #2).
+shapes, default stays off.
 
     python scripts/soak_fused_attn.py
 
@@ -485,9 +485,7 @@ def main_seq(args):
     from distribuuuu_tpu.ops import attention as att
     from distribuuuu_tpu.parallel.seq import seq_attention
     from distribuuuu_tpu.runtime import create_mesh
-    from distribuuuu_tpu.runtime.compat import ensure_jax_compat
 
-    ensure_jax_compat()
     interpret = jax.devices()[0].platform != "tpu"
     print(f"devices: {jax.devices()}", flush=True)
     rng = np.random.default_rng(0)

@@ -8,7 +8,7 @@ arm — a mid-sweep wedge costs one arm's timeout, not the sweep.
     python scripts/xla_flag_sweep.py                  # default arm list
     python scripts/xla_flag_sweep.py --arm big-vmem=--xla_tpu_scoped_vmem_limit_kib=98304
 
-Prints a markdown table for docs/BENCH_NOTES.md; arms that fail or regress
+Prints a markdown table; arms that fail or regress
 are data, not errors.
 """
 
@@ -89,27 +89,10 @@ def main() -> None:
     for label, flags in arms:
         rec = run_arm(label, flags, args.timeout, cpu=args.cpu)
         if "error" in rec:
-            # Distinguish "this flag is rejected/fatal on this backend" from
-            # "the chip wedged": re-probe WITHOUT the arm's flags. The
-            # 2026-07-31 sweep hit exactly this — every vmem/scheduler arm
-            # "failed probe" while the device was fine (the perf sweep ran
-            # clean minutes later); the flags themselves kill the runtime.
-            verdict = ""
-            if flags and not args.cpu:
-                try:
-                    probe = subprocess.run(
-                        [sys.executable, os.path.join(os.path.dirname(__file__), "probe_chip.py")],
-                        capture_output=True, text=True, timeout=240,
-                    )
-                    healthy = probe.returncode == 0
-                except subprocess.TimeoutExpired:
-                    healthy = False
-                verdict = (
-                    " [flags rejected by backend — chip healthy without them]"
-                    if healthy
-                    else " [chip unhealthy even without the arm's flags — wedge]"
-                )
-            print(f"| {label} | `{flags or '-'}` | FAILED: {rec['error']}{verdict} |", flush=True)
+            # a flag the backend rejects kills the runtime at start-up: the
+            # arm fails, the baseline arm (no flags) says whether the device
+            # itself is fine
+            print(f"| {label} | `{flags or '-'}` | FAILED: {rec['error']} |", flush=True)
             continue
         v = rec.get("value", 0.0)
         print(f"| {label} | `{flags or '-'}` | {v} |", flush=True)

@@ -31,10 +31,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-from distribuuuu_tpu.runtime.compat import ensure_jax_compat  # noqa: E402
-
-ensure_jax_compat()  # older runtimes: alias jax.shard_map (used below)
-
 from distribuuuu_tpu.parallel import ring_attention  # noqa: E402
 
 assert jax.process_count() == 2 and jax.device_count() == 8

@@ -44,6 +44,20 @@ def test_save_load_roundtrip(tmp_path, tiny_state):
     )
 
 
+def test_relative_out_dir_roundtrip(tmp_path, tiny_state, monkeypatch):
+    """Every shipped config has a relative OUT_DIR (``./resnet50``) and Orbax
+    refuses relative directories: the checkpoint layer makes them absolute,
+    on the save side and for a relative ``MODEL.WEIGHTS`` on the load side."""
+    monkeypatch.chdir(tmp_path)
+    path = ckpt.save_checkpoint("./run", 0, tiny_state, best_acc1=1.0, is_best=False)
+    ckpt.wait_for_saves()
+    assert os.path.isabs(path) and path == str(tmp_path / "run" / "checkpoints" / "ckpt_ep_001")
+    blank = jax.tree.map(jnp.zeros_like, tiny_state)
+    restored, start_epoch, _ = ckpt.load_checkpoint("run/checkpoints/ckpt_ep_001", blank)
+    assert start_epoch == 1
+    np.testing.assert_array_equal(np.asarray(restored.params["w"]), np.arange(4.0))
+
+
 def test_weights_only_best_load(tmp_path, tiny_state):
     out = str(tmp_path)
     ckpt.save_checkpoint(out, 0, tiny_state, best_acc1=1.0, is_best=True)
