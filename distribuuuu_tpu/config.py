@@ -301,8 +301,9 @@ _C.OBS.PROFILE_TOP_OPS = 20
 # Live-array/HBM snapshot journaled at each epoch boundary.
 _C.OBS.MEMORY_SNAPSHOTS = True
 # Train-side tracing (obs/trace.py): journal typed `span` records per
-# PRINT_FREQ window (data-wait + compute phases, from the values the window
-# fetch already holds — zero added syncs) and per checkpoint dispatch.
+# PRINT_FREQ window (data_wait / throttle / dispatch / fetch_wait / host
+# phases, from counters the loop already feeds — zero added syncs) and per
+# checkpoint dispatch.
 _C.OBS.TRAIN_SPANS = True
 # Declarative alarm rules (obs/alarms.py) evaluated by the live aggregator
 # (the export sidecar, the serve frontend, the fleet controller — never the

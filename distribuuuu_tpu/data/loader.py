@@ -42,6 +42,7 @@ from distribuuuu_tpu.data import native
 from distribuuuu_tpu.data.dataset import DummyDataset, ImageFolder, open_image_dataset
 from distribuuuu_tpu.data.transforms import eval_transform_u8, train_transform_u8
 from distribuuuu_tpu.logging import logger
+from distribuuuu_tpu.obs.trace import phase
 
 
 def shard_indices(
@@ -661,12 +662,10 @@ def prefetch_to_device(iterator, mesh, prefetch: int = 2):
                 if batch is last_host:
                     dev = last_dev  # marked replay batch: ship once
                 else:
-                    t0 = time.monotonic()
-                    dev = to_device(batch)
                     # dispatch-side H2D cost on the dedicated transfer
-                    # thread (the copy itself may still be in flight —
-                    # deliberately NOT a sync)  # dtpu-lint: disable=DT006
-                    obs.current().add_wait("h2d_transfer_s", time.monotonic() - t0)
+                    # thread (the copy itself may still be in flight)
+                    with phase("h2d_transfer"):
+                        dev = to_device(batch)
                     if REPLAY_CONST in batch:
                         # memoize ONLY marked batches: holding a reference to
                         # every real batch would pin ~one extra host+device
@@ -694,9 +693,8 @@ def prefetch_to_device(iterator, mesh, prefetch: int = 2):
             # window record's ``data_wait_frac`` — the data-wait alarm's
             # signal (docs/OBSERVABILITY.md). Host clock around a queue get:
             # no device sync.
-            t_wait = time.monotonic()
-            item = q.get()
-            obs.current().add_wait("data_wait_s", time.monotonic() - t_wait)
+            with phase("data_wait"):
+                item = q.get()
             if item is done:
                 break
             if isinstance(item, BaseException):

@@ -19,7 +19,13 @@ The observable surface of the framework, in one subsystem:
 - **Live telemetry plane** (dtpu-obs v2): incremental journal tailing +
   current-state aggregation (`obs.stream`), Prometheus ``/metrics``
   exporters + the embeddable `ObsPlane` (`obs.exporter`), request/step
-  tracing (`obs.trace`), and the declarative alarm engine (`obs.alarms`).
+  tracing (`obs.trace`: serve request spans; the train loop's phases
+  ``h2d_transfer`` / ``data_wait`` / ``throttle`` / ``dispatch`` /
+  ``fetch_wait`` / ``checkpoint`` as profiler spans and wait counters, the
+  jitted step's ``dtpu.grad_sync`` / ``optimizer`` / ``guard`` /
+  ``metrics`` / ``loss`` scopes, and per-window ``data_wait`` /
+  ``throttle`` / ``dispatch`` / ``fetch_wait`` / ``host`` journal spans),
+  and the declarative alarm engine (`obs.alarms`).
 - **CLI** (`obs.__main__`): ``python -m distribuuuu_tpu.obs
   summarize|validate|export``.
 """

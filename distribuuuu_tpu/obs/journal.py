@@ -99,6 +99,13 @@ SCHEMA: dict[str, tuple[dict[str, tuple], dict[str, tuple]]] = {
             # window the step loop spent blocked on the input pipeline
             # (the data-wait alarm's signal)
             "data_wait_frac": _NUM,
+            # seconds of this window the loop spent blocked in the step's
+            # first launches, inside the train_step call and inside the
+            # boundary fetch (obs/trace.py phases): with data wait they say
+            # where a slow window lost its time
+            "throttle_s": _NUM,
+            "dispatch_s": _NUM,
+            "fetch_wait_s": _NUM,
         },
     ),
     "epoch_train": (
@@ -484,7 +491,8 @@ SCHEMA: dict[str, tuple[dict[str, tuple], dict[str, tuple]]] = {
     # trace id that ties the phases together: serve requests carry the
     # client-minted ``x-dtpu-trace-id`` through frontend -> batcher ->
     # engine (phases queue_wait / pad / execute / total); train windows
-    # mint ``train-<run>-g<gstep>`` ids (phases data_wait / compute) and
+    # mint ``train-<run>-g<gstep>`` ids (phases data_wait / throttle /
+    # dispatch / fetch_wait / host, summing to the window's wall) and
     # checkpoint dispatches ``train-<run>-ck<epoch>`` (phase checkpoint)
     "span": (
         {"trace_id": _STR, "phase": _STR, "ms": _NUM},

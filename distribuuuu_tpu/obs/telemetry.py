@@ -285,6 +285,16 @@ class Telemetry:
         # the data-wait alarm's signal, measured where the stall is felt
         data_wait_s = self._window_wait_delta("data_wait_s")
         data_wait_frac = min(1.0, data_wait_s / wall_s)
+        # where the rest of the wall went (obs/trace.py phases): blocked in
+        # the step's first launches by the runtime's full queue, inside the
+        # train_step call, inside the boundary fetch, or in none — a slow
+        # window with the loss in throttle_s or fetch_wait_s lost it on the
+        # device or its feed, one with the loss in dispatch_s in the
+        # runtime's launch path, one with the loss in none to a host that
+        # was not running the loop
+        throttle_s = self._window_wait_delta("throttle_s")
+        dispatch_s = self._window_wait_delta("dispatch_s")
+        fetch_wait_s = self._window_wait_delta("fetch_wait_s")
         self.event(
             "window",
             epoch=epoch,
@@ -299,6 +309,9 @@ class Telemetry:
             step_time_max=round(times[-1], 6),
             data_time=round(float(data_time), 6),
             data_wait_frac=round(data_wait_frac, 6),
+            throttle_s=round(throttle_s, 6),
+            dispatch_s=round(dispatch_s, 6),
+            fetch_wait_s=round(fetch_wait_s, 6),
             imgs_per_sec=round(imgs / wall_s, 3),
             goodput=round(self.goodput(), 6),
             mfu=round(mfu_val, 6) if mfu_val is not None else None,
@@ -309,14 +322,19 @@ class Telemetry:
             acck=float(acck) if acck is not None else None,
         )
         if self._train_spans:
-            # the window IS the trace: its wall splits into the time spent
-            # blocked on data and everything else (compute + dispatch) —
-            # both derived from values already on the host, zero syncs
+            # the window IS the trace: its wall splits into the loop's four
+            # measured phases and ``host``, the rest — all from values already
+            # on the host, zero syncs
             tid = self.trace_tag(f"g{gstep}")
-            self.span(tid, "data_wait", 1000.0 * data_wait_s,
-                      gstep=gstep, epoch=epoch)
-            self.span(tid, "compute", 1000.0 * max(0.0, wall_s - data_wait_s),
-                      gstep=gstep, epoch=epoch)
+            phases = {
+                "data_wait": data_wait_s,
+                "throttle": throttle_s,
+                "dispatch": dispatch_s,
+                "fetch_wait": fetch_wait_s,
+            }
+            phases["host"] = max(0.0, wall_s - sum(phases.values()))
+            for name, seconds in phases.items():
+                self.span(tid, name, 1000.0 * seconds, gstep=gstep, epoch=epoch)
 
     def epoch_end(
         self, *, epoch: int, steps: int, skipped: int, wall_s: float, imgs: float

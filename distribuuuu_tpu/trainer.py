@@ -51,6 +51,7 @@ from distribuuuu_tpu.metrics import (
     topk_correct_weighted,
 )
 from distribuuuu_tpu.models import build_model
+from distribuuuu_tpu.obs.trace import phase, step_scope
 from distribuuuu_tpu.parallel import fsdp
 from distribuuuu_tpu.parallel import seq as seqpar
 from distribuuuu_tpu.runtime import data_mesh, setup_distributed, setup_seed
@@ -86,7 +87,10 @@ def _forward_loss(model, params, batch_stats, batch, train: bool, rng, qat=None)
     else:
         logits = apply(variables, images, train=False)
         new_stats = batch_stats
-    loss = cross_entropy_loss(logits, batch["label"], cfg.TRAIN.LABEL_SMOOTH)
+    # the loss outside the module gets a name of its own, so its forward and
+    # backward read as jvp(dtpu.loss) and not as bare ops (obs/trace.py)
+    with step_scope("loss"):
+        loss = cross_entropy_loss(logits, batch["label"], cfg.TRAIN.LABEL_SMOOTH)
     if qat is not None and train and cfg.QUANT.QAT_DISTILL > 0.0:
         # self-distillation toward the model's own fp logits: the serve
         # gate's logit-RMSE metric, optimized directly (the rescue knob —
@@ -137,15 +141,16 @@ def _forward_loss_mae(model, params, batch_stats, batch, train: bool, rng, seq_n
     if seq_n > 1:
         target = seqpar.local_tokens(target)
         mask_f = seqpar.local_tokens(mask_f)
-    err = jnp.mean((pred.astype(jnp.float32) - target) ** 2, axis=-1)  # [B, L_local]
-    se = jnp.sum(err * mask_f)
-    cnt = jnp.sum(mask_f)
-    if seq_n > 1:
-        # psum_partial, not lax.psum: the members' sums are PARTIAL and the
-        # cotangent coming back is replicated — plain psum's unchecked-mode
-        # transpose would scale every gradient by seq_n (parallel/seq.py)
-        se, cnt = seqpar.psum_partial((se, cnt), seqpar.SEQ_AXIS)
-    loss = se / jnp.maximum(cnt, 1.0)
+    with step_scope("loss"):
+        err = jnp.mean((pred.astype(jnp.float32) - target) ** 2, axis=-1)  # [B, L_local]
+        se = jnp.sum(err * mask_f)
+        cnt = jnp.sum(mask_f)
+        if seq_n > 1:
+            # psum_partial, not lax.psum: the members' sums are PARTIAL and the
+            # cotangent coming back is replicated — plain psum's unchecked-mode
+            # transpose would scale every gradient by seq_n (parallel/seq.py)
+            se, cnt = seqpar.psum_partial((se, cnt), seqpar.SEQ_AXIS)
+        loss = se / jnp.maximum(cnt, 1.0)
     # pred rides the logits slot (metrics skip top-k for mae); MAE has no
     # BatchNorm, so the stats pass through untouched
     return loss, (pred, batch_stats)
@@ -248,7 +253,13 @@ def make_train_step(
         )
         return loss, logits, new_stats, grads
 
-    def step(state: TrainState, batch, lr, rng):
+    # The name is the compiled module's (``jit_step_training``) and part of
+    # the persistent compile cache's key, which a scope alone is not (debug
+    # info is stripped before hashing, so a step that differs from a cached
+    # one in its scopes alone loads the older build's metadata: whoever moves
+    # a scope renames the step). benchmark/xplane finds the train step by the
+    # ``jit_step`` prefix, and tests/test_trace_phases.py pins it.
+    def step_training(state: TrainState, batch, lr, rng):
         # distinct dropout stream per device (rng arrives replicated); on a
         # 2-D mesh the fold uses the linearized device index so a (d, f) mesh
         # reproduces the stream of a (d·f,)-device data-parallel mesh
@@ -293,77 +304,91 @@ def make_train_step(
             # input stats never enter a train-mode forward, so grads/outputs
             # are unaffected; equality vs the sequential oracle is pinned in
             # tests/test_train_step.py).
-        if seq_n > 1:
-            # each seq member holds the PARTIAL gradient of its token shard
-            # (the model's seq path keeps every parameter use partial —
-            # slice-transpose zero-padding, bias-1/P head, psum'd loss
-            # sums); the sum over the seq axis is the full gradient. This
-            # runs FIRST so the fsdp/data reductions below see seq-complete
-            # values, exactly as on a seq-less mesh.
-            grads = jax.lax.psum(grads, seqpar.SEQ_AXIS)
-        if use_fsdp:
-            # sharded leaves arrive as per-shard fsdp-axis SUMS from the
-            # gather transpose (÷N makes them means); replicated leaves still
-            # differ along fsdp and take an explicit pmean there
-            grads = fsdp.average_grads(grads, param_specs, fsdp_n)
-        grads = jax.lax.pmean(grads, "data")
-        # Running BN stats: averaged across replicas so state stays replicated.
-        # (With SYNCBN the normalization stats are already cross-replica; this
-        # additionally keeps the *running* estimates identical on every chip —
-        # strictly more consistent than DDP's per-rank copies, SURVEY §2b.)
-        new_stats = jax.lax.pmean(new_stats, reduce_axes)
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optim.apply_updates_with_lr(state.params, updates, lr)
+        # What follows the gradient is named in the executable's metadata
+        # (obs/trace.py STEP_SCOPES), so a device trace can tell the
+        # optimizer from the gradient mean from the guard; the model's
+        # forward and backward keep the module paths flax gives them. The
+        # scopes are metadata only: same equations, same order, same fusions.
+        with step_scope("grad_sync"):
+            if seq_n > 1:
+                # each seq member holds the PARTIAL gradient of its token shard
+                # (the model's seq path keeps every parameter use partial —
+                # slice-transpose zero-padding, bias-1/P head, psum'd loss
+                # sums); the sum over the seq axis is the full gradient. This
+                # runs FIRST so the fsdp/data reductions below see seq-complete
+                # values, exactly as on a seq-less mesh.
+                grads = jax.lax.psum(grads, seqpar.SEQ_AXIS)
+            if use_fsdp:
+                # sharded leaves arrive as per-shard fsdp-axis SUMS from the
+                # gather transpose (÷N makes them means); replicated leaves still
+                # differ along fsdp and take an explicit pmean there
+                grads = fsdp.average_grads(grads, param_specs, fsdp_n)
+            grads = jax.lax.pmean(grads, "data")
+            # Running BN stats: averaged across replicas so state stays replicated.
+            # (With SYNCBN the normalization stats are already cross-replica; this
+            # additionally keeps the *running* estimates identical on every chip —
+            # strictly more consistent than DDP's per-rank copies, SURVEY §2b.)
+            new_stats = jax.lax.pmean(new_stats, reduce_axes)
+        with step_scope("optimizer"):
+            updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
+            new_params = optim.apply_updates_with_lr(state.params, updates, lr)
         n = jnp.float32(batch["label"].shape[0])
         if task == "mae":
             # pixel reconstruction has no top-k; the counters stay zero so
             # the metric schema (and the meters) are task-invariant
             correct = {1: jnp.float32(0.0), topk: jnp.float32(0.0)}
         else:
-            correct = topk_correct(logits, batch["label"], ks=(1, topk))
+            with step_scope("metrics"):
+                correct = topk_correct(logits, batch["label"], ks=(1, topk))
         if nonfinite_guard:
-            # keep is derived from pmean'd values only, so it is identical on
-            # every device and the selection below stays replicated. A NaN
-            # anywhere on any device poisons the pmean'd grads, so checking
-            # the post-collective values catches per-device faults too.
-            keep = jnp.isfinite(jax.lax.pmean(loss, reduce_axes))
-            local_ok = jnp.bool_(True)
-            for g in jax.tree.leaves(grads):
-                local_ok = jnp.logical_and(local_ok, jnp.all(jnp.isfinite(g)))
-            if use_fsdp:
-                # grads are per-device SHARDS here, so finiteness is a local
-                # fact — agree across the mesh or devices would diverge on
-                # the select below (the replicated path needs no collective:
-                # its pmean'd grads are identical everywhere already)
-                ok_count = jax.lax.psum(
-                    local_ok.astype(jnp.float32), reduce_axes
-                )
-                keep = jnp.logical_and(keep, ok_count == n_reduce_devices)
-            else:
-                keep = jnp.logical_and(keep, local_ok)
+            with step_scope("guard"):
+                # keep is derived from pmean'd values only, so it is identical on
+                # every device and the selection below stays replicated. A NaN
+                # anywhere on any device poisons the pmean'd grads, so checking
+                # the post-collective values catches per-device faults too.
+                keep = jnp.isfinite(jax.lax.pmean(loss, reduce_axes))
+                local_ok = jnp.bool_(True)
+                for g in jax.tree.leaves(grads):
+                    local_ok = jnp.logical_and(local_ok, jnp.all(jnp.isfinite(g)))
+                if use_fsdp:
+                    # grads are per-device SHARDS here, so finiteness is a local
+                    # fact — agree across the mesh or devices would diverge on
+                    # the select below (the replicated path needs no collective:
+                    # its pmean'd grads are identical everywhere already)
+                    ok_count = jax.lax.psum(
+                        local_ok.astype(jnp.float32), reduce_axes
+                    )
+                    keep = jnp.logical_and(keep, ok_count == n_reduce_devices)
+                else:
+                    keep = jnp.logical_and(keep, local_ok)
 
             def sel(new, old):
                 return jnp.where(keep, new, old)
 
-            new_params = jax.tree.map(sel, new_params, state.params)
-            new_opt_state = jax.tree.map(sel, new_opt_state, state.opt_state)
-            new_stats = jax.tree.map(sel, new_stats, state.batch_stats)
-            # a skipped step contributes nothing to the epoch averages (its
-            # loss is NaN and NaN logits rank every label "correct")
-            zero = jnp.float32(0.0)
-            loss_term = jnp.where(keep, loss * n, zero)
-            n = jnp.where(keep, n, zero)
-            correct = {k: jnp.where(keep, v, zero) for k, v in correct.items()}
-        else:
-            loss_term = loss * n
-        metrics = {
-            "loss_sum": jax.lax.psum(loss_term, reduce_axes),
-            "n": jax.lax.psum(n, reduce_axes),
-            "correct1": jax.lax.psum(correct[1], reduce_axes),
-            f"correct{topk}": jax.lax.psum(correct[topk], reduce_axes),
-        }
-        if nonfinite_guard:
-            metrics["skipped"] = 1.0 - keep.astype(jnp.float32)
+            # the selects are named where the update is: each is the last op
+            # on its leaf, and a trace gives a fused update its root's scope
+            with step_scope("optimizer"):
+                new_params = jax.tree.map(sel, new_params, state.params)
+                new_opt_state = jax.tree.map(sel, new_opt_state, state.opt_state)
+                new_stats = jax.tree.map(sel, new_stats, state.batch_stats)
+        with step_scope("metrics"):
+            if nonfinite_guard:
+                # a skipped step contributes nothing to the epoch averages (its
+                # loss is NaN and NaN logits rank every label "correct")
+                zero = jnp.float32(0.0)
+                loss_term = jnp.where(keep, loss * n, zero)
+                n = jnp.where(keep, n, zero)
+                correct = {k: jnp.where(keep, v, zero) for k, v in correct.items()}
+            else:
+                loss_term = loss * n
+            metrics = {
+                "loss_sum": jax.lax.psum(loss_term, reduce_axes),
+                "n": jax.lax.psum(n, reduce_axes),
+                "correct1": jax.lax.psum(correct[1], reduce_axes),
+                f"correct{topk}": jax.lax.psum(correct[topk], reduce_axes),
+            }
+            if nonfinite_guard:
+                metrics["skipped"] = 1.0 - keep.astype(jnp.float32)
         return (
             TrainState(params=new_params, batch_stats=new_stats, opt_state=new_opt_state),
             metrics,
@@ -371,7 +396,7 @@ def make_train_step(
 
     state_in_specs = state_specs if use_fsdp else P()
     sharded = jax.shard_map(
-        step,
+        step_training,
         mesh=mesh,
         in_specs=(state_in_specs, P(fsdp.batch_axes(mesh)), P(), P()),
         out_specs=(state_in_specs, P()),
@@ -405,7 +430,9 @@ def make_eval_step(model, mesh: Mesh, topk: int, state_specs=None, qat=None,
         task = cfg.TRAIN.TASK
     seq_n = seqpar.seq_size(mesh)
 
-    def step(state: TrainState, batch, totals):
+    # named apart from the train step (``jit_eval_step``): a device trace
+    # finds the train step by its ``jit_step`` prefix and must not find this
+    def eval_step(state: TrainState, batch, totals):
         params = state.params
         if use_fsdp:
             params = fsdp.all_gather_params(params, state_specs.params)
@@ -445,7 +472,7 @@ def make_eval_step(model, mesh: Mesh, topk: int, state_specs=None, qat=None,
 
     state_in_specs = state_specs if use_fsdp else P()
     sharded = jax.shard_map(
-        step, mesh=mesh, in_specs=(state_in_specs, P(fsdp.batch_axes(mesh)), P()),
+        eval_step, mesh=mesh, in_specs=(state_in_specs, P(fsdp.batch_axes(mesh)), P()),
         out_specs=P(), check_vma=False,
     )
     # NB: totals is NOT donated — the buffers are 4 scalars, and donating a
@@ -786,22 +813,31 @@ def train_epoch(
             batch = resilience.poison_batch_nan(batch)
             if is_primary:
                 logger.warning(f"FAULT INJECTION: NaN batch at global step {gstep}")
-        # two-level fold: no collisions however long the epoch runs
-        step_rng = jax.random.fold_in(jax.random.fold_in(rng, epoch), it)
+        # The step's first launches (the per-step key's two fold_ins). The
+        # runtime lets the host run only a few steps ahead of the device and
+        # blocks it inside whichever launch comes first once its queue is
+        # full: here. So this phase holds the device's back-pressure on the
+        # loop, and ``dispatch`` below the host's own cost of launching the
+        # step (obs/trace.py phases).
+        with phase("throttle", gstep=gstep):
+            # two-level fold: no collisions however long the epoch runs
+            step_rng = jax.random.fold_in(jax.random.fold_in(rng, epoch), it)
         if tel.wants_step_cost:
             # one-shot analytical step pricing for MFU: LOWERS the jitted
             # step (tracing only — no compile, CompileGuard stays exact)
             tel.capture_step_cost(train_step, state, batch, lr_arr, step_rng)
         if prof is not None:
             prof.maybe_start(gstep)
-        state, m = train_step(state, batch, lr_arr, step_rng)
+        with phase("dispatch", step_num=gstep):
+            state, m = train_step(state, batch, lr_arr, step_rng)
         window.append(m)
         if prof is not None:
             prof.after_step(gstep, window)
         if it % cfg.TRAIN.PRINT_FREQ == 0 or it == len(loader) - 1:
             # device_get is the sync point (block_until_ready is unreliable on
             # some transports); fetch BEFORE timestamping the window
-            vals = jax.device_get(window)
+            with phase("fetch_wait", gstep=gstep):
+                vals = jax.device_get(window)
             now = time.time()
             win_wall = now - t_window
             win_steps = len(window)
@@ -1330,8 +1366,8 @@ def train_model():
             is_best = acc1 > best_acc1
             best_acc1 = max(acc1, best_acc1)
             resilience.watchdog_beat(phase="checkpoint")  # long saves ≠ hangs
-            ck_tic = time.time()
-            path = ckpt.save_checkpoint(cfg.OUT_DIR, epoch, state, best_acc1, is_best)
+            with phase("checkpoint", epoch=epoch) as ck_phase:
+                path = ckpt.save_checkpoint(cfg.OUT_DIR, epoch, state, best_acc1, is_best)
             if cfg.OBS.TRAIN_SPANS:
                 # the epoch boundary's checkpoint phase as a typed span: the
                 # DISPATCH wall (saves are async — the write itself overlaps
@@ -1339,7 +1375,7 @@ def train_model():
                 tel_run = obs.current()
                 tel_run.span(
                     tel_run.trace_tag(f"ck{epoch}"), "checkpoint",
-                    1000.0 * (time.time() - ck_tic), epoch=epoch,
+                    1000.0 * ck_phase.seconds, epoch=epoch,
                 )
             logger.info(f"Saving checkpoint (async): {path} (best Acc@1 {best_acc1:.3f})")
     finally:

@@ -83,7 +83,7 @@ def main() -> int:
     assert windows, "train journaled no windows"
     assert all("data_wait_frac" in w for w in windows), "data_wait_frac missing"
     spans = [r for r in read_journal(journal) if r["kind"] == "span"]
-    assert {s["phase"] for s in spans} >= {"data_wait", "compute"}, spans
+    assert {s["phase"] for s in spans} >= {"data_wait", "throttle", "dispatch", "fetch_wait", "host"}, spans
     print(f"train OK: {len(windows)} window(s), {len(spans)} span(s)")
 
     # 2. + 3. the export sidecar with a deliberately-unmeetable goodput

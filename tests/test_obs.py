@@ -354,17 +354,22 @@ def test_smoke_train_emits_schema_valid_journal(fresh_cfg, tmp_path):
         # time / window wall, journaled on every window
         assert 0.0 <= w["data_wait_frac"] <= 1.0
 
-    # train-side spans (dtpu-obs v2): each window journals its data-wait +
-    # compute phases under one trace id; epoch boundaries add a checkpoint
-    # span — all fed from the existing PRINT_FREQ fetch
+    # train-side spans (obs/trace.py TRAIN_PHASES): each window journals the
+    # loop's four measured phases and the rest of its wall (host) under one
+    # trace id; epoch boundaries add a checkpoint span — all fed from the
+    # existing PRINT_FREQ fetch
+    from distribuuuu_tpu.obs.trace import TRAIN_PHASES
+
+    assert "compute" not in TRAIN_PHASES
+    window_phases = set(TRAIN_PHASES) - {"checkpoint"}
     spans = [r for r in recs if r["kind"] == "span"]
-    assert {s["phase"] for s in spans} >= {"data_wait", "compute", "checkpoint"}
+    assert {s["phase"] for s in spans} == set(TRAIN_PHASES)
     by_trace = {}
     for s in spans:
         by_trace.setdefault(s["trace_id"], set()).add(s["phase"])
-    window_traces = [p for p in by_trace.values() if "compute" in p]
+    window_traces = [p for p in by_trace.values() if "dispatch" in p]
     assert len(window_traces) == len(windows)
-    assert all({"data_wait", "compute"} == p for p in window_traces)
+    assert all(window_phases == p for p in window_traces)
 
     # monitoring counters journaled per epoch; epoch 0 must have seen the
     # compile machinery (trace events fire even when the persistent compile
