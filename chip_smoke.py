@@ -42,6 +42,9 @@ EPILOGUE_SHAPES = [
 ]
 # (batch, heads, L, d): botnet50's MHSA at 224 px, and the 4x-token case
 ATTENTION_SHAPES = [(8, 4, 196, 128), (4, 4, 784, 64)]
+# (batch, L, heads, hd) of the packed-qkv pair: vit_b16.train's own shape,
+# then MAE's 50-token encoder and its hd-32 decoder (four heads a lane group)
+SELF_ATTENTION_SHAPES = [(128, 197, 12, 64), (8, 50, 12, 64), (8, 197, 16, 32)]
 
 # max|fused - ref| over max|ref|, per output and per gradient. Epilogue: one
 # bf16 ulp at the top of the range forward (elementwise, both round once at
@@ -416,6 +419,25 @@ def kernels_attention(shapes, seed: int, cmp: Comparisons) -> None:
         )
 
 
+def kernels_self_attention(shapes, seed: int, cmp: Comparisons) -> None:
+    """The bias-free pair the ViT block takes on the chip (`dtpu_attn_fwd`,
+    `dtpu_attn_bwd`) against the einsum route, output and all of d_qkv."""
+    import jax
+    import jax.numpy as jnp
+
+    from distribuuuu_tpu.ops.attention import fused_self_attention, xla_self_attention
+
+    key = jax.random.PRNGKey(seed + 2)
+    for b, l, h, hd in shapes:
+        kq, key = jax.random.split(key)
+        qkv = jax.random.normal(kq, (b, l, 3 * h * hd), jnp.float32).astype(jnp.bfloat16)
+        cmp.case(
+            f"self_attention.L{l}h{h}d{hd}",
+            lambda x: fused_self_attention(x, h, cmp.interpret),
+            lambda x: xla_self_attention(x, h), (qkv,), ATTENTION_TOL, ATTENTION_TOL,
+        )
+
+
 def fused_train_steps(args, interpret: bool, n_steps: int = 3) -> dict:
     """resnet50 train steps with `MODEL.FUSED_EPILOGUE True` against the
     unfused steps from the same state and batch."""
@@ -482,20 +504,23 @@ def phase_kernels(args, bridge) -> None:
     # compiled, asserted, never defaulted: only the rehearsal asks for the
     # interpreter, and it lifted the device check to do so
     check(interpret == args.rehearse_cpu, "Pallas interpret mode was switched on by something")
-    epi_shapes, attn_shapes = EPILOGUE_SHAPES, ATTENTION_SHAPES
+    epi_shapes, attn_shapes, self_shapes = EPILOGUE_SHAPES, ATTENTION_SHAPES, SELF_ATTENTION_SHAPES
     if args.rehearse_cpu:
         epi_shapes = [(2, 8, 8, 64), (2, 4, 4, 128)]
         attn_shapes = [(1, 2, 16, 128)]
+        self_shapes = [(2, 21, 2, 64)]
     ph = Phase("kernels", bridge)
     cmp = Comparisons(interpret)
     kernels_epilogue(epi_shapes, args.seed, cmp)
     kernels_attention(attn_shapes, args.seed, cmp)
+    kernels_self_attention(self_shapes, args.seed, cmp)
     steps = fused_train_steps(args, interpret)
     missed = cmp.missed + steps.pop("missed")
     ph.line(
         interpret=interpret,
         epilogue_shapes=epi_shapes,
         attention_shapes_bnld=attn_shapes,
+        self_attention_shapes_blhd=self_shapes,
         tolerance=(
             f"max|fused-ref|/max|ref|: epilogue forward <= {EPILOGUE_TOL} (one bf16 "
             f"ulp), gradient <= {EPILOGUE_GRAD_TOL} (two); attention <= {ATTENTION_TOL}"

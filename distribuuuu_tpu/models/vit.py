@@ -22,6 +22,11 @@ TPU notes:
   preserves the stability the f32-params/bf16-compute convention targets);
   the next matmul casts back down.
 - softmax in float32 (``preferred_element_type``), like the rest of the zoo.
+- the attention core (scores, softmax, weighted values and their gradients)
+  is `ops.attention.self_attention`: on TPUs one fused forward and one fused
+  backward kernel that read the packed qkv activation as the ``qkv`` Dense
+  leaves it and keep every L×L tensor in VMEM; the same einsums as ever on
+  other devices and for tiles VMEM cannot hold. Device and shape decide.
 - no data-dependent control flow; blocks unroll at trace time;
   ``MODEL.REMAT`` wraps each encoder block in `jax.checkpoint`.
 - the encoder is position-agnostic (positions enter once, at embed time),
@@ -62,26 +67,23 @@ class MultiHeadSelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         b, l, d = x.shape
-        head_dim = d // self.num_heads
         qkv = nn.Dense(
             3 * d, dtype=self.dtype, param_dtype=jnp.float32,
             kernel_init=xavier_uniform, name="qkv",
         )(x)
-        qkv = qkv.reshape(b, l, 3, self.num_heads, head_dim)
-        q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))  # [B,H,L,hd]
+        if self.seq_axis is None:
+            from distribuuuu_tpu.ops.attention import self_attention
 
-        if self.seq_axis is not None:
+            # fused forward+backward pair or the einsums, by device and shape
+            out = self_attention(qkv, self.num_heads)
+        else:
             from distribuuuu_tpu.parallel.seq import seq_attention
 
+            qkv = qkv.reshape(b, l, 3, self.num_heads, d // self.num_heads)
+            q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))  # [B,H,L,hd]
             # MODEL.SEQ_ATTN routes here; scales internally
             out = seq_attention(q, k, v, impl=self.seq_impl, axis_name=self.seq_axis)
-        else:
-            scale = head_dim**-0.5
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
-            w = jax.nn.softmax(s * scale, axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd", w.astype(v.dtype), v)
-
-        out = out.transpose(0, 2, 1, 3).reshape(b, l, d)
+            out = out.transpose(0, 2, 1, 3).reshape(b, l, d)
         return nn.Dense(
             d, dtype=self.dtype, param_dtype=jnp.float32,
             kernel_init=xavier_uniform, name="proj",

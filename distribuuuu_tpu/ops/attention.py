@@ -1,29 +1,40 @@
-"""Fused biased attention for BoTNet's MHSA — Pallas TPU kernel + XLA fallback.
+"""Fused attention kernels (Pallas TPU) with their XLA formulations.
 
-The BoTNet attention (reference `/root/reference/distribuuuu/models/botnet.py:193-215`)
-is ``softmax(q·kᵀ + pos_bias)·v`` over L = H·W ≈ 196 tokens. The kernel keeps
-the whole per-(batch, head) tile resident in VMEM — one HBM read of
-q/k/v/bias, one write of the output.
+Two families, sorted apart on purpose:
 
-MEASURED VERDICT (on-chip, 2026-07-31): XLA's own fusion WINS at these shapes — abs-fused 0.77x vs abs-xla in
-the fwd+bwd soak, and botnet50 end-to-end 1545 vs 1834 img/s. At L~196 the
-L×L intermediates are small enough that XLA's emitter already keeps them
-close to the MXU; the hand kernel's per-tile grid overhead costs more than
-the HBM traffic it saves. The kernel stays as an opt-in (DTPU_FUSED_ATTN=1)
-for larger-L regimes where the O(L²) HBM round-trip argument regains force —
-and past the single-tile VMEM budget the dispatch now re-tiles to the
-BLOCKWISE online-softmax kernels below (O(block²) per tile), so L≥1024 runs
-in-kernel instead of falling back; the large-L flip/keep verdict comes from
-`scripts/soak_fused_attn.py --seq` (docs/PERFORMANCE.md "Large-L kernels").
+**Bias-free self-attention from packed qkv** (ViT, MAE; bottom of this file):
+`self_attention` is what `models/vit.py` calls. It takes one fused forward
+kernel and one fused backward kernel (`dtpu_attn_fwd`, `dtpu_attn_bwd`)
+where the step is being traced for TPUs and a batch row's tile fits VMEM,
+and the plain einsums elsewhere; device and shape decide, nothing else. On
+the v5e the pair took `vit_b16.train`'s twelve ``block*/attn`` from 109.9 to
+42.0 ms a step (PERF.md section 6, PR 27).
 
-Training support: `fused_attention` is a `jax.custom_vjp`. The forward is the
-Pallas kernel; the backward recomputes the attention weights with XLA einsums
-(flash-attention-style recompute — cheaper than saving the L×L weights to
-HBM) and emits standard gradients.
+**Biased attention for BoTNet's MHSA** (reference
+`/root/reference/distribuuuu/models/botnet.py:193-215`):
+``softmax(q·kᵀ + pos_bias)·v`` over L = H·W ≈ 196 tokens, one (batch, head)
+tile a grid step, opt-in through `switch_attention` (``DTPU_FUSED_ATTN`` or
+a perfdb verdict; off by default). What these kernels pay that the bias-free
+pair does not: a float32 ``[L, L]`` bias read from HBM per (batch, head) (as
+many bytes as one of the passes they save; the ``abs`` variant forms it
+in-kernel from the ``[L, D]`` table instead), q, k and v relaid to
+``[B·H, L, d]`` before the call, and a backward that is XLA einsums in
+float32 (`_bwd`), which writes every L×L tensor back to HBM. An earlier
+docstring carried a "0.77x against XLA, 1545 vs 1834 img/s" verdict dated
+2026-07-31; no ledger line, journal or `PERF.md` entry records that run, so
+it is not repeated here: the kernels are off until a benchmark cell
+(`botnet50.train`, PERF.md section 7) gives them a verdict. Past the
+single-tile VMEM budget the dispatch re-tiles to the BLOCKWISE
+online-softmax kernels below (O(block²) per tile), so L≥1024 runs in-kernel
+instead of falling back.
 
-The kernel runs per (batch·head) grid step; tiles (L ≤ a few hundred, D=128)
-fit VMEM comfortably: q/k/v bf16 196×128 ≈ 50 KB each, bias/logits f32
-196×196 ≈ 154 KB.
+Training support of the biased family: `fused_attention` is a
+`jax.custom_vjp`. The forward is the Pallas kernel; the backward recomputes
+the attention weights with XLA einsums and emits standard gradients.
+
+The biased kernels run per (batch·head) grid step; tiles (L ≤ a few hundred,
+D=128) fit VMEM comfortably: q/k/v bf16 196×128 ≈ 50 KB each, bias/logits
+f32 196×196 ≈ 154 KB.
 """
 
 from __future__ import annotations
@@ -35,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from distribuuuu_tpu.ops.vmem_guard import VmemBudgetGuard
+from distribuuuu_tpu.ops.interpret import pallas_interpret
+from distribuuuu_tpu.ops.vmem_guard import DEFAULT_VMEM_BUDGET_MB, VmemBudgetGuard
 
 # VMEM-budget guard: the single-tile kernels keep a whole (batch·head) tile
 # resident, so per-tile footprint grows O(L²) — past ~16 MB/core the Mosaic
@@ -560,3 +572,247 @@ def fused_attention_abs(q, k, v, emb, *, interpret: bool = False):
             preferred_element_type=jnp.float32,
         ),
     )
+
+
+# ---------------------------------------------------------------------------
+# Bias-free self-attention from packed qkv (ViT/MAE): fused forward + backward
+# ---------------------------------------------------------------------------
+#
+# The ViT block's attention has no bias, so nothing of size L×L has to exist
+# outside VMEM in either direction. One grid step holds one batch row with all
+# its heads: the packed ``[L, 3·D]`` row of the qkv Dense comes in as it lies
+# (q, k and v are its 128-lane column groups, so no ``[B,H,L,hd]`` relayout is
+# ever made) and the output leaves as the ``[L, D]`` row the proj Dense reads.
+# A 128-lane group holds ``128 // hd`` heads. One head is picked out of a
+# group by zeroing the other heads' lanes of ONE operand, which costs the MXU
+# nothing (a 64-deep contraction fills half of its 128 rows either way) and
+# needs no lane slicing: q masked gives that head's scores, the incoming
+# gradient masked gives its dP and dV, k and q masked give dQ and dK, each
+# landing in that head's lanes of a full-width result.
+#
+# The arithmetic is the einsum route's: operands in the input dtype, float32
+# accumulation, scores and softmax in float32 (max-subtracted; the row sum is
+# divided exactly, once per row), weights cast to the value dtype. Saved for
+# the way back beside qkv and the output: the row log-sum-exp, ``[B, L, H]``.
+
+
+def _packed_tile_vmem_bytes(l: int, d_model: int, num_heads: int, itemsize: int) -> int:
+    """VMEM footprint of one grid step of the BACKWARD kernel (the larger of
+    the pair): its blocks (qkv, d_qkv, out, d_out, lse), double-buffered by
+    the grid pipeline, the three float32 ``[L, 128]`` accumulators and the
+    float32 ``[L, L]`` intermediates one head keeps alive (scores, weights,
+    dP, dS and the two casts: priced as six), all as the chip tiles them."""
+    rows, lanes = pl.cdiv(l, 16) * 16, pl.cdiv(l, 128) * 128
+    blocks = rows * (3 + 3 + 1 + 1) * d_model * itemsize + rows * pl.cdiv(num_heads, 128) * 128 * 4
+    return 2 * blocks + 3 * rows * 128 * 4 + 6 * rows * lanes * 4
+
+
+def _head_masks(head_dim: int):
+    """Lane masks ``[1, 128]`` of the heads that share a 128-lane group (one
+    ``None`` where a head fills the group)."""
+    per = 128 // head_dim
+    if per == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    return [(lane >= j * head_dim) & (lane < (j + 1) * head_dim) for j in range(per)]
+
+
+def _keep(mask, x):
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
+
+
+def _cols(part: int, group: int, d_model: int) -> slice:
+    """Lanes of 128-lane group ``group`` of q (part 0), k (1) or v (2) in a
+    packed ``[L, 3·D]`` row."""
+    start = part * d_model + group * 128
+    return slice(start, start + 128)
+
+
+def _nt(a, b):  # a·bᵀ, float32 accumulation
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):  # aᵀ·b
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _self_attn_fwd_kernel(qkv_ref, o_ref, lse_ref, *, num_heads: int, head_dim: int):
+    d_model = num_heads * head_dim
+    scale = head_dim**-0.5
+    masks = _head_masks(head_dim)
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_heads), 1)
+    lse_all = jnp.zeros(lse_ref.shape[1:], jnp.float32)
+    for g in range(d_model // 128):
+        q, k, v = (qkv_ref[0, :, _cols(part, g, d_model)] for part in range(3))
+        out = None
+        for j, mask in enumerate(masks):
+            s = _nt(_keep(mask, q), k) * scale  # [L, L] float32
+            m = jnp.max(s, axis=-1, keepdims=True)
+            e = jnp.exp(s - m)
+            l = jnp.sum(e, axis=-1, keepdims=True)
+            p = (e * (1.0 / l)).astype(v.dtype)
+            o = _nn(p, v)  # [L, 128]: this head's lanes hold its output
+            out = o if out is None else jnp.where(mask, o, out)
+            lse_all = jnp.where(head_lane == g * len(masks) + j, m + jnp.log(l), lse_all)
+        o_ref[0, :, _cols(0, g, d_model)] = out.astype(o_ref.dtype)
+    lse_ref[0] = lse_all
+
+
+def _self_attn_bwd_kernel(
+    qkv_ref, o_ref, do_ref, lse_ref, dqkv_ref, *, num_heads: int, head_dim: int
+):
+    d_model = num_heads * head_dim
+    scale = head_dim**-0.5
+    masks = _head_masks(head_dim)
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_heads), 1)
+    lse_all = lse_ref[0]
+    for g in range(d_model // 128):
+        q, k, v = (qkv_ref[0, :, _cols(part, g, d_model)] for part in range(3))
+        do = do_ref[0, :, _cols(0, g, d_model)]
+        # rowsum(dO ∘ O) per head = rowsum(P ∘ dP): the softmax backward's row term
+        row = do.astype(jnp.float32) * o_ref[0, :, _cols(0, g, d_model)].astype(jnp.float32)
+        dq = dk = dv = jnp.zeros(q.shape, jnp.float32)
+        for j, mask in enumerate(masks):
+            head = head_lane == g * len(masks) + j
+            lse = jnp.sum(jnp.where(head, lse_all, 0.0), axis=-1, keepdims=True)
+            qj, doj = _keep(mask, q), _keep(mask, do)
+            p = jnp.exp(_nt(qj, k) * scale - lse)  # the forward's weights, float32
+            dp = _nt(doj, v)
+            delta = jnp.sum(_keep(mask, row), axis=-1, keepdims=True)
+            ds = (p * (dp - delta) * scale).astype(q.dtype)
+            dv = dv + _tn(p.astype(v.dtype), doj)
+            dq = dq + _nn(ds, _keep(mask, k))
+            dk = dk + _tn(ds, qj)
+        for part, grad in enumerate((dq, dk, dv)):
+            dqkv_ref[0, :, _cols(part, g, d_model)] = grad.astype(dqkv_ref.dtype)
+
+
+def _row_spec(l: int, width: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, l, width), lambda i: (i, 0, 0))
+
+
+# jitted so that the twelve blocks of a model share one traced kernel a shape
+@functools.partial(jax.jit, static_argnames=("num_heads", "interpret"))
+def _self_attn_fwd_call(qkv, *, num_heads: int, interpret: bool):
+    b, l, d3 = qkv.shape
+    d_model = d3 // 3
+    kernel = functools.partial(
+        _self_attn_fwd_kernel, num_heads=num_heads, head_dim=d_model // num_heads
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=(b,),
+        in_specs=[_row_spec(l, d3)],
+        out_specs=[_row_spec(l, d_model), _row_spec(l, num_heads)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, l, d_model), qkv.dtype),
+            jax.ShapeDtypeStruct((b, l, num_heads), jnp.float32),
+        ],
+        name="dtpu_attn_fwd",
+        interpret=interpret,
+    )(qkv)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "interpret"))
+def _self_attn_bwd_call(qkv, out, d_out, lse, *, num_heads: int, interpret: bool):
+    b, l, d3 = qkv.shape
+    d_model = d3 // 3
+    kernel = functools.partial(
+        _self_attn_bwd_kernel, num_heads=num_heads, head_dim=d_model // num_heads
+    )
+    row, heads = _row_spec(l, d_model), _row_spec(l, num_heads)
+    return pl.pallas_call(
+        kernel,
+        grid=(b,),
+        in_specs=[_row_spec(l, d3), row, row, heads],
+        out_specs=_row_spec(l, d3),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        name="dtpu_attn_bwd",
+        interpret=interpret,
+    )(qkv, out, d_out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def fused_self_attention(qkv, num_heads: int, interpret: bool = False):
+    """softmax(q·kᵀ/√hd)·v for every head of packed ``qkv [B, L, 3·H·hd]``
+    (columns ordered (3, H, hd), as the qkv Dense leaves them) → ``[B, L,
+    H·hd]``; differentiable, with one packed ``d_qkv``. Needs ``hd`` to
+    divide 128 and ``H·hd`` to be a multiple of 128 (`self_attention`
+    checks, and takes the einsums otherwise)."""
+    return _self_attn_fwd_call(qkv, num_heads=num_heads, interpret=interpret)[0]
+
+
+def _self_attn_fwd(qkv, num_heads, interpret):
+    out, lse = _self_attn_fwd_call(qkv, num_heads=num_heads, interpret=interpret)
+    return out, (qkv, out, lse)
+
+
+def _self_attn_bwd(num_heads, interpret, res, d_out):
+    qkv, out, lse = res
+    return (_self_attn_bwd_call(qkv, out, d_out, lse, num_heads=num_heads, interpret=interpret),)
+
+
+fused_self_attention.defvjp(_self_attn_fwd, _self_attn_bwd)
+
+
+def xla_self_attention(qkv, num_heads: int):
+    """The einsum route, as `models/vit.py` had it: four XLA ops whose
+    float32 ``[B, H, L, L]`` scores and weights go through HBM."""
+    b, l, d3 = qkv.shape
+    head_dim = d3 // 3 // num_heads
+    qkv = qkv.reshape(b, l, 3, num_heads, head_dim)
+    q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))  # [B,H,L,hd]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
+    w = jax.nn.softmax(s * head_dim**-0.5, axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", w.astype(v.dtype), v)
+    return out.transpose(0, 2, 1, 3).reshape(b, l, d3 // 3)
+
+
+#: `jax.monitoring` events of `self_attention`, one per call traced for a
+#: mesh; the journal's ``counters`` records carry them (obs/monitors.py)
+FUSED_CALLS_EVENT = "attn_fused_calls"
+XLA_CALLS_EVENT = "attn_xla_calls"
+
+
+def self_attention_fuses(
+    device_kind: str, l: int, num_heads: int, head_dim: int, itemsize: int
+) -> bool:
+    """The route of `self_attention`, from what it can observe: the fused
+    pair where the program is being traced for TPUs and one batch row's tile
+    fits VMEM in a geometry the kernels tile (heads that divide a 128-lane
+    group, a width of whole groups); the einsums everywhere else."""
+    d_model = num_heads * head_dim
+    return (
+        device_kind.upper().startswith("TPU")
+        and 128 % head_dim == 0
+        and d_model % 128 == 0
+        and _packed_tile_vmem_bytes(l, d_model, num_heads, itemsize)
+        <= DEFAULT_VMEM_BUDGET_MB * 2**20
+    )
+
+
+def self_attention(qkv, num_heads: int):
+    """Bias-free multi-head self-attention over packed ``qkv [B, L, 3·D]`` →
+    ``[B, L, D]``: `fused_self_attention` or `xla_self_attention`, chosen by
+    `self_attention_fuses` from the devices of the mesh in use (inside the
+    trainer's `shard_map`'d steps; the described chips of a compile-only
+    test count as what they describe) and the shapes. Traced outside any
+    mesh it is the einsums, uncounted: ``model.init``, shape inference, and
+    programs partitioned by named shardings alone (`serve/engine.py`), where
+    a Mosaic call could not be partitioned."""
+    mesh = jax.sharding.get_abstract_mesh()
+    device = None if mesh.empty else mesh.abstract_device
+    if device is None:
+        return xla_self_attention(qkv, num_heads)
+    _, l, d3 = qkv.shape
+    fused = self_attention_fuses(
+        device.device_kind, l, num_heads, d3 // 3 // num_heads, np.dtype(qkv.dtype).itemsize
+    )
+    jax.monitoring.record_event(FUSED_CALLS_EVENT if fused else XLA_CALLS_EVENT)
+    if fused:
+        return fused_self_attention(qkv, num_heads, pallas_interpret())
+    return xla_self_attention(qkv, num_heads)

@@ -19,8 +19,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from distribuuuu_tpu.ops import (
     fused_attention,
@@ -41,6 +42,9 @@ EPILOGUE_SHAPES = [
 ]
 # (B, heads, L, d): botnet50's MHSA at 224 px, and the 4x-token case
 ATTENTION_SHAPES = [(8, 4, 196, 128), (4, 4, 784, 64)]
+# (B, L, heads, hd) of the packed-qkv pair: vit_b16.train's own shape, MAE's
+# 50-token encoder and hd-32 decoder, vit_l16's width
+SELF_ATTENTION_SHAPES = [(128, 197, 12, 64), (8, 50, 12, 64), (8, 197, 16, 32), (4, 197, 16, 64)]
 
 
 @pytest.fixture(scope="module")
@@ -121,6 +125,79 @@ def test_fused_attention_compiles_for_v5e(one_chip, bnld, variant, grad):
         return kernel(*xs, interpret=False)
 
     assert "tpu_custom_call" in _compile(fn, args, one_chip, grad)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("blhd", SELF_ATTENTION_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_fused_self_attention_compiles_for_v5e(one_chip, blhd, grad):
+    from distribuuuu_tpu.ops.attention import fused_self_attention
+
+    b, l, heads, hd = blhd
+    text = _compile(
+        lambda qkv: fused_self_attention(qkv, heads, False),
+        [_struct((b, l, 3 * heads * hd), jnp.bfloat16)], one_chip, grad,
+    )
+    assert "dtpu_attn_fwd" in text and ("dtpu_attn_bwd" in text) is grad
+    # nothing of size L x L outside the two kernels, in either direction
+    assert f"{l},{l}]" not in text
+
+
+def test_vit_step_for_described_v5e_takes_the_fused_pair(topo, one_chip):
+    """The trainer's own step lowered for the described chip's mesh: every
+    block takes the fused pair (`attn_fused_calls` = depth, no
+    `attn_xla_calls`), and the kernels stand in the lowered step under their
+    names. Only lowered: the whole-step compile is `chip_smoke.py`'s."""
+    from distribuuuu_tpu import config, optim, trainer
+    from distribuuuu_tpu.models.vit import ViT
+    from distribuuuu_tpu.obs.monitors import MonitoringBridge
+    from distribuuuu_tpu.ops import attention
+    from distribuuuu_tpu.ops.interpret import set_pallas_interpret
+
+    depth, im = 3, 32
+    config.reset_cfg()
+    config.cfg.OPTIM.OPTIMIZER = "lamb"
+    # conftest asks for the interpreter; the chip's route does not
+    interpret = set_pallas_interpret(False)
+    try:
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        model = ViT(patch=16, dim=128, depth=depth, num_heads=2, mlp_dim=64, num_classes=4,
+                    dtype=jnp.bfloat16)
+        tx = optim.construct_optimizer()
+
+        def init(key):
+            params = model.init(key, jnp.zeros((1, im, im, 3), jnp.float32), train=False)["params"]
+            return trainer.TrainState(params=params, batch_stats={}, opt_state=tx.init(params))
+
+        replicated = NamedSharding(mesh, P())
+        rows = NamedSharding(mesh, P("data"))
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated),
+            jax.eval_shape(init, jax.random.PRNGKey(0)),
+        )
+        batch = {
+            "image": jax.ShapeDtypeStruct(
+                (2, im, im, 3), jnp.float32,
+                sharding=NamedSharding(mesh, P("data", None, None, None)),
+            ),
+            "label": jax.ShapeDtypeStruct((2,), jnp.int32, sharding=rows),
+            "weight": jax.ShapeDtypeStruct((2,), jnp.float32, sharding=rows),
+        }
+        step = trainer.make_train_step(model, tx, mesh, topk=2)
+        bridge = MonitoringBridge().install()
+        try:
+            text = step.lower(
+                state, batch, jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated),
+                jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated),
+            ).as_text()
+            counters = bridge.snapshot()["counters"]
+        finally:
+            bridge.close()
+    finally:
+        set_pallas_interpret(interpret)
+        config.reset_cfg()
+    assert counters.get(attention.FUSED_CALLS_EVENT) == depth
+    assert attention.XLA_CALLS_EVENT not in counters
+    assert "dtpu_attn_fwd" in text and "dtpu_attn_bwd" in text
 
 
 # ops/moe_kernel.py is refused by Mosaic at every shape its VMEM guard admits
