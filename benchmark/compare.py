@@ -43,17 +43,21 @@ LARGE_LEAF = 4096  # entries: from here on a leaf's norm averages enough entries
 
 
 def split_rows(batch: dict, shards: int) -> list[dict]:
-    rows = batch["label"].shape[0]
+    """Contiguous blocks of rows, every leaf split on its leading axis."""
+    (rows,) = {v.shape[0] for v in batch.values()}  # the leaves agree on the rows or it is no batch
     if rows % shards:
         raise ValueError(f"{rows} rows do not split over {shards} shards")
     n = rows // shards
     return [{k: v[i * n:(i + 1) * n] for k, v in batch.items()} for i in range(shards)]
 
 
-def reference_readings(ref, opt, hp: dict, key, batches, lrs, shards: int,
-                       precision: str = "f32", fault: str | None = None,
-                       num_classes: int = 1000, im_size: int = 224) -> dict:
+def reference_readings(ref, opt, settings: dict, key, batches, lrs, shards: int,
+                       precision: str = "f32", fault: str | None = None) -> dict:
     """Three steps of the plain reference; returns losses and per-leaf norms.
+
+    ``settings``: the keys merged into the program's ``cfg``; the reference reads its
+    sizes from them and the optimizer its hyperparameters (``OPTIM``). ``batches``:
+    the pool's first batches as they are, whatever leaves the input kind gives them.
 
     ``shards``: the rows each device of the cell sees are a contiguous block;
     statistics of a normalisation are per block, gradients and running
@@ -66,13 +70,12 @@ def reference_readings(ref, opt, hp: dict, key, batches, lrs, shards: int,
     import jax
     import jax.numpy as jnp
 
-    params = jax.jit(lambda k: ref.init(k, num_classes, im_size))(key)
-    stats = ref.init_stats(num_classes)
+    hp = settings["OPTIM"]
+    params = jax.jit(lambda k: ref.init(k, settings))(key)
+    stats = ref.init_stats(settings)
     p0, s0 = params, stats
     opt_state = opt.init(params)
-    grad_fn = jax.jit(
-        lambda p, s, x, y: jax.value_and_grad(ref.loss_fn, has_aux=True)(p, s, x, y, precision)
-    )
+    grad_fn = jax.jit(lambda p, s, block: jax.value_and_grad(ref.loss_fn, has_aux=True)(p, s, block, precision))
     update = jax.jit(lambda p, o, g, lr: opt.step(p, o, g, lr, hp))
     mean = jax.jit(lambda trees: jax.tree.map(lambda *xs: sum(xs) / len(xs), *trees))
     norms = jax.jit(lambda t: {k: jnp.linalg.norm(v.ravel()) for k, v in ref.compare_leaves(t).items()})
@@ -86,7 +89,7 @@ def reference_readings(ref, opt, hp: dict, key, batches, lrs, shards: int,
             blocks = [{k: v[: v.shape[0] // 2] for k, v in b.items()} for b in blocks]
         losses, grads, new_stats = [], [], []
         for block in blocks:
-            (loss, block_stats), g = grad_fn(params, stats, block["image"], block["label"])
+            (loss, block_stats), g = grad_fn(params, stats, block)
             losses.append(loss)
             grads.append(g)
             new_stats.append(block_stats)

@@ -3,7 +3,9 @@
 Patch embedding, 12 blocks of (qkv, scores, weighted values, projection,
 MLP in, MLP out), and the head on the class token. The two attention products
 have no weights: both operands are activations (``w`` is 0 and ``in`` holds
-both).
+both). The ``L x L`` scores (the first product's result) and weights (the
+second's operand) are ``internal``: a fused implementation never writes them,
+so they are no part of the work's least bytes (``roofline.py``).
 """
 
 from __future__ import annotations
@@ -27,9 +29,11 @@ def layers(settings: dict) -> list[dict]:
         p = f"blk{i}"
         out.append(_dense(f"{p}.qkv", tokens, DIM, 3 * DIM))
         out.append({"name": f"{p}.scores", "macs": HEADS * tokens * tokens * hd,
-                    "in": 2 * tokens * DIM, "out": HEADS * tokens * tokens, "w": 0})
+                    "in": 2 * tokens * DIM, "out": HEADS * tokens * tokens, "w": 0,
+                    "internal": HEADS * tokens * tokens})
         out.append({"name": f"{p}.values", "macs": HEADS * tokens * tokens * hd,
-                    "in": HEADS * tokens * tokens + tokens * DIM, "out": tokens * DIM, "w": 0})
+                    "in": HEADS * tokens * tokens + tokens * DIM, "out": tokens * DIM, "w": 0,
+                    "internal": HEADS * tokens * tokens})
         out.append(_dense(f"{p}.proj", tokens, DIM, DIM))
         out.append(_dense(f"{p}.fc1", tokens, DIM, MLP))
         out.append(_dense(f"{p}.fc2", tokens, MLP, DIM))

@@ -41,11 +41,11 @@ def memory_peak_bytes(devices) -> int:
 
 
 TRACE_START_SHARE = 0.35  # of the window, after which the trace starts
-# The device's tracer holds about 26 000 op events a device (9 steps of resnet50, 0.88 s); once it
-# is full the device stands still until the trace stops (PERF.md section 5). So the span that the
-# reduction reads starts as soon as ``start_trace`` returns and ends, with the trace, well before
-# that many events: the cell's file says after how long (``trace_seconds``), since it knows how
-# many ops its step runs. A cell that does not say gets the span that holds 6 steps of resnet50.
+# While the profiler runs the device stands still for some tenths of a second, from about 0.9 s after
+# ``start_trace`` returns (PERF.md section 5; the cause lies in the profiler and is not the number of
+# events). So the span that the reduction reads starts as soon as ``start_trace`` returns and ends,
+# with the trace, before that: the cell's file says after how long (``trace_seconds``), since it knows
+# how long its step is. A cell that does not say gets the span that holds 6 steps of resnet50.
 TRACE_SECONDS_SHARE, TRACE_SECONDS_DEFAULT = 0.3, 0.6
 
 
@@ -134,13 +134,12 @@ class Program:
         # the program has made. The optimizer state starts at zero either way.
         self.ref = files.load_module("reference", cell["config"])
         self.opt = files.load_module("reference", f"optim_{settings['OPTIM']['OPTIMIZER']}")
+        self.settings = settings
         self.hp = settings["OPTIM"]
-        self.num_classes = int(settings["MODEL"]["NUM_CLASSES"])
-        self.im_size = int(settings["TRAIN"]["IM_SIZE"])
         self.weights_key = seed_key(seed)
         replicated = NamedSharding(self.mesh, P())
-        ref, classes, size = self.ref, self.num_classes, self.im_size
-        make = lambda k: ref.to_program(ref.init(k, classes, size), ref.init_stats(classes))
+        ref = self.ref
+        make = lambda k: ref.to_program(ref.init(k, settings), ref.init_stats(settings))
         # twice in one call: the step donates its state, and the readings need the start
         weights = jax.jit(lambda k: (make(k), make(k)), out_shardings=replicated)
         (params, stats), (self.params0, self.stats0) = weights(self.weights_key)
@@ -202,8 +201,8 @@ def first_steps(program: Program, pool) -> dict:
     import jax.numpy as jnp
 
     opt, ref, hp = program.opt, program.ref, program.hp
-    names = list(ref.shapes(program.num_classes, program.im_size))
-    stat_names = list(ref.init_stats(program.num_classes))
+    names = list(ref.shapes(program.settings))
+    stat_names = list(ref.init_stats(program.settings))
 
     def leaf_norms(flat):
         return {k: jnp.linalg.norm(v.astype(jnp.float32).ravel()) for k, v in flat.items()}
@@ -242,9 +241,8 @@ def reference_for(program: Program, pool, shards: int, precision: str = "f32", f
 
     lrs = [schedule.lr_at_epoch(program.hp, e) for e in (0, 1, 1)]
     return compare.reference_readings(
-        program.ref, program.opt, program.hp, program.weights_key, pool[:FIRST_STEPS], lrs,
+        program.ref, program.opt, program.settings, program.weights_key, pool[:FIRST_STEPS], lrs,
         shards, precision=precision, fault=fault,
-        num_classes=program.num_classes, im_size=program.im_size,
     )
 
 
@@ -325,9 +323,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, rehearse: boo
     phase("program built", t_process_start)
     if int(program.mesh.devices.size) != cell["chips"]:
         raise RuntimeError(f"the mesh holds {program.mesh.devices.size} devices, the cell asks for {cell['chips']}")
-    pool = traffic.make_pool(
-        seed, cell["mix"]["pool_batches"], program.global_batch, program.im_size, program.num_classes
-    )
+    pool = traffic.make_pool(config["input"], seed, cell["mix"]["pool_batches"], program.global_batch, settings)
     phase("pool made", t_process_start)
     got = first_steps(program, pool)
     phase("first three steps done", t_process_start)
@@ -396,6 +392,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, rehearse: boo
         context = {
             "cell": cell, "settings": settings, "journal": journal, "window": result["window"],
             "trace": reduced, "classes": hlo.classify(hlo_text), "layers": layers,
+            "kernels": roofline.kernel_costs(hlo.kernel_calls(hlo_text)),  # a kernel with no file fails the run
             "device": result["device"], "rehearse": rehearse, "chips": cell["chips"],
             "batch_per_chip": program.global_batch // cell["chips"], "roofline": roofline,
         }

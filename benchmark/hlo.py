@@ -8,6 +8,10 @@ a substring of its name:
 - ``collective``: the instruction is, or contains, an all-reduce, all-gather,
   reduce-scatter, all-to-all or collective-permute (async halves included);
 - ``mxu``: it is, or contains, a ``convolution`` or a ``dot``;
+- ``kernel``: it is, or contains, a ``custom-call`` whose target is ``tpu_custom_call``: a
+  Mosaic kernel, whose body the text does not show. Its name is the ``name`` the program gave
+  its ``pallas_call`` (the component of ``op_name`` before ``/pallas_call``), and what it costs
+  is said by ``benchmark/kernels/<name>.py`` from the call's shapes (`kernel_calls`);
 - ``vector``: everything else that runs on the device.
 """
 
@@ -22,28 +26,41 @@ COLLECTIVE_OPCODES = frozenset({
     "collective-permute-start", "collective-permute-done", "collective-broadcast",
 })
 MXU_OPCODES = frozenset({"convolution", "dot"})
-CLASSES = ("mxu", "vector", "collective")
+KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+CLASSES = ("mxu", "kernel", "vector", "collective")
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _CALLEE = re.compile(r"(?:calls|to_apply|body|condition|branch_computations)=\{?%?([\w.\-]+(?:,\s*%?[\w.\-]+)*)\}?")
 _OPCODE = re.compile(r"\s*([a-z][\w\-]*)\(")
+_ARRAY = re.compile(r"\b([a-z]+\d*[a-z0-9]*)\[([\d,]*)\]")
+_KERNEL_NAME = re.compile(r'op_name="[^"]*?([^/"]+)/pallas_call[^"]*"')
+_OPERANDS = re.compile(r"\bcustom-call\(([^)]*)\)")
 
 
-def _opcode(rest: str) -> str | None:
-    """Opcode of an instruction, given the text after ``name = ``."""
-    if rest.startswith("("):  # tuple type: skip its balanced parentheses
+def _split_type(rest: str) -> tuple[str, str]:
+    """The result type of an instruction and what follows it, given the text after ``name = ``."""
+    if rest.startswith("("):  # tuple type: up to its balancing parenthesis
         depth = 0
         for i, ch in enumerate(rest):
             depth += ch == "("
             depth -= ch == ")"
             if depth == 0:
-                rest = rest[i + 1:]
-                break
-    else:  # array type with layout: no spaces inside
-        _, _, rest = rest.partition(" ")
-    m = _OPCODE.match(rest)
+                return rest[: i + 1], rest[i + 1:]
+        return rest, ""
+    head, _, tail = rest.partition(" ")  # array type with layout: no spaces inside
+    return head, tail
+
+
+def _opcode(rest: str) -> str | None:
+    """Opcode of an instruction, given the text after ``name = ``."""
+    m = _OPCODE.match(_split_type(rest)[1])
     return m.group(1) if m else None
+
+
+def arrays(type_text: str) -> list[tuple[str, tuple[int, ...]]]:
+    """``(dtype, shape)`` of every array in a type's text (``bf16[128,197,768]{2,1,0:T(8,128)(2,1)}``, or a tuple of such)."""
+    return [(dtype, tuple(int(d) for d in dims.split(",") if d)) for dtype, dims in _ARRAY.findall(type_text)]
 
 
 def parse(text: str) -> dict[str, tuple[str, tuple[str, ...], str]]:
@@ -70,9 +87,44 @@ def parse(text: str) -> dict[str, tuple[str, tuple[str, ...], str]]:
     return instructions
 
 
+def _kernel_lines(text: str) -> dict[str, str]:
+    """instruction name -> the text after ``name = ``, for the lines that name the Mosaic custom-call target."""
+    found = {}
+    for line in text.splitlines():
+        if KERNEL_TARGET in line:
+            m = _INSTRUCTION.match(line)
+            if m:
+                found[m.group(1)] = m.group(2)
+    return found
+
+
+def kernel_calls(text: str) -> dict[str, dict]:
+    """instruction name -> ``{"kernel", "operands", "results"}`` for every Mosaic kernel call of the text.
+
+    ``kernel`` is the name the program gave the kernel; ``operands`` and ``results`` are lists of
+    ``(dtype, shape)`` as the compiled step holds them: what ``benchmark/kernels/<kernel>.py``'s
+    ``cost`` prices the call from. A call that carries no name raises: nothing could price it.
+    """
+    calls = _kernel_lines(text)
+    if not calls:
+        return {}
+    types = {m.group(1): _split_type(m.group(2))[0]
+             for m in map(_INSTRUCTION.match, text.splitlines()) if m}
+    out = {}
+    for name, rest in calls.items():
+        named = _KERNEL_NAME.search(rest)
+        if named is None:
+            raise ValueError(f"the kernel call {name} carries no op_name ending in <name>/pallas_call: name its pallas_call")
+        operands = [o.split()[-1].lstrip("%") for o in _OPERANDS.search(rest).group(1).split(",") if o.strip()]
+        out[name] = {"kernel": named.group(1), "results": arrays(types[name]),
+                     "operands": [a for o in operands for a in arrays(types[o])]}
+    return out
+
+
 def classify(text: str) -> dict[str, str]:
-    """instruction name -> ``mxu`` | ``vector`` | ``collective``."""
+    """instruction name -> ``mxu`` | ``kernel`` | ``vector`` | ``collective``."""
     instructions = parse(text)
+    kernels = set(_kernel_lines(text))
     by_computation: dict[str, list[str]] = {}
     for name, (_, _, owner) in instructions.items():
         by_computation.setdefault(owner, []).append(name)
@@ -84,7 +136,7 @@ def classify(text: str) -> dict[str, str]:
             return memo[name]
         memo[name] = frozenset()  # guards a cycle, which HLO does not have
         opcode, callees, _ = instructions[name]
-        found = {opcode}
+        found = {"kernel" if name in kernels else opcode}
         for comp in callees:
             for inner in by_computation.get(comp, ()):
                 found |= contents(inner)
@@ -98,6 +150,8 @@ def classify(text: str) -> dict[str, str]:
             classes[name] = "collective"
         elif ops & MXU_OPCODES:
             classes[name] = "mxu"
+        elif "kernel" in ops:
+            classes[name] = "kernel"
         else:
             classes[name] = "vector"
     return classes

@@ -1,4 +1,4 @@
-"""Share of device busy time in ops that hold no convolution, dot or collective (trace + the step's HLO)."""
+"""Share of device busy time in ops that hold no convolution, dot, collective or Mosaic kernel (trace + the step's HLO)."""
 
 NAME = "vector_share_pct"
 UNIT = "%"

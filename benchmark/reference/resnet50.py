@@ -48,8 +48,9 @@ def _blocks():
             cin = 4 * planes
 
 
-def shapes(num_classes: int = 1000, im_size: int = 224) -> dict[str, tuple]:
-    """Flat name -> shape of every trainable leaf."""
+def shapes(settings: dict) -> dict[str, tuple]:
+    """Flat name -> shape of every trainable leaf (``settings``: the keys merged into the program's ``cfg``)."""
+    num_classes = int(settings["MODEL"]["NUM_CLASSES"])
     out: dict[str, tuple] = {"stem.conv": (7, 7, 3, 64), "stem.bn.scale": (64,), "stem.bn.bias": (64,)}
     for p, cin, planes, _, ds in _blocks():
         out[f"{p}.conv1"] = (1, 1, cin, planes)
@@ -67,11 +68,11 @@ def shapes(num_classes: int = 1000, im_size: int = 224) -> dict[str, tuple]:
     return out
 
 
-def init(key, num_classes: int = 1000, im_size: int = 224) -> dict[str, jax.Array]:
+def init(key, settings: dict) -> dict[str, jax.Array]:
     """Seeded weights: kaiming-normal fan-out convs, unit BN scale, zero
     biases, U(+-1/sqrt(fan_in)) classifier (the published initialisation)."""
     params = {}
-    for i, (name, shape) in enumerate(shapes(num_classes).items()):
+    for i, (name, shape) in enumerate(shapes(settings).items()):
         k = jax.random.fold_in(key, i)
         if len(shape) == 4:
             fan_out = shape[0] * shape[1] * shape[3]
@@ -86,10 +87,10 @@ def init(key, num_classes: int = 1000, im_size: int = 224) -> dict[str, jax.Arra
     return params
 
 
-def init_stats(num_classes: int = 1000) -> dict[str, jax.Array]:
+def init_stats(settings: dict) -> dict[str, jax.Array]:
     """Running statistics at their defined start: mean 0, variance 1."""
     stats = {}
-    for name, shape in shapes(num_classes).items():
+    for name, shape in shapes(settings).items():
         if name.endswith(".scale"):
             bn = name[: -len(".scale")]
             stats[f"{bn}.mean"] = jnp.zeros(shape, jnp.float32)
@@ -211,9 +212,11 @@ def forward(params, stats, images_u8, precision: str = "f32"):
     return logits, new_stats
 
 
-def loss_fn(params, stats, images_u8, labels, precision: str = "f32"):
-    """Mean softmax cross-entropy over the rows. Returns (loss, new stats)."""
-    logits, new_stats = forward(params, stats, images_u8, precision)
+def loss_fn(params, stats, batch, precision: str = "f32"):
+    """Mean softmax cross-entropy over the rows of ``batch``, a block of rows of the pool's dict
+    (input kind ``image``). Returns (loss, new stats)."""
+    logits, new_stats = forward(params, stats, batch["image"], precision)
+    labels = batch["label"]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
     return jnp.mean(nll), new_stats

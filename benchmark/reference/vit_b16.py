@@ -32,8 +32,10 @@ STD = (0.229, 0.224, 0.225)
 HI = lax.Precision.HIGHEST
 
 
-def shapes(num_classes: int = 1000, im_size: int = 224) -> dict[str, tuple]:
-    tokens = (im_size // PATCH) ** 2 + 1
+def shapes(settings: dict) -> dict[str, tuple]:
+    """Flat name -> shape of every trainable leaf (``settings``: the keys merged into the program's ``cfg``)."""
+    num_classes = int(settings["MODEL"]["NUM_CLASSES"])
+    tokens = (int(settings["TRAIN"]["IM_SIZE"]) // PATCH) ** 2 + 1
     out: dict[str, tuple] = {
         "patch.w": (PATCH, PATCH, 3, DIM), "patch.b": (DIM,),
         "cls": (1, 1, DIM), "pos": (1, tokens, DIM),
@@ -53,11 +55,11 @@ def shapes(num_classes: int = 1000, im_size: int = 224) -> dict[str, tuple]:
     return out
 
 
-def init(key, num_classes: int = 1000, im_size: int = 224) -> dict[str, jax.Array]:
+def init(key, settings: dict) -> dict[str, jax.Array]:
     """Seeded weights: truncated-normal(0.02) tables, xavier-uniform
     projections, unit LN scale, zero biases, N(0, 0.02) head (see above)."""
     params = {}
-    for i, (name, shape) in enumerate(shapes(num_classes, im_size).items()):
+    for i, (name, shape) in enumerate(shapes(settings).items()):
         k = jax.random.fold_in(key, i)
         if name in ("patch.w", "cls", "pos"):
             params[name] = 0.02 * jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32)
@@ -73,7 +75,7 @@ def init(key, num_classes: int = 1000, im_size: int = 224) -> dict[str, jax.Arra
     return params
 
 
-def init_stats(num_classes: int = 1000) -> dict:
+def init_stats(settings: dict) -> dict:
     return {}
 
 
@@ -191,8 +193,11 @@ def forward(params, stats, images_u8, precision: str = "f32"):
     return logits, {}
 
 
-def loss_fn(params, stats, images_u8, labels, precision: str = "f32"):
-    logits, new_stats = forward(params, stats, images_u8, precision)
+def loss_fn(params, stats, batch, precision: str = "f32"):
+    """Mean softmax cross-entropy over the rows of ``batch``, a block of rows of the pool's dict
+    (input kind ``image``). Returns (loss, new stats)."""
+    logits, new_stats = forward(params, stats, batch["image"], precision)
+    labels = batch["label"]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
     return jnp.mean(nll), new_stats

@@ -111,7 +111,7 @@ def scope_of(hlo_text: str) -> dict[str, tuple[str, str]]:
 
 def ms_per_step(trace, keep, device: str | None = None) -> float | None:
     """Median over the step's module events of the union time of the ops whose instruction ``keep``
-    accepts (as ``Trace.class_ms_per_step`` does for classes); nothing where no such op ran in a step."""
+    accepts (a class of ops, a phase, a block); nothing where no such op ran in a step."""
     device = device or trace.busiest()
     steps = trace.step_events(device)
     ops = xplane.union((s, e) for n, s, e in trace.devices[device][xplane.OPS_LINE] if keep(xplane.op_key(n)))

@@ -1,6 +1,6 @@
 """Drive one rehearsal run of a cell with the timed path broken underneath; print ``correct``.
 
-    python3 benchmark/tests/_drive_fault.py <workload> <unchanged|stats_unchanged|half_batch|no_exchange|none>
+    python3 benchmark/tests/_drive_fault.py <workload> <unchanged|stats_unchanged|half_batch|no_exchange|unpriced_kernel|none>
 
 Skips the harness's look for a chip (it is the CPU rehearsal) and drives the
 rest of a run: the program built as ever, its first three steps, a short
@@ -13,6 +13,14 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+
+
+UNPRICED_KERNEL = """
+%zz_computation (p: bf16[8,128]) -> bf16[8,128] {
+  %p = bf16[8,128]{1,0} parameter(0)
+  ROOT %zz_kernel.1 = bf16[8,128]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(M)/zz_unpriced_kernel/pallas_call"}, backend_config={"custom_call_config":{"body":"..."}}
+}
+"""
 
 
 def main(workload: str, fault: str) -> int:
@@ -52,7 +60,11 @@ def main(workload: str, fault: str) -> int:
 
     from benchmark import harness
 
-    result = harness.run_cell(workload, seed=41, seconds=1.0, trace=False, rehearse=True,
+    if fault == "unpriced_kernel":  # the CPU's step holds no kernel: put one into its text
+        lower = harness.Program.lower_step_text
+        harness.Program.lower_step_text = lambda self, batch_abs: lower(self, batch_abs) + UNPRICED_KERNEL
+
+    result = harness.run_cell(workload, seed=41, seconds=1.0, trace=fault == "unpriced_kernel", rehearse=True,
                               out_root=os.path.join(ROOT, "benchmark_out", "faults", fault), step_wrapper=wrapper)
     print(json.dumps({"correct": result["correct"], "compared": result["compared"]}))
     return 0

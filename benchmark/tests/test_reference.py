@@ -34,8 +34,8 @@ def one_step(config_name, tmp_path):
     seed = 3
     program = harness.Program(cell, config, settings, seed, str(tmp_path))
     ref, opt, hp = program.ref, program.opt, program.hp
-    names = list(ref.shapes(program.num_classes, program.im_size))
-    pool = traffic.make_pool(seed, 1, program.global_batch, program.im_size, program.num_classes)
+    names = list(ref.shapes(settings))
+    pool = traffic.make_pool(config["input"], seed, 1, program.global_batch, settings)
     params0 = jax.tree.map(np.array, program.params0)
     program.epoch(traffic.PoolLoader(pool, steps=1), epoch=0)
     state = jax.tree.map(np.array, program.state)
@@ -45,16 +45,15 @@ def one_step(config_name, tmp_path):
         "loss": loss,
         "grad": ref.from_program(opt.first_gradient(state.opt_state, params0, hp), names),
         "params": ref.from_program(state.params, names),
-        "stats": ref.from_program(state.batch_stats, list(ref.init_stats(program.num_classes))),
+        "stats": ref.from_program(state.batch_stats, list(ref.init_stats(settings))),
     }
     # the reference, from the seed
     from benchmark.reference import schedule
 
     key = program.weights_key
-    p = ref.init(key, program.num_classes, program.im_size)
-    s = ref.init_stats(program.num_classes)
-    batch = pool[0]
-    (ref_loss, new_stats), g = jax.value_and_grad(ref.loss_fn, has_aux=True)(p, s, batch["image"], batch["label"])
+    p = ref.init(key, settings)
+    s = ref.init_stats(settings)
+    (ref_loss, new_stats), g = jax.value_and_grad(ref.loss_fn, has_aux=True)(p, s, pool[0])
     new_p, _ = opt.step(p, opt.init(p), g, schedule.lr_at_epoch(hp, 0), hp)
     want = {"loss": float(ref_loss), "grad": g, "params": new_p, "stats": new_stats, "params0": p}
     return ref, got, want
@@ -125,13 +124,12 @@ def test_control_precision_is_told_from_the_reference():
     settings = harness.settings_for(cell, config, rehearse=True)
     ref = files.load_module("reference", "resnet50")
     opt = files.load_module("reference", "optim_sgd")
-    hp = settings["OPTIM"]
-    pool = traffic.make_pool(5, 3, 8, 64, 1000)
-    lrs = [schedule.lr_at_epoch(hp, e) for e in (0, 1, 1)]
+    pool = traffic.make_pool(config["input"], 5, 3, 8, settings)
+    lrs = [schedule.lr_at_epoch(settings["OPTIM"], e) for e in (0, 1, 1)]
     key = harness.seed_key(5)
-    want = compare.reference_readings(ref, opt, hp, key, pool, lrs, 1, im_size=64)
-    again = compare.reference_readings(ref, opt, hp, key, pool, lrs, 1, im_size=64)
-    control = compare.reference_readings(ref, opt, hp, key, pool, lrs, 1, precision="fp8", im_size=64)
+    want = compare.reference_readings(ref, opt, settings, key, pool, lrs, 1)
+    again = compare.reference_readings(ref, opt, settings, key, pool, lrs, 1)
+    control = compare.reference_readings(ref, opt, settings, key, pool, lrs, 1, precision="fp8")
     same = compare.gaps(again, want)["numbers"]
     off = compare.gaps(control, want)["numbers"]
     assert all(v == 0.0 for v in same.values())  # the same seed gives the same readings

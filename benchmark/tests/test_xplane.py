@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from benchmark import hlo, xplane
+from benchmark import hlo, scopes, xplane
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OPS, MODS = xplane.OPS_LINE, xplane.MODULES_LINE
@@ -33,7 +33,7 @@ def test_step_time_classes_and_exposed_collectives():
     trace = make(ops, modules)
     assert trace.step_device_ms() == pytest.approx(800 / 1e6)  # union of 0..800 per step
     assert trace.step_period_ms() == pytest.approx(1000 / 1e6)
-    assert trace.class_ms_per_step(classes, "mxu") == pytest.approx(400 / 1e6)
+    assert scopes.ms_per_step(trace, lambda op: classes.get(op) == "mxu") == pytest.approx(400 / 1e6)
     # the collective runs 500..800, compute until 600: 200 ns a step are exposed
     assert trace.exposed_collective_ns(classes) == 600
     share = trace.busy_ns("/device:TPU:0", classes, only="vector") / trace.busy_ns("/device:TPU:0")
@@ -85,7 +85,7 @@ def test_recorded_fixture_reduces_to_its_recorded_numbers():
     assert len(trace.step_events(device)) == want["steps"]
     assert trace.step_device_ms() == pytest.approx(want["step_device_ms"], rel=1e-9)
     assert trace.step_period_ms() == pytest.approx(want["step_period_ms"], rel=1e-9)
-    assert trace.class_ms_per_step(classes, "mxu") == pytest.approx(want["mxu_ms_per_step"], rel=1e-9)
+    assert scopes.ms_per_step(trace, lambda op: classes.get(op) == "mxu") == pytest.approx(want["mxu_ms_per_step"], rel=1e-9)
     # on this chip the ops of one core never overlap, so here the union equals the sum; the made
     # intervals above are where a sum in a union's place fails
     assert want["steps"] == 2 and 90 < want["step_device_ms"] < 105

@@ -93,12 +93,11 @@ def main(argv=None) -> int:
             program = types.SimpleNamespace(
                 ref=files.load_module("reference", cell["config"]),
                 opt=files.load_module("reference", f"optim_{optim['OPTIMIZER']}"), hp=optim,
-                weights_key=harness.seed_key(seed), num_classes=int(settings["MODEL"]["NUM_CLASSES"]),
-                im_size=int(train["IM_SIZE"]),
+                weights_key=harness.seed_key(seed), settings=settings,
                 global_batch=train["BATCH_SIZE"] * train.get("ACCUM_STEPS", 1) * cell["chips"])
         else:
             program = harness.Program(cell, config, settings, seed, out_dir)
-        pool = traffic.make_pool(seed, compare.STEPS, program.global_batch, program.im_size, program.num_classes)
+        pool = traffic.make_pool(config["input"], seed, compare.STEPS, program.global_batch, settings)
         if not args.reference_only:
             got = harness.first_steps(program, pool)
             program.end_run()

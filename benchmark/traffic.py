@@ -3,8 +3,9 @@
 A traffic mix is a file of parameters, ``benchmark/mixes/<traffic>.json``, found
 by the ``traffic`` name of the cell: ``pool_batches`` and ``input_mode`` (the
 per-chip batch comes from the configuration). The pool is made once from
-``--seed`` in set-up: u8 images and labels drawn uniformly over the classes,
-every row different. ``fresh`` cycles the pool so that each step hands
+``--seed`` in set-up by the configuration's input kind, ``benchmark/inputs/<kind>.py``
+(``make_pool``): a list of host batches, each a dict of arrays with the rows
+leading, every row different. ``fresh`` cycles the pool so that each step hands
 ``prefetch_to_device`` a different host batch with no replay marker, and every
 step ships its bytes over the link as a real epoch does. ``replay`` hands it one
 batch of the pool again and again under the loader's replay marker, so that it
@@ -15,21 +16,19 @@ from __future__ import annotations
 
 import time
 
-import numpy as np
+from benchmark import files
 
 INPUT_MODES = ("fresh", "replay")
 UNBOUNDED = 10**9  # an epoch "length" no window reaches
 
 
-def make_pool(seed: int, pool_batches: int, global_batch: int, im_size: int, num_classes: int) -> list[dict]:
-    rng = np.random.default_rng(seed)
-    pool = []
-    for _ in range(pool_batches):
-        pool.append({
-            "image": rng.integers(0, 256, (global_batch, im_size, im_size, 3), dtype=np.uint8),
-            "label": rng.integers(0, num_classes, global_batch).astype(np.int32),
-            "weight": np.ones((global_batch,), np.float32),
-        })
+def make_pool(kind: str, seed: int, pool_batches: int, global_batch: int, settings: dict) -> list[dict]:
+    """The pool of the configuration's input kind, checked: every leaf of every batch leads with the rows."""
+    pool = files.load_module("inputs", kind).make_pool(seed, pool_batches, global_batch, settings)
+    for batch in pool:
+        for name, leaf in batch.items():
+            if leaf.shape[:1] != (global_batch,):
+                raise ValueError(f"input kind {kind!r}: leaf {name!r} has shape {leaf.shape}, not {global_batch} rows leading")
     return pool
 
 

@@ -199,16 +199,6 @@ class Trace:
             return None
         return (steps[-1][1] - steps[0][1]) / (len(steps) - 1) / 1e6
 
-    def class_ms_per_step(self, classes: dict, only: str, device: str | None = None) -> float | None:
-        """Median over steps of the union time of one op class inside the step's module event."""
-        device = device or self.busiest()
-        steps = self.step_events(device)
-        if not steps:
-            return None
-        ops = union((s, e) for n, s, e in self.devices[device][OPS_LINE]
-                    if classes.get(op_key(n), "vector") == only)
-        return statistics.median(length(clip(ops, s, e)) for _, s, e in steps) / 1e6
-
     # -- classes ------------------------------------------------------------
 
     def class_ns(self, classes: dict, device: str | None = None) -> dict[str, int]:
