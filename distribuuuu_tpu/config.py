@@ -100,9 +100,11 @@ _C.TRAIN.PIN_MEMORY = True  # kept for CLI compat; maps to device prefetch
 _C.TRAIN.PRINT_FREQ = 30
 _C.TRAIN.TOPK = 5
 # Training task: "classify" (softmax-CE on labels — the reference's only
-# task) or "mae" (masked-autoencoder pixel reconstruction, models/mae.py:
+# task), "mae" (masked-autoencoder pixel reconstruction, models/mae.py:
 # patch-masking in the input path, pixel MSE on masked patches; labels ride
-# along unused). "mae" is the large-L workload that exercises MESH.SEQ.
+# along unused; the large-L workload that exercises MESH.SEQ) or "lm"
+# (next-token cross-entropy over rows of LM.SEQ_LEN + 1 token ids, batch key
+# ``tokens``; the model is sized by the LM section).
 _C.TRAIN.TASK = "classify"
 # TPU additions
 _C.TRAIN.PREFETCH = 2  # batches prefetched to device HBM ahead of compute
@@ -148,10 +150,12 @@ _C.CUDNN.BENCHMARK = True
 _C.CUDNN.DETERMINISTIC = False
 
 _C.OPTIM = CN()
-# TPU addition: 'sgd' (reference-exact default) or 'lamb' (layerwise-adaptive
+# TPU addition: 'sgd' (reference-exact default), 'lamb' (layerwise-adaptive
 # large-batch training — the standard recipe beyond the linear-scaling
-# envelope the reference's SGD recipes stop at). BETA1/BETA2/EPS apply to
-# lamb only.
+# envelope the reference's SGD recipes stop at; BETA1/BETA2/EPS apply to it
+# only) or 'adafactor' (Shazeer & Stern 2018: a factored second moment and no
+# first, so the state is rows + columns and not two copies of the model — what
+# a model takes whose parameters fill most of a chip).
 _C.OPTIM.OPTIMIZER = "sgd"
 _C.OPTIM.BETA1 = 0.9
 _C.OPTIM.BETA2 = 0.999
@@ -169,6 +173,39 @@ _C.OPTIM.NESTEROV = True
 _C.OPTIM.WARMUP_FACTOR = 0.1
 _C.OPTIM.WARMUP_EPOCHS = 5
 _C.OPTIM.WEIGHT_DECAY = 5e-5
+
+# Token-sequence model (TRAIN.TASK "lm"): the section reaches the arch's
+# factory key by key in lower case; the factory lives in the module that
+# MODEL.MODULE names (models/nemotron_h.py takes these). Widths are the
+# model's; the *_HELD counts, KV/group counts and VOCAB are what this chip
+# holds of a layer shared over chips (all of it by default: the published
+# counts of config/nemotron3_super.yaml's source are in that file's comments).
+_C.LM = CN()
+_C.LM.SEQ_LEN = 8192        # tokens a row (the batch ships SEQ_LEN + 1: inputs and labels are one leaf shifted)
+_C.LM.VOCAB = 16384         # rows of embedding and head held; ids are drawn from 0 ... VOCAB - 1
+_C.LM.PATTERN = "EMEMEMEMEM*"  # one letter a layer: M Mamba-2, * attention, E latent experts
+_C.LM.LAYERS_TOTAL = 88     # depth of the whole model (scales the residual projections' init)
+_C.LM.DIM = 4096
+_C.LM.MAMBA_HEADS = 16
+_C.LM.MAMBA_HEAD_DIM = 64
+_C.LM.MAMBA_GROUPS = 1
+_C.LM.SSM_STATE = 128
+_C.LM.CONV_KERNEL = 4
+_C.LM.CHUNK = 128
+_C.LM.ATTN_HEADS = 4
+_C.LM.KV_HEADS = 1
+_C.LM.HEAD_DIM = 128
+_C.LM.EXPERTS = 512         # the router's outputs
+_C.LM.EXPERTS_HELD = 8      # experts EXPERT_FIRST ... EXPERT_FIRST + EXPERTS_HELD - 1 live here
+_C.LM.EXPERT_FIRST = 0
+_C.LM.TOP_K = 22
+_C.LM.LATENT = 1024
+_C.LM.EXPERT_WIDTH = 2688
+_C.LM.SHARED_WIDTH = 5376
+_C.LM.ROUTED_SCALE = 5.0
+_C.LM.NORM_EPS = 1e-5
+# tokens a block of the loss: float32 logits exist for one block at a time
+_C.LM.LOSS_BLOCK = 2048
 
 # Device mesh (TPU addition). The reference's only axis is data parallelism;
 # axes are declared here so multi-axis meshes (see parallel/) slot in.

@@ -427,10 +427,10 @@ class DummyLoader:
     the loop measures pure compute, like the reference's in-memory random
     dataset (`utils.py:109-118`)."""
 
-    def __init__(self, host_batch: int, im_size: int, num_batches: int):
+    def __init__(self, host_batch: int, im_size: int, num_batches: int, batch: dict | None = None):
         self.num_batches = max(1, num_batches)
         self.start_batch = 0
-        self._batch = DummyDataset(im_size=im_size).sample_batch(host_batch)
+        self._batch = batch if batch is not None else DummyDataset(im_size=im_size).sample_batch(host_batch)
         self._batch[REPLAY_CONST] = True
 
     def set_epoch(self, epoch: int, start_batch: int = 0) -> None:
@@ -442,6 +442,24 @@ class DummyLoader:
     def __iter__(self):
         for _ in range(self.start_batch, self.num_batches):
             yield self._batch
+
+
+def _dummy_tokens(host_batch: int) -> dict | None:
+    """TRAIN.TASK "lm" under DUMMY_INPUT: one batch of rows of LM.SEQ_LEN + 1
+    ids uniform over the held vocabulary slice (inputs and labels are one leaf
+    shifted); None for the image tasks, which keep `DummyDataset`'s batch."""
+    if cfg.TRAIN.TASK != "lm":
+        return None
+    rng = np.random.default_rng(0)
+    return {"tokens": rng.integers(0, cfg.LM.VOCAB, (host_batch, cfg.LM.SEQ_LEN + 1)).astype(np.int32)}
+
+
+def _no_token_dataset() -> None:
+    if cfg.TRAIN.TASK == "lm":
+        raise ValueError(
+            "TRAIN.TASK 'lm' has no dataset reader yet: set MODEL.DUMMY_INPUT True "
+            "(synthetic token rows), or feed train_epoch a loader of {'tokens': int32[rows, LM.SEQ_LEN + 1]}"
+        )
 
 
 def _topology(mesh=None):
@@ -521,7 +539,9 @@ def construct_train_loader(mesh=None):
             cfg.TRAIN.IM_SIZE,
             num_batches=cfg.TRAIN.DUMMY_EPOCH_SAMPLES
             // max(1, step_batch * global_dev),
+            batch=_dummy_tokens(host_batch),
         )
+    _no_token_dataset()
     root = os.path.join(cfg.TRAIN.DATASET, cfg.TRAIN.SPLIT)
     service = _service_loader(
         root, train=True, host_batch=host_batch, im_size=cfg.TRAIN.IM_SIZE,
@@ -560,7 +580,9 @@ def construct_val_loader(mesh=None):
             cfg.TEST.CROP_SIZE,
             num_batches=cfg.TRAIN.DUMMY_EPOCH_SAMPLES
             // max(1, cfg.TEST.BATCH_SIZE * global_dev),
+            batch=_dummy_tokens(host_batch),
         )
+    _no_token_dataset()
     # Reference quirk kept for migration compat: its val loader reads
     # TRAIN.DATASET + TEST.SPLIT and TEST.DATASET is unused (`utils.py:157`),
     # so reference users only ever set TRAIN.DATASET. Honor TEST.DATASET only
@@ -619,15 +641,19 @@ def prefetch_to_device(iterator, mesh, prefetch: int = 2):
     # committed layout must match the step's in_specs or every batch pays a
     # reshard collective at step entry.
     bx = batch_axes(mesh)
-    img_sharding = NamedSharding(mesh, P(bx, None, None, None))
-    vec_sharding = NamedSharding(mesh, P(bx))
+    shardings: dict[int, NamedSharding] = {}  # by rank: rows over the batch axes, the rest whole
 
     def to_device(batch):
-        return {
-            "image": jax.make_array_from_process_local_data(img_sharding, batch["image"]),
-            "label": jax.make_array_from_process_local_data(vec_sharding, batch["label"]),
-            "weight": jax.make_array_from_process_local_data(vec_sharding, batch["weight"]),
-        }
+        # every array leaf the batch holds (images, labels and weights; a
+        # token batch's ids), the replay marker aside
+        out = {}
+        for name, leaf in batch.items():
+            if name == REPLAY_CONST:
+                continue
+            if leaf.ndim not in shardings:
+                shardings[leaf.ndim] = NamedSharding(mesh, P(bx, *([None] * (leaf.ndim - 1))))
+            out[name] = jax.make_array_from_process_local_data(shardings[leaf.ndim], leaf)
+        return out
 
     done = object()
     # The in-flight bound: the worker takes a ticket BEFORE starting each

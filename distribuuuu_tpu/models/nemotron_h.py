@@ -1,0 +1,305 @@
+"""Hybrid Mamba-2 / attention / latent-expert language model (the nemotron_h family).
+
+A stack of pre-norm residual layers, one mixer each, chosen by a pattern
+string: ``M`` a Mamba-2 mixer, ``*`` causal grouped-query attention, ``E`` a
+latent mixture of experts with one shared expert. ``h <- h + Mixer(RMSNorm(h))``
+with the residual stream in the compute dtype; token embedding in, final
+RMSNorm and an untied head out. No bias anywhere but the Mamba convolution's,
+no position embedding (the Mamba layers carry order).
+
+Every count a chip may hold its share of is a size (`Sizes`): ``mamba_heads``
+with their ``mamba_groups`` of B/C, ``attn_heads`` with ``kv_heads``, the
+experts ``expert_first … expert_first + experts_held - 1`` of ``experts``, and
+the ``vocab`` rows of embedding and head. With the published counts it is the
+whole layer; with a share it computes that chip's partial sum of ``out_proj``,
+``o`` and of its experts' mixture (router, latent projections and shared
+expert are whole on every chip), which is what goes on to the next layer on
+one chip. Widths (``dim``, head sizes, state, latent, expert widths, the
+router's ``experts`` outputs and ``top_k``) are never a share.
+
+The parameters are one flat dict (`param_shapes`), the mixers pure functions
+of their layer's leaves. Where the pattern repeats a unit (``EMEMEMEMEM*`` is
+five times ``EM``, then ``*``), the repeats' parameters are one leaf with the
+repeats leading (``U<j>_<leaf> [repeats, ...]`` for the unit's layer ``j``) and
+run as one `lax.scan`, so the compiler sees a unit once: a third of the
+program and half of its compile time, and no copy of a parameter is made to
+stack it. The layers after the repeats are ``L<i>_<leaf>``; beside them
+``embed``, ``head``, ``norm_f``.
+
+``__call__`` returns the final-normed hidden states and the routing counters;
+`head_logits` maps hidden states to logits, so that a loss can take the
+vocabulary in blocks of tokens (trainer._forward_loss_lm).
+
+Float32: parameters, RMSNorm statistics, the router (scores, top-k, weights),
+softmax, the scan's decays and state (ops/ssm.py). Matrix products and the
+residual stream: ``dtype``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from distribuuuu_tpu.models.registry import register_model
+from distribuuuu_tpu.obs.trace import step_scope
+from distribuuuu_tpu.ops.attention import self_attention
+from distribuuuu_tpu.ops.ssm import ssd_scan
+from distribuuuu_tpu.parallel.moe import held_experts, round_rows_for, sigmoid_topk_route
+
+F32 = jnp.float32
+#: projections back into the residual stream: their init is scaled by 1/sqrt(2·layers_total)
+RESIDUAL_OUT = ("out_proj", "o", "w2", "shared2", "up")
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """Every size of the model; the counts a chip may hold its share of are the ones held here."""
+
+    pattern: str           # one letter a layer: M, * or E
+    vocab: int             # rows of embedding and head held (a slice of the vocabulary)
+    dim: int
+    layers_total: int      # depth of the whole model: the residual projections' init is scaled by it
+    mamba_heads: int       # held, with their mamba_groups of B/C
+    mamba_head_dim: int
+    mamba_groups: int
+    ssm_state: int
+    conv_kernel: int
+    chunk: int
+    attn_heads: int        # held, with their kv_heads
+    kv_heads: int
+    head_dim: int
+    experts: int           # the router's outputs: all experts of the layer
+    experts_held: int      # experts expert_first ... expert_first + experts_held - 1 live here
+    expert_first: int
+    top_k: int
+    latent: int
+    expert_width: int
+    shared_width: int
+    routed_scale: float
+    eps: float = 1e-5
+
+
+def layer_shapes(kind: str, s: Sizes) -> dict[str, tuple]:
+    """Leaf -> shape of one layer's parameters (its ``norm`` and its mixer's)."""
+    d = s.dim
+    if kind == "M":
+        inner, bc = s.mamba_heads * s.mamba_head_dim, s.mamba_groups * s.ssm_state
+        mixer = {"in_proj": (d, 2 * inner + 2 * bc + s.mamba_heads),  # z | x B C | dt
+                 "conv_w": (s.conv_kernel, inner + 2 * bc), "conv_b": (inner + 2 * bc,),
+                 "dt_bias": (s.mamba_heads,), "a_log": (s.mamba_heads,), "d": (s.mamba_heads,),
+                 "gnorm": (inner,), "out_proj": (inner, d)}
+    elif kind == "*":
+        q, kv = s.attn_heads * s.head_dim, s.kv_heads * s.head_dim
+        mixer = {"q": (d, q), "k": (d, kv), "v": (d, kv), "o": (q, d)}
+    elif kind == "E":
+        mixer = {"router": (d, s.experts), "down": (d, s.latent),
+                 "w1": (s.experts_held, s.latent, s.expert_width),
+                 "w2": (s.experts_held, s.expert_width, s.latent),
+                 "up": (s.latent, d), "shared1": (d, s.shared_width), "shared2": (s.shared_width, d)}
+    else:
+        raise ValueError(f"unknown layer kind {kind!r} in pattern {s.pattern!r}: one of M, *, E")
+    return {"norm": (d,), **mixer}
+
+
+def layer_prefixes(s: Sizes) -> list[tuple[str, str, int]]:
+    """``(prefix, kind, repeats)`` of every group of leaves: ``U<j>`` for layer ``j`` of the repeated unit
+    (its leaves lead with the repeats), ``L<i>`` for each layer after the repeats (``repeats`` 0: no such axis)."""
+    unit, repeats = repeated_unit(s.pattern)
+    scanned = unit * repeats if repeats > 1 else 0
+    return ([(f"U{j}", s.pattern[j], repeats) for j in range(unit if scanned else 0)]
+            + [(f"L{i}", s.pattern[i], 0) for i in range(scanned, len(s.pattern))])
+
+
+def param_shapes(s: Sizes) -> dict[str, tuple]:
+    out = {"embed": (s.vocab, s.dim)}
+    for prefix, kind, repeats in layer_prefixes(s):
+        lead = (repeats,) if repeats else ()
+        out.update({f"{prefix}_{leaf}": lead + shape for leaf, shape in layer_shapes(kind, s).items()})
+    out.update({"norm_f": (s.dim,), "head": (s.dim, s.vocab)})
+    return out
+
+
+def _initializer(name: str, s: Sizes):
+    leaf = name.split("_", 1)[-1] if name[0] in "LU" and name[1].isdigit() else name
+    if leaf in ("norm", "norm_f", "gnorm", "d"):
+        return nn.initializers.ones
+    if leaf == "a_log":
+        return lambda key, shape, dtype=F32: jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+    if leaf == "dt_bias":  # the inverse softplus of a log-uniform step in [1e-3, 0.1], floored at 1e-4
+        def dt_bias(key, shape, dtype=F32):
+            dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3), math.log(0.1)))
+            dt = jnp.maximum(dt, 1e-4)
+            return dt + jnp.log(-jnp.expm1(-dt))
+
+        return dt_bias
+    if leaf in ("conv_w", "conv_b"):  # a depthwise conv1d's default: fan-in is the kernel alone
+        bound = s.conv_kernel ** -0.5
+        return lambda key, shape, dtype=F32: jax.random.uniform(key, shape, dtype, -bound, bound)
+    return nn.initializers.normal(0.02 / math.sqrt(2 * s.layers_total) if leaf in RESIDUAL_OUT else 0.02)
+
+
+def repeated_unit(pattern: str) -> tuple[int, int]:
+    """``(unit length, repeats)`` of the prefix that `NemotronH` scans: the unit and count, at least two,
+    that cover most of the pattern from its start; ``(len, 1)`` where nothing repeats."""
+    best = (len(pattern), 1)
+    covered = 0
+    for k in range(1, len(pattern) // 2 + 1):
+        r = 1
+        while pattern[r * k:(r + 1) * k] == pattern[:k]:
+            r += 1
+        if r >= 2 and k * r > covered:
+            best, covered = (k, r), k * r
+    return best
+
+
+# ---------------------------------------------------------------------------
+# the mixers: pure functions of one layer's leaves
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, scale, eps: float, groups: int = 1):
+    """``x / sqrt(mean(x²) + eps) · scale`` in float32, the mean over each of ``groups`` slices of the width."""
+    x = x.astype(F32)
+    grouped = x.reshape(*x.shape[:-1], groups, x.shape[-1] // groups)
+    grouped = grouped * lax.rsqrt(jnp.mean(jnp.square(grouped), axis=-1, keepdims=True) + eps)
+    return grouped.reshape(x.shape) * scale.astype(F32)
+
+
+def _mm(x, kernel):
+    """``x @ kernel``, operands in ``x.dtype``, float32 out."""
+    return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=F32)
+
+
+def mamba_mixer(p: dict, u, s: Sizes):
+    b, l, _ = u.shape
+    inner, bc = s.mamba_heads * s.mamba_head_dim, s.mamba_groups * s.ssm_state
+    z, xbc, dt = jnp.split(_mm(u, p["in_proj"]), (inner, 2 * inner + 2 * bc), axis=-1)
+    # causal depthwise convolution over time, then silu
+    padded = jnp.pad(xbc, ((0, 0), (s.conv_kernel - 1, 0), (0, 0)))
+    xbc = p["conv_b"] + sum(p["conv_w"][j] * padded[:, j:j + l] for j in range(s.conv_kernel))
+    x, bmat, cmat = jnp.split(jax.nn.silu(xbc).astype(u.dtype), (inner, inner + bc), axis=-1)
+    y = ssd_scan(
+        x.reshape(b, l, s.mamba_heads, s.mamba_head_dim), jax.nn.softplus(dt + p["dt_bias"]), -jnp.exp(p["a_log"]),
+        bmat.reshape(b, l, s.mamba_groups, s.ssm_state), cmat.reshape(b, l, s.mamba_groups, s.ssm_state),
+        p["d"], s.chunk,
+    ).reshape(b, l, inner)
+    y = rms_norm(y.astype(F32) * jax.nn.silu(z), p["gnorm"], s.eps, groups=s.mamba_groups)
+    return _mm(y.astype(u.dtype), p["out_proj"])
+
+
+def attention_mixer(p: dict, u, s: Sizes):
+    qkv = jnp.concatenate([_mm(u, p[name]).astype(u.dtype) for name in "qkv"], axis=-1)
+    return _mm(self_attention(qkv, s.attn_heads, kv_heads=s.kv_heads, causal=True), p["o"])
+
+
+def moe_mixer(p: dict, b_corr, u32, s: Sizes, dtype):
+    """``u32``: the normed stream in float32, which the router reads as it is. Returns the mixture and the
+    held experts' loads ``[experts_held]``."""
+    b, l, dim = u32.shape
+    u32 = u32.reshape(b * l, dim)
+    u = u32.astype(dtype)
+    with step_scope("moe_route"):
+        logits = jnp.dot(u32, p["router"], precision=lax.Precision.HIGHEST)
+        idx, weights = sigmoid_topk_route(logits, s.top_k, b_corr, s.routed_scale)
+    latent = _mm(u, p["down"]).astype(dtype)
+    rows = round_rows_for(b * l, s.top_k, s.experts, s.experts_held)
+    mixed, counts = held_experts(latent, idx, weights, p["w1"], p["w2"], s.expert_first, rows)
+    shared = _mm(jnp.square(jax.nn.relu(_mm(u, p["shared1"]))).astype(dtype), p["shared2"])
+    return (_mm(mixed.astype(dtype), p["up"]) + shared).reshape(b, l, dim), counts
+
+
+def layer(kind: str, p: dict, b_corr, h, s: Sizes):
+    """``h + Mixer(RMSNorm(h))`` with the layer's own leaves; also an expert layer's loads, else None."""
+    u = rms_norm(h, p["norm"], s.eps)
+    counts = None
+    if kind == "M":
+        out = mamba_mixer(p, u.astype(h.dtype), s)
+    elif kind == "*":
+        out = attention_mixer(p, u.astype(h.dtype), s)
+    else:
+        out, counts = moe_mixer(p, b_corr, u, s, h.dtype)
+    return h + out.astype(h.dtype), counts
+
+
+class NemotronH(nn.Module):
+    sizes: Sizes
+    dtype: Any = jnp.bfloat16
+    remat: bool = False
+
+    def dummy_input(self, size: int):
+        """What `trainer.create_train_state` initialises with: parameter shapes do not depend on the length."""
+        del size
+        return jnp.zeros((1, 8), jnp.int32)
+
+    def setup(self):
+        s = self.sizes
+        self.p = {name: self.param(name, _initializer(name, s), shape, F32)
+                  for name, shape in param_shapes(s).items()}
+        # a buffer of the checkpoint, not a parameter: its update rule is the recipe's, not the model's
+        self.b_corr = {
+            prefix: self.variable("batch_stats", f"{prefix}_b_corr", jnp.zeros,
+                                  ((repeats,) if repeats else ()) + (s.experts,), F32)
+            for prefix, kind, repeats in layer_prefixes(s) if kind == "E"
+        }
+
+    def head_logits(self, hidden):
+        """Float32 logits over the held vocabulary slice of final-normed hidden states ``[..., dim]``."""
+        return _mm(hidden, self.p["head"])
+
+    def _leaves(self, prefix: str) -> tuple[dict, Any]:
+        """The leaves of one prefix by their short names, and its router's buffer (None where it has none)."""
+        leaves = {k[len(prefix) + 1:]: v for k, v in self.p.items() if k.startswith(prefix + "_")}
+        return leaves, self.b_corr[prefix].value if prefix in self.b_corr else None
+
+    def __call__(self, tokens, train: bool = False):
+        del train  # no dropout, no running statistics
+        s = self.sizes
+        one_layer = jax.checkpoint(layer, static_argnums=(0, 4)) if self.remat else layer
+
+        h = self.p["embed"][tokens].astype(self.dtype)
+        loads = []
+        groups = layer_prefixes(s)
+        unit = [(prefix, kind) for prefix, kind, repeats in groups if repeats]
+        if unit:
+            def one_unit(h, leaves_and_buffers):
+                counts = []
+                for (prefix, kind), (leaves, b_corr) in zip(unit, leaves_and_buffers):
+                    with jax.named_scope(prefix):
+                        h, c = one_layer(kind, leaves, b_corr, h, s)
+                    counts += [] if c is None else [c]
+                return h, counts
+
+            h, counts = lax.scan(one_unit, h, [self._leaves(prefix) for prefix, _ in unit])
+            loads += [c.astype(F32) for c in counts]  # each [repeats, held]
+        for prefix, kind, repeats in groups:
+            if not repeats:
+                with jax.named_scope(prefix):
+                    h, c = one_layer(kind, *self._leaves(prefix), h, s)
+                loads += [] if c is None else [c.astype(F32)[None]]
+        hidden = rms_norm(h, self.p["norm_f"], s.eps).astype(self.dtype)
+        counters = {}
+        if loads:
+            loads = jnp.concatenate(loads)  # [expert layers, held]
+            counters = {
+                "moe_slots_here": jnp.sum(loads),
+                "moe_load_max_over_mean": jnp.max(jnp.max(loads, axis=1) / jnp.maximum(jnp.mean(loads, axis=1), 1.0)),
+            }
+        return hidden, counters
+
+
+@register_model("nemotron_h")
+def nemotron_h(num_classes=None, dtype=jnp.bfloat16, bn_axis_name=None, remat: bool = False,
+               seq_len=None, loss_block=None, norm_eps: float = 1e-5, **sizes):
+    """The model of the config's ``LM`` section, which `trainer._build_cfg_model` passes key by key
+    in lower case under ``TRAIN.TASK lm``; ``MODEL.MODULE`` names this module to have it registered."""
+    del num_classes, bn_axis_name  # a token model has a vocabulary, and no BatchNorm
+    del seq_len, loss_block        # the batch's and the loss's, not the model's
+    if not sizes:
+        raise ValueError("MODEL.ARCH 'nemotron_h' maps token ids to hidden states and is sized "
+                         "by the LM section: set TRAIN.TASK 'lm'")
+    return NemotronH(Sizes(eps=norm_eps, **sizes), dtype=dtype, remat=remat)

@@ -41,6 +41,7 @@ from distribuuuu_tpu.obs.exporter import (  # noqa: F401
     render_prometheus,
 )
 from distribuuuu_tpu.obs.journal import (  # noqa: F401
+    WINDOW_COUNTERS,
     Journal,
     read_journal,
     validate_journal,

@@ -49,6 +49,15 @@ _BOOL = (bool,)
 _DICT = (dict,)
 _LIST = (list,)
 
+#: a train step's own counters that ride its metrics to the ``window`` record
+#: (fetched at the PRINT_FREQ boundary as the loss is: no sync of their own),
+#: each with how it is reduced: ``sum`` adds up a step's micro-batches and
+#: devices and takes the window's mean step; ``max`` keeps the worst of each.
+#: A token model's routing (models/nemotron_h.py): the token-expert slots
+#: that landed on the experts held here, a step; the busiest held expert over
+#: the mean one, worst layer, worst step of the window.
+WINDOW_COUNTERS = {"moe_slots_here": "sum", "moe_load_max_over_mean": "max"}
+
 SCHEMA: dict[str, tuple[dict[str, tuple], dict[str, tuple]]] = {
     # run lifecycle -------------------------------------------------------
     "run_start": (
@@ -106,6 +115,7 @@ SCHEMA: dict[str, tuple[dict[str, tuple], dict[str, tuple]]] = {
             "throttle_s": _NUM,
             "dispatch_s": _NUM,
             "fetch_wait_s": _NUM,
+            **dict.fromkeys(WINDOW_COUNTERS, _NUM),
         },
     ),
     "epoch_train": (

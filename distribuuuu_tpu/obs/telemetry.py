@@ -262,10 +262,12 @@ class Telemetry:
         loss: float | None = None,
         acc1: float | None = None,
         acck: float | None = None,
+        counters: dict | None = None,
     ) -> None:
         """One PRINT_FREQ window, fed from the trainer's existing boundary
         fetch. Derives step time, percentiles (over this epoch's steady-state
-        windows), throughput, goodput and MFU."""
+        windows), throughput, goodput and MFU. ``counters``: the step's own
+        counters of `journal.WINDOW_COUNTERS`, from the same fetch."""
         steps = max(1, steps)
         wall_s = max(wall_s, 1e-9)
         step_time = wall_s / steps
@@ -320,6 +322,7 @@ class Telemetry:
             loss=float(loss) if loss is not None else None,
             acc1=float(acc1) if acc1 is not None else None,
             acck=float(acck) if acck is not None else None,
+            **(counters or {}),
         )
         if self._train_spans:
             # the window IS the trace: its wall splits into the loop's four

@@ -17,7 +17,8 @@ each boundary where the work happens (docs/OBSERVABILITY.md "Tracing"):
 - `step_scope` names what follows the gradient inside the jitted train step
   (`STEP_SCOPES`) in the executable's own metadata, so a device trace splits
   the step by phase. The model's forward and backward keep the module paths
-  flax gives them.
+  flax gives them; `MODEL_SCOPES` name the parts of a mixer those paths
+  cannot tell apart.
 
 Propagation contract (docs/OBSERVABILITY.md "Tracing"):
 
@@ -72,9 +73,14 @@ HOST_PHASES = {
     "fetch_wait": "fetch_wait_s",      # loop: device_get at the window boundary
     "checkpoint": None,                # loop: epoch-end save dispatch
 }
-#: what follows the gradient inside the jitted train step, plus the loss
-#: outside the module (trainer.make_train_step)
-STEP_SCOPES = ("grad_sync", "optimizer", "guard", "metrics", "loss")
+#: inside a model, what flax's module paths cannot tell apart: the scan
+#: proper of a state-space mixer (ops/ssm.py), and an expert layer's routing
+#: (scores, top-k, weights, sorting tokens to experts) and grouped products
+#: over the experts held (parallel/moe.py)
+MODEL_SCOPES = ("ssm_scan", "moe_route", "moe_experts")
+#: what follows the gradient inside the jitted train step, the loss outside
+#: the module (trainer.make_train_step), and `MODEL_SCOPES`
+STEP_SCOPES = ("grad_sync", "optimizer", "guard", "metrics", "loss") + MODEL_SCOPES
 
 
 def mint_trace_id() -> str:

@@ -795,15 +795,64 @@ def self_attention_fuses(
     )
 
 
-def self_attention(qkv, num_heads: int):
-    """Bias-free multi-head self-attention over packed ``qkv [B, L, 3·D]`` →
-    ``[B, L, D]``: `fused_self_attention` or `xla_self_attention`, chosen by
+#: query rows a block of `xla_causal_attention`: the float32 scores of one
+#: block against its keys are what goes through HBM at a time
+CAUSAL_BLOCK = 1024
+
+
+def _causal_block(q, k, v, start: int):
+    """One block of queries ``q [B, Q, G, R, hd]`` (rows ``start …``) against
+    the keys and values ``[B, K, G, hd]`` of rows ``0 … start + Q - 1``."""
+    head_dim = q.shape[-1]
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k, preferred_element_type=jnp.float32)
+    rows = start + jnp.arange(q.shape[1])[:, None]
+    s = jnp.where(jnp.arange(k.shape[1])[None, :] <= rows, s * head_dim**-0.5, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)  # row r always sees key 0: no empty row
+    return jnp.einsum("bgrqk,bkgd->bqgrd", w.astype(v.dtype), v)
+
+
+def xla_causal_attention(qkv, num_heads: int, kv_heads: int, block: int = CAUSAL_BLOCK):
+    """Causal grouped-query attention over packed ``qkv [B, L, (H + 2·G)·hd]``
+    (``H`` query heads, then ``G`` key heads, then ``G`` value heads; query
+    heads ``g·H/G …`` read key/value head ``g``) → ``[B, L, H·hd]``.
+
+    Blocks of `CAUSAL_BLOCK` query rows, each against the keys up to its own
+    last row only, so the products above the diagonal are never formed (half
+    of them at large L) and no ``L x L`` tensor exists; each block is
+    rematerialised in the backward pass, so what is kept for it is q, k, v."""
+    b, l, width = qkv.shape
+    hd = width // (num_heads + 2 * kv_heads)
+    q, k, v = jnp.split(qkv, (num_heads * hd, (num_heads + kv_heads) * hd), axis=-1)
+    q = q.reshape(b, l, kv_heads, num_heads // kv_heads, hd)
+    k, v = k.reshape(b, l, kv_heads, hd), v.reshape(b, l, kv_heads, hd)
+    one_block = jax.checkpoint(_causal_block, static_argnums=(3,))
+    out = [
+        one_block(q[:, start:start + block], k[:, :start + block], v[:, :start + block], start)
+        for start in range(0, l, block)
+    ]
+    return jnp.concatenate(out, axis=1).reshape(b, l, num_heads * hd)
+
+
+def self_attention(qkv, num_heads: int, *, kv_heads: int | None = None, causal: bool = False):
+    """Multi-head self-attention over a packed projection, two kinds behind
+    one entry point.
+
+    Bias-free bidirectional (the default; ``qkv [B, L, 3·D]`` → ``[B, L, D]``):
+    `fused_self_attention` or `xla_self_attention`, chosen by
     `self_attention_fuses` from the devices of the mesh in use (inside the
     trainer's `shard_map`'d steps; the described chips of a compile-only
     test count as what they describe) and the shapes. Traced outside any
     mesh it is the einsums, uncounted: ``model.init``, shape inference, and
     programs partitioned by named shardings alone (`serve/engine.py`), where
-    a Mosaic call could not be partitioned."""
+    a Mosaic call could not be partitioned.
+
+    Causal, grouped-query (``causal=True``, ``kv_heads`` key/value heads;
+    ``qkv [B, L, (H + 2·G)·hd]``): `xla_causal_attention` everywhere; no
+    kernel computes it yet."""
+    if causal:
+        return xla_causal_attention(qkv, num_heads, num_heads if kv_heads is None else kv_heads)
+    if kv_heads not in (None, num_heads):
+        raise ValueError("grouped-query attention is implemented for causal=True only")
     mesh = jax.sharding.get_abstract_mesh()
     device = None if mesh.empty else mesh.abstract_device
     if device is None:

@@ -49,6 +49,10 @@ def lr_fun_cos(cur_epoch: int) -> float:
 
 _LR_POLICIES = {"steps": lr_fun_steps, "cos": lr_fun_cos}
 
+#: adafactor: a leaf's second moment is factored over its two largest axes
+#: where both have at least this many entries (optax's own default)
+FACTOR_MIN_DIM = 128
+
 
 def get_epoch_lr(cur_epoch: int) -> float:
     """LR for a given epoch: policy × BASE_LR, with linear warmup."""
@@ -160,6 +164,8 @@ def construct_optimizer(
 
     - ``sgd`` (default): torch-exact SGD+momentum+nesterov+coupled-WD
       (reference `utils.py:187-196`).
+    - ``adafactor``: factored second moment, no first (Shazeer & Stern 2018)
+      — for models whose parameters fill most of a chip.
     - ``lamb``: layerwise-adaptive large-batch optimizer (You et al. 2020) —
       beyond the reference, whose large-batch story stops at SGD + linear LR
       scaling (`README.md:174-192`); LAMB is the standard recipe for pushing
@@ -185,15 +191,15 @@ def construct_optimizer(
                 nesterov=cfg.OPTIM.NESTEROV,
             ),
         )
+    # Weight decay masked to multi-dim params: published large-batch LAMB
+    # recipes exclude biases and BN scale/shift from decay (unlike the
+    # SGD branch, where decay-everything IS the torch reference parity).
+    def _wd_mask(params):
+        return jax.tree.map(lambda p: p.ndim > 1, params)
+
     if name == "lamb":
-        # Weight decay masked to multi-dim params: published large-batch LAMB
-        # recipes exclude biases and BN scale/shift from decay (unlike the
-        # SGD branch, where decay-everything IS the torch reference parity).
         # The trust ratio stays optax-canonical (unmasked) — for 1-D params
         # scale_by_trust_ratio already degenerates gracefully.
-        def _wd_mask(params):
-            return jax.tree.map(lambda p: p.ndim > 1, params)
-
         if param_specs is not None and fsdp_axis is not None:
             trust = _scale_by_trust_ratio_fsdp(param_specs, fsdp_axis)
         else:
@@ -205,8 +211,26 @@ def construct_optimizer(
             optax.add_decayed_weights(cfg.OPTIM.WEIGHT_DECAY, mask=_wd_mask),
             trust,
         )
+    if name == "adafactor":
+        if param_specs is not None:
+            raise ValueError(
+                "OPTIM.OPTIMIZER 'adafactor' keeps row/column statistics and a "
+                "block RMS of whole leaves: not under MESH.FSDP > 1"
+            )
+        # Shazeer & Stern 2018 as `optax.adafactor` composes it, minus the
+        # learning rate (the trainer's) and the optional momentum: the
+        # factored second moment (decay 1 - t^-0.8), the update clipped to
+        # unit RMS a leaf, scaled by the leaf's own RMS (at least 1e-3), then
+        # LAMB's masked decoupled decay. The state is a row and a column
+        # vector a matrix: a few MB beside a model of GBs.
+        return optax.chain(
+            optax.scale_by_factored_rms(min_dim_size_to_factor=FACTOR_MIN_DIM),
+            optax.clip_by_block_rms(1.0),
+            optax.scale_by_param_block_rms(),
+            optax.add_decayed_weights(cfg.OPTIM.WEIGHT_DECAY, mask=_wd_mask),
+        )
     raise ValueError(
-        f"Unknown OPTIM.OPTIMIZER {name!r} (available: 'sgd', 'lamb')"
+        f"Unknown OPTIM.OPTIMIZER {name!r} (available: 'sgd', 'lamb', 'adafactor')"
     )
 
 

@@ -44,11 +44,14 @@ the training loss to keep routing balanced.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from distribuuuu_tpu.obs.trace import step_scope
 
 
 # cfg.MODEL.FUSED_MOE lands here for the duration of a trainer run
@@ -213,3 +216,120 @@ def switch_moe(
     p_e = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(f_e * p_e)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# Top-k experts held by share: route over all, compute the experts held here
+# ---------------------------------------------------------------------------
+#
+# The layer of a model whose experts outnumber the chips: this chip is told
+# which experts it holds (``first … first + held - 1`` of ``E``), scores every
+# token over all ``E``, and computes the part of the mixture its own experts
+# give, for the tokens routed to them. What the absent experts would add is
+# left out (their chips add it; on one chip that exchange does not run). No
+# capacity and no drops: a token-expert slot is never discarded, however
+# uneven the routing. The slots are sorted by expert into one list, laid out in
+# blocks of `BLOCK` rows that belong to one expert each, and computed a round
+# of rows at a time: the work follows the number of slots that landed here,
+# not the fullest expert's (`held_experts`).
+
+#: rows of a block of the sorted layout: one expert's, so that a block is one
+#: product with that expert's weights; an expert's last block is padded
+BLOCK = 256
+
+
+def sigmoid_topk_route(logits, k: int, bias, scale: float):
+    """Top-``k`` of ``E`` by sigmoid score, float32 throughout.
+
+    ``logits [T, E]``; the choice is the top-``k`` of ``sigmoid(logits) + bias``
+    (``bias [E]``, a correction buffer, not trained), the mixture weights are
+    the chosen scores themselves, normalised over the choice and scaled:
+    ``w_i = scale · s_i / Σ_choice s_j``. Returns ``(idx [T, k] int32, w [T, k])``.
+    """
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+
+
+def round_rows_for(tokens: int, k: int, experts: int, held: int, room: float = 1.5) -> int:
+    """Rows of one round of `held_experts`: the slots expected on the experts held, ``tokens·k·held/experts``,
+    with ``room``, and a block of padding an expert, in whole blocks; never more than every token on
+    every held expert would need."""
+    blocks = lambda rows: max(1, math.ceil(rows / BLOCK))
+    return BLOCK * min(blocks(room * tokens * k * held / experts) + held, blocks(tokens) * held)
+
+
+def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
+    """The held experts' part of the mixture ``Σ_i w_i · W2_i relu(W1_i x)²``.
+
+    ``x [T, D]``; ``idx, w [T, K]`` from the router (ids over all experts);
+    ``w1 [H, D, F]``, ``w2 [H, F, D]`` the experts ``first … first + H - 1``;
+    ``round_rows`` a multiple of `BLOCK` (`round_rows_for`).
+    Returns ``(y [T, D] float32, counts [H] int32)``: the sum over the slots
+    that landed here, and how many landed on each held expert.
+
+    Under ``dtpu.moe_route``: which tokens hit which held expert and with what
+    weight (``[T, H]``; a token picks an expert at most once), one stable sort
+    that lists the hits by expert and then by token, the layout of that list
+    in blocks of `BLOCK` rows, one expert a block (an expert's last block
+    padded), the gather of a round's rows and the weighted scatter-add back.
+    Under ``dtpu.moe_experts``: the two products of a round, a batch of blocks
+    each against its own expert's weights.
+
+    Round 0 takes the first ``round_rows`` rows of the layout and is
+    straight-line code; the rows beyond go in groups of one, two, four …
+    further rounds, each group behind a `lax.cond` taken only where the
+    layout reaches that far. So every slot is computed whatever the routing
+    (all tokens on every held expert: every round), the work follows the
+    number of slots and not the fullest expert, a step whose slots fit
+    ``round_rows`` pays for round 0 alone, and no round holds more than
+    ``round_rows`` rows at a time.
+    """
+    tokens, dim = x.shape
+    held = w1.shape[0]
+    f32 = jnp.float32
+    with step_scope("moe_route"):
+        hits = idx[:, :, None] == (first + jnp.arange(held))[None, None, :]       # [T, K, H]
+        hit = jnp.any(hits, axis=1).T                                             # [H, T]
+        gate = jnp.sum(jnp.where(hits, w[:, :, None].astype(f32), 0.0), axis=1).T.reshape(-1)  # [H·T]
+        counts = jnp.sum(hit, axis=1, dtype=jnp.int32)                            # [H]
+        # the hits' flat ids e·T + t, by expert and then by token; the misses follow
+        order = jnp.argsort(jnp.logical_not(hit.reshape(-1)), stable=True).astype(jnp.int32)
+        first_hit = jnp.cumsum(counts) - counts                                   # an expert's place in the list
+        padded = -(-counts // BLOCK) * BLOCK
+        ends = jnp.cumsum(padded)                                                 # ... and in the layout
+        begins = ends - padded
+
+    def one_round(x, gate, w1, w2, start):
+        """The mixture's part from the rows ``start … start + round_rows - 1`` of the layout, ``[T, D]``."""
+        rows = round_rows
+        with step_scope("moe_route"):
+            at = start + jnp.arange(rows // BLOCK) * BLOCK
+            expert = jnp.minimum(jnp.searchsorted(ends, at, side="right"), held - 1)   # [blocks]; past the end: dead rows
+            of_row = jnp.repeat(expert, BLOCK)
+            rank = start + jnp.arange(rows) - begins[of_row]
+            live = rank < counts[of_row]
+            flat = order[jnp.clip(first_hit[of_row] + rank, 0, held * tokens - 1)]
+            token = jnp.where(live, flat % tokens, tokens)       # dead rows read row 0 and write nothing
+            picked = x[jnp.minimum(token, tokens - 1)].reshape(rows // BLOCK, BLOCK, dim)
+            weight = jnp.where(live, gate[flat], 0.0)
+            w1_of, w2_of = w1[expert].astype(x.dtype), w2[expert].astype(x.dtype)     # [blocks, ·, ·]
+        with step_scope("moe_experts"):
+            hidden = jnp.einsum("brd,bdf->brf", picked, w1_of, preferred_element_type=f32)
+            hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
+            out = jnp.einsum("brf,bfd->brd", hidden, w2_of, preferred_element_type=f32)
+        with step_scope("moe_route"):
+            return jnp.zeros((tokens, dim), f32).at[token].add(out.reshape(rows, dim) * weight[:, None], mode="drop")
+
+    y = one_round(x, gate, w1, w2, 0)
+    later = jax.checkpoint(one_round)  # a round not taken keeps nothing for the backward pass either
+    done, rounds = 1, 1  # rounds done, and rounds in the next group: 1, 2, 4, ...
+    while done * round_rows < held * (-(-tokens // BLOCK) * BLOCK):
+        def group(y, x, gate, w1, w2, done=done, rounds=rounds):
+            more = lambda acc, r: (acc + later(x, gate, w1, w2, (done + r) * round_rows), None)
+            return lax.scan(more, y, jnp.arange(rounds))[0]
+
+        y = lax.cond(ends[-1] > done * round_rows, group, lambda y, *_: y, y, x, gate, w1, w2)
+        done, rounds = done + rounds, 2 * rounds
+    return y, counts

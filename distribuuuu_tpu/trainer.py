@@ -21,6 +21,7 @@ from __future__ import annotations
 import functools
 import importlib
 import os
+import statistics
 import sys
 import time
 from typing import Any
@@ -156,6 +157,58 @@ def _forward_loss_mae(model, params, batch_stats, batch, train: bool, rng, seq_n
     return loss, (pred, batch_stats)
 
 
+TASKS = ("classify", "mae", "lm")
+
+
+def _check_task(task: str) -> None:
+    if task not in TASKS:
+        raise ValueError(f"TRAIN.TASK must be one of {TASKS}, got {task!r}")
+
+
+#: how a step's own counter (obs/journal WINDOW_COUNTERS) is reduced: over a
+#: step's micro-batches, over the devices, over a window's steps
+_COUNTER_REDUCERS = {
+    "sum": (jnp.sum, jax.lax.psum, statistics.fmean),
+    "max": (jnp.max, jax.lax.pmax, max),
+}
+
+
+def next_token_loss(logits_of, hidden, labels, block: int):
+    """Mean next-token cross-entropy, the vocabulary's logits taken for
+    ``block`` tokens at a time: ``logits_of(hidden[block, D]) -> [block, V]``
+    float32 exists for one block only, forward and (rematerialised) backward.
+    A token count that ``block`` does not divide is taken as one block."""
+    tokens = labels.size
+    if tokens % block:
+        block = tokens
+    hidden = hidden.reshape(tokens // block, block, hidden.shape[-1])
+    labels = labels.reshape(tokens // block, block)
+
+    @jax.checkpoint
+    def block_nll(h, y):
+        logits = logits_of(h)
+        picked = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+    return jnp.sum(jax.lax.map(lambda hy: block_nll(*hy), (hidden, labels))) / tokens
+
+
+def _forward_loss_lm(model, params, batch_stats, batch):
+    """Next-token forward + loss (TRAIN.TASK "lm"): a row of ``batch["tokens"]``
+    is ``L + 1`` ids, inputs and labels one leaf shifted. The model returns
+    its final hidden states and its routing counters, which ride the logits
+    slot (metrics skip top-k for lm); its buffers pass through untouched."""
+    variables = {"params": params, "batch_stats": batch_stats}
+    tokens = batch["tokens"]
+    hidden, counters = model.apply(variables, tokens[:, :-1], train=True)
+    with step_scope("loss"):
+        loss = next_token_loss(
+            lambda h: model.apply(variables, h, method="head_logits"),
+            hidden, tokens[:, 1:], cfg.LM.LOSS_BLOCK,
+        )
+    return loss, (counters, batch_stats)
+
+
 def make_train_step(
     model, tx, mesh: Mesh, topk: int, accum_steps: int = 1,
     nonfinite_guard: bool | None = None, state_specs=None, qat=None,
@@ -198,8 +251,10 @@ def make_train_step(
     changes.
 
     ``task`` (default ``cfg.TRAIN.TASK``): "classify" (softmax-CE, top-k
-    metrics) or "mae" (masked pixel reconstruction, `_forward_loss_mae`;
-    top-k counters stay zero).
+    metrics), "mae" (masked pixel reconstruction, `_forward_loss_mae`;
+    top-k counters stay zero) or "lm" (next-token cross-entropy,
+    `_forward_loss_lm`; top-k stays zero and the model's routing counters
+    join the metrics).
 
     A mesh with a ``seq`` axis (cfg.MESH.SEQ > 1, `parallel/seq.py`) runs
     the model sequence-parallel: the batch replicates along seq (in_specs
@@ -214,11 +269,13 @@ def make_train_step(
         nonfinite_guard = cfg.FAULT.NONFINITE_GUARD
     if task is None:
         task = cfg.TRAIN.TASK
-    if task not in ("classify", "mae"):
-        raise ValueError(f"TRAIN.TASK must be 'classify' or 'mae', got {task!r}")
+    _check_task(task)
     seq_n = seqpar.seq_size(mesh)
-    if task == "mae" and qat is not None:
+    if task != "classify" and qat is not None:
         raise ValueError("QUANT.QAT supports TRAIN.TASK 'classify' only")
+    if task == "lm" and seq_n > 1:
+        raise ValueError("TRAIN.TASK 'lm' has no sequence-parallel path: MESH.SEQ must be 1")
+    rows_key = "tokens" if task == "lm" else "label"
     if fsdp.fsdp_size(mesh) > 1 and state_specs is None:
         # without specs the step would shard the batch over both axes but
         # reduce grads over 'data' only — silent per-fsdp-group divergence
@@ -246,6 +303,8 @@ def make_train_step(
                 p = fsdp.all_gather_params(p, param_specs)
             if task == "mae":
                 return _forward_loss_mae(model, p, batch_stats, micro, True, rng, seq_n)
+            if task == "lm":
+                return _forward_loss_lm(model, p, batch_stats, micro)
             return _forward_loss(model, p, batch_stats, micro, True, rng, qat=qat)
 
         (loss, (logits, new_stats)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -298,7 +357,10 @@ def make_train_step(
             )
             grads = jax.tree.map(lambda g: g / accum_steps, sum_grads)
             loss = sum_loss / accum_steps
-            logits = logits_all.reshape(-1, logits_all.shape[-1])
+            if task == "lm":  # the counters of the micro-batches, a step's worth
+                logits = {k: _COUNTER_REDUCERS[obs.WINDOW_COUNTERS[k]][0](v) for k, v in logits_all.items()}
+            else:
+                logits = logits_all.reshape(-1, logits_all.shape[-1])
             # Running stats thread through the scan carry, so each micro-batch
             # EMAs them IN ORDER — torch's sequential semantics, exactly (the
             # input stats never enter a train-mode forward, so grads/outputs
@@ -332,10 +394,11 @@ def make_train_step(
         with step_scope("optimizer"):
             updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
             new_params = optim.apply_updates_with_lr(state.params, updates, lr)
-        n = jnp.float32(batch["label"].shape[0])
-        if task == "mae":
-            # pixel reconstruction has no top-k; the counters stay zero so
-            # the metric schema (and the meters) are task-invariant
+        n = jnp.float32(batch[rows_key].shape[0])
+        if task in ("mae", "lm"):
+            # pixel reconstruction and next-token loss have no top-k; the
+            # counters stay zero so the metric schema (and the meters) are
+            # task-invariant
             correct = {1: jnp.float32(0.0), topk: jnp.float32(0.0)}
         else:
             with step_scope("metrics"):
@@ -389,6 +452,11 @@ def make_train_step(
             }
             if nonfinite_guard:
                 metrics["skipped"] = 1.0 - keep.astype(jnp.float32)
+            if task == "lm":
+                # the model's own counters, each reduced over the devices as
+                # obs/journal WINDOW_COUNTERS declares it
+                for name, value in logits.items():
+                    metrics[name] = _COUNTER_REDUCERS[obs.WINDOW_COUNTERS[name]][1](value, reduce_axes)
         return (
             TrainState(params=new_params, batch_stats=new_stats, opt_state=new_opt_state),
             metrics,
@@ -436,6 +504,16 @@ def make_eval_step(model, mesh: Mesh, topk: int, state_specs=None, qat=None,
         params = state.params
         if use_fsdp:
             params = fsdp.all_gather_params(params, state_specs.params)
+        if task == "lm":
+            loss, _ = _forward_loss_lm(model, params, state.batch_stats, batch)
+            n_local = jnp.float32(batch["tokens"].shape[0])
+            m = {
+                "loss_sum": jax.lax.psum(loss * n_local, reduce_axes),
+                "n": jax.lax.psum(n_local, reduce_axes),
+                "correct1": jnp.float32(0.0),
+                f"correct{topk}": jnp.float32(0.0),
+            }
+            return jax.tree.map(jnp.add, totals, m)
         w = batch["weight"]
         if task == "mae":
             # same mask for every batch/run: eval is a fixed, comparable
@@ -514,9 +592,10 @@ def create_train_state(model, key, mesh: Mesh, im_size: int):
         init_model = model.clone(seq_axis=None)
 
     def model_init(key):
-        variables = init_model.init(
-            key, jnp.zeros((1, im_size, im_size, 3), jnp.float32), train=False
-        )
+        # a model that is not fed images says what it is initialised with
+        dummy = getattr(init_model, "dummy_input", None)
+        x = dummy(im_size) if dummy else jnp.zeros((1, im_size, im_size, 3), jnp.float32)
+        variables = init_model.init(key, x, train=False)
         return variables["params"], variables.get("batch_stats", {})
 
     # fsdp_n derives from cfg.MESH (identical on every host), so the two
@@ -576,7 +655,8 @@ def _import_arch_modules() -> None:
     """Import MODEL.MODULE so out-of-tree archs self-register (the explicit
     analog of the reference's timm fallback, `trainer.py:117-128`). External
     factories must accept the `build_model` kwargs: ``num_classes``,
-    ``dtype``, ``bn_axis_name``, ``remat`` (and ``stem_s2d`` when opted in).
+    ``dtype``, ``bn_axis_name``, ``remat`` (and ``stem_s2d`` when opted in;
+    under TRAIN.TASK "lm" the LM section's keys in lower case).
     """
     for mod in filter(None, (m.strip() for m in cfg.MODEL.MODULE.split(","))):
         try:
@@ -593,10 +673,7 @@ def _build_cfg_model():
     from distribuuuu_tpu.models.layers import set_bn_compute_dtype
 
     _import_arch_modules()
-    if cfg.TRAIN.TASK not in ("classify", "mae"):
-        raise ValueError(
-            f"TRAIN.TASK must be 'classify' or 'mae', got {cfg.TRAIN.TASK!r}"
-        )
+    _check_task(cfg.TRAIN.TASK)
     if cfg.MODEL.DTYPE not in ("float32", "bfloat16"):
         raise ValueError(
             f"MODEL.DTYPE must be 'float32' or 'bfloat16', got {cfg.MODEL.DTYPE!r}"
@@ -655,6 +732,13 @@ def _build_cfg_model():
             # the class token has no home shard; gap pooling is the
             # seq-compatible representation (models/vit.py)
             kwargs["pool"] = "gap"
+    if cfg.TRAIN.TASK == "lm":
+        if cfg.MESH.SEQ > 1 or cfg.MESH.FSDP not in (0, 1):
+            raise ValueError("TRAIN.TASK 'lm' runs data-parallel only: MESH.SEQ and MESH.FSDP must be 1")
+        # a token model's factory takes the LM section, key by key in lower
+        # case; it lives in the module MODEL.MODULE names, so an image run
+        # neither imports nor builds any of it
+        kwargs.update({key.lower(): value for key, value in cfg.LM.items()})
     if cfg.TRAIN.TASK == "mae":
         if not cfg.MODEL.ARCH.startswith("mae_"):
             raise ValueError(
@@ -881,10 +965,17 @@ def train_epoch(
                 losses.update(win_loss, n=int(n))
                 top1.update(win_acc1, n=int(n))
                 topk_m.update(win_acck, n=int(n))
+            # the step's own counters beside its loss (a token model's
+            # routing): the window's mean step, or its worst
+            counters = {
+                name: float(_COUNTER_REDUCERS[how][2](float(v[name]) for v in vals))
+                for name, how in obs.WINDOW_COUNTERS.items() if name in vals[0]
+            }
             window.clear()
             # journal the window from the values fetched above — telemetry
             # adds no sync of its own (docs/OBSERVABILITY.md)
             tel.window(
+                counters=counters,
                 epoch=epoch, step=it, gstep=gstep, steps=win_steps,
                 skipped=win_skipped, lr=lr, wall_s=win_wall,
                 data_time=data_time.avg, imgs=win_steps * step_imgs,

@@ -200,6 +200,50 @@ def test_vit_step_for_described_v5e_takes_the_fused_pair(topo, one_chip):
     assert "dtpu_attn_fwd" in text and "dtpu_attn_bwd" in text
 
 
+# -- the token model's mixers at the widths of config/nemotron3_super.yaml (XLA only: no Mosaic kernel,
+# which is also what lets the benchmark price the step without a kernel file) -----------------------
+
+def _compiles_without_kernels(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+    return text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_chunked_scan_compiles_for_v5e_at_the_cells_sizes(one_chip, grad):
+    """One row of 8192 positions, the 16 heads x 64 and the one B/C group of 128 states a chip holds."""
+    from distribuuuu_tpu.ops.ssm import ssd_scan
+
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    b, l, h, p, n = 1, 8192, 16, 64, 128
+    args = (shape((b, l, h, p), jnp.bfloat16), shape((b, l, h), jnp.float32), shape((h,), jnp.float32),
+            shape((b, l, 1, n), jnp.bfloat16), shape((b, l, 1, n), jnp.bfloat16), shape((h,), jnp.float32))
+    scan = lambda *a: ssd_scan(*a, 128)
+    fn = jax.grad(lambda *a: jnp.sum(scan(*a).astype(jnp.float32)), argnums=(0, 1, 3, 4)) if grad else scan
+    assert "dtpu.ssm_scan" in _compiles_without_kernels(fn, *args)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_held_experts_compile_for_v5e_at_the_cells_sizes(one_chip, grad):
+    """8 of 512 experts, top-22, latent 1024, width 2688, 8192 tokens: round 0 and the overflow rounds."""
+    from distribuuuu_tpu.parallel.moe import held_experts, round_rows_for, sigmoid_topk_route
+
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    t, e, held, d, f = 8192, 512, 8, 1024, 2688
+    rows = round_rows_for(t, 22, e, held)
+
+    def mix(x, logits, w1, w2):
+        idx, w = sigmoid_topk_route(logits, 22, jnp.zeros((e,)), 5.0)
+        y, counts = held_experts(x, idx, w, w1, w2, 0, rows)
+        return y if not grad else jnp.sum(y)
+
+    fn = jax.grad(mix, argnums=(0, 1, 2, 3)) if grad else mix
+    text = _compiles_without_kernels(fn, shape((t, d), jnp.bfloat16), shape((t, e), jnp.float32),
+                                     shape((held, d, f), jnp.float32), shape((held, f, d), jnp.float32))
+    assert "dtpu.moe_route" in text and "dtpu.moe_experts" in text
+    assert text.count(" conditional(") >= 3  # groups of 1, 2, 4, ... rounds of 6400 rows beyond the first
+
+
 # ops/moe_kernel.py is refused by Mosaic at every shape its VMEM guard admits
 # (larger ones hand over to the einsum formulation before the kernel is
 # reached). No trainer path calls it; repair or deletion belongs to the first

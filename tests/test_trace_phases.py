@@ -96,6 +96,8 @@ def test_compiled_step_names_every_scope_and_both_passes(fresh_cfg, no_compile_c
     for scope in DTPU_SCOPES:
         if scope == "dtpu.grad_sync":
             continue  # a mean over one device is no op: see the four-device test
+        if scope[len("dtpu."):] in obs_trace.MODEL_SCOPES:
+            continue  # a state-space or expert mixer's: tests/test_nemotron_h.py
         assert any(scope in n for n in names), f"no op under {scope}"
     # the loss outside the module reads as forward and backward of a named thing
     assert any("/jvp(dtpu.loss)/" in n for n in names)
