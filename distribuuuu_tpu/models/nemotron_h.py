@@ -50,7 +50,7 @@ from distribuuuu_tpu.models.registry import register_model
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import self_attention
 from distribuuuu_tpu.ops.ssm import ssd_scan
-from distribuuuu_tpu.parallel.moe import held_experts, round_rows_for, sigmoid_topk_route
+from distribuuuu_tpu.parallel.moe import BLOCK, held_experts, round_rows_for, sigmoid_topk_route
 
 F32 = jnp.float32
 #: projections back into the residual stream: their init is scaled by 1/sqrt(2·layers_total)
@@ -287,6 +287,7 @@ class NemotronH(nn.Module):
             loads = jnp.concatenate(loads)  # [expert layers, held]
             counters = {
                 "moe_slots_here": jnp.sum(loads),
+                "moe_rows_here": jnp.sum(jnp.ceil(loads / BLOCK) * BLOCK),  # what the rounds computed: the slots in whole blocks
                 "moe_load_max_over_mean": jnp.max(jnp.max(loads, axis=1) / jnp.maximum(jnp.mean(loads, axis=1), 1.0)),
             }
         return hidden, counters

@@ -54,9 +54,11 @@ _LIST = (list,)
 #: each with how it is reduced: ``sum`` adds up a step's micro-batches and
 #: devices and takes the window's mean step; ``max`` keeps the worst of each.
 #: A token model's routing (models/nemotron_h.py): the token-expert slots
-#: that landed on the experts held here, a step; the busiest held expert over
-#: the mean one, worst layer, worst step of the window.
-WINDOW_COUNTERS = {"moe_slots_here": "sum", "moe_load_max_over_mean": "max"}
+#: that landed on the experts held here, a step; the rows computed for them
+#: (the slots in whole blocks of one expert: slots over rows is the fill of
+#: what was computed); the busiest held expert over the mean one, worst
+#: layer, worst step of the window.
+WINDOW_COUNTERS = {"moe_slots_here": "sum", "moe_rows_here": "sum", "moe_load_max_over_mean": "max"}
 
 SCHEMA: dict[str, tuple[dict[str, tuple], dict[str, tuple]]] = {
     # run lifecycle -------------------------------------------------------

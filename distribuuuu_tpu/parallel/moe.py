@@ -52,6 +52,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from distribuuuu_tpu.obs.trace import step_scope
+from distribuuuu_tpu.ops.grouped import grouped_product, grouped_product_fuses
+from distribuuuu_tpu.ops.interpret import pallas_interpret
 
 
 # cfg.MODEL.FUSED_MOE lands here for the duration of a trainer run
@@ -231,7 +233,10 @@ def switch_moe(
 # uneven the routing. The slots are sorted by expert into one list, laid out in
 # blocks of `BLOCK` rows that belong to one expert each, and computed a round
 # of rows at a time: the work follows the number of slots that landed here,
-# not the fullest expert's (`held_experts`).
+# not the fullest expert's (`held_experts`). A block's two products read its
+# expert's weights where they lie (`ops/grouped.py`: two Mosaic kernels) where
+# the program is traced for TPUs and the widths tile, and are XLA's batched
+# products against gathered copies of the weights everywhere else.
 
 #: rows of a block of the sorted layout: one expert's, so that a block is one
 #: product with that expert's weights; an expert's last block is padded
@@ -248,7 +253,10 @@ def sigmoid_topk_route(logits, k: int, bias, scale: float):
     """
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    # the chosen scores by comparison, not `take_along_axis`: the same numbers (a token's choices are
+    # distinct, so each sum holds one score and zeros, forward and backward), and one fused pass over
+    # [T, k, E] where the gather of T·k single elements took 1.8 ms a call on the chip (PERF.md §5, PR 30)
+    chosen = jnp.sum(jnp.where(idx[:, :, None] == jnp.arange(scores.shape[-1]), scores[:, None, :], 0.0), axis=-1)
     return idx, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
 
 
@@ -258,6 +266,26 @@ def round_rows_for(tokens: int, k: int, experts: int, held: int, room: float = 1
     every held expert would need."""
     blocks = lambda rows: max(1, math.ceil(rows / BLOCK))
     return BLOCK * min(blocks(room * tokens * k * held / experts) + held, blocks(tokens) * held)
+
+
+#: `jax.monitoring` events of `held_experts`, one per call traced for a mesh:
+#: which realisation of a block's products it took; the journal's ``counters``
+#: records carry them (obs/monitors.py)
+GROUPED_CALLS_EVENT = "moe_grouped_calls"
+XLA_CALLS_EVENT = "moe_xla_calls"
+
+
+def _takes_the_kernels(dim: int, width: int, dtype) -> bool:
+    """The realisation of a block's products, from what the trace can observe: `ops/grouped.py`'s
+    kernels where a mesh of TPUs is in use (the described chips of a compile-only test count as what
+    they describe) and `grouped_product_fuses` admits the shapes; outside any mesh (``model.init``,
+    shape inference) XLA's batched products, uncounted."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return False
+    fuses = grouped_product_fuses(mesh.abstract_device.device_kind, BLOCK, dim, width, jnp.dtype(dtype).itemsize)
+    jax.monitoring.record_event(GROUPED_CALLS_EVENT if fuses else XLA_CALLS_EVENT)
+    return fuses
 
 
 def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
@@ -274,8 +302,11 @@ def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
     that lists the hits by expert and then by token, the layout of that list
     in blocks of `BLOCK` rows, one expert a block (an expert's last block
     padded), the gather of a round's rows and the weighted scatter-add back.
-    Under ``dtpu.moe_experts``: the two products of a round, a batch of blocks
-    each against its own expert's weights.
+    Under ``dtpu.moe_experts``: the two products of a round, every block with
+    its own expert's weights: `ops.grouped.grouped_product` (forward and
+    backward kernels; no copy of the weights a block, no weight gradient a
+    block) or, where `_takes_the_kernels` says no, a batch of blocks against
+    gathered copies of the weights.
 
     Round 0 takes the first ``round_rows`` rows of the layout and is
     straight-line code; the rows beyond go in groups of one, two, four …
@@ -289,6 +320,7 @@ def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
     tokens, dim = x.shape
     held = w1.shape[0]
     f32 = jnp.float32
+    kernels = _takes_the_kernels(dim, w1.shape[2], x.dtype)
     with step_scope("moe_route"):
         hits = idx[:, :, None] == (first + jnp.arange(held))[None, None, :]       # [T, K, H]
         hit = jnp.any(hits, axis=1).T                                             # [H, T]
@@ -312,13 +344,22 @@ def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
             live = rank < counts[of_row]
             flat = order[jnp.clip(first_hit[of_row] + rank, 0, held * tokens - 1)]
             token = jnp.where(live, flat % tokens, tokens)       # dead rows read row 0 and write nothing
-            picked = x[jnp.minimum(token, tokens - 1)].reshape(rows // BLOCK, BLOCK, dim)
+            picked = x[jnp.minimum(token, tokens - 1)]
             weight = jnp.where(live, gate[flat], 0.0)
-            w1_of, w2_of = w1[expert].astype(x.dtype), w2[expert].astype(x.dtype)     # [blocks, ·, ·]
+            if kernels:
+                live_blocks = jnp.clip((ends[-1] - start) // BLOCK, 0, rows // BLOCK)
+            else:
+                picked = picked.reshape(rows // BLOCK, BLOCK, dim)
+                w1_of, w2_of = w1[expert].astype(x.dtype), w2[expert].astype(x.dtype)  # [blocks, ·, ·]
         with step_scope("moe_experts"):
-            hidden = jnp.einsum("brd,bdf->brf", picked, w1_of, preferred_element_type=f32)
-            hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
-            out = jnp.einsum("brf,bfd->brd", hidden, w2_of, preferred_element_type=f32)
+            if kernels:
+                hidden = grouped_product(picked, expert, live_blocks, w1, False, pallas_interpret())
+                hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
+                out = grouped_product(hidden, expert, live_blocks, w2, False, pallas_interpret())
+            else:
+                hidden = jnp.einsum("brd,bdf->brf", picked, w1_of, preferred_element_type=f32)
+                hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
+                out = jnp.einsum("brf,bfd->brd", hidden, w2_of, preferred_element_type=f32)
         with step_scope("moe_route"):
             return jnp.zeros((tokens, dim), f32).at[token].add(out.reshape(rows, dim) * weight[:, None], mode="drop")
 

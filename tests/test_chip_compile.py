@@ -200,8 +200,8 @@ def test_vit_step_for_described_v5e_takes_the_fused_pair(topo, one_chip):
     assert "dtpu_attn_fwd" in text and "dtpu_attn_bwd" in text
 
 
-# -- the token model's mixers at the widths of config/nemotron3_super.yaml (XLA only: no Mosaic kernel,
-# which is also what lets the benchmark price the step without a kernel file) -----------------------
+# -- the token model's mixers at the widths of config/nemotron3_super.yaml: the scan is XLA's alone, the
+# expert layer's products XLA's outside a mesh and the kernel pair of ops/grouped.py inside the chip's ----
 
 def _compiles_without_kernels(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -224,24 +224,58 @@ def test_chunked_scan_compiles_for_v5e_at_the_cells_sizes(one_chip, grad):
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_held_experts_compile_for_v5e_at_the_cells_sizes(one_chip, grad):
-    """8 of 512 experts, top-22, latent 1024, width 2688, 8192 tokens: round 0 and the overflow rounds."""
-    from distribuuuu_tpu.parallel.moe import held_experts, round_rows_for, sigmoid_topk_route
+@pytest.mark.parametrize("route", ["xla", "kernels"])
+def test_held_experts_compile_for_v5e_at_the_cells_sizes(topo, one_chip, route, grad):
+    """8 of 512 experts, top-22, latent 1024, width 2688, 8192 tokens: round 0 and the overflow rounds.
+    Outside any mesh a block's products are XLA's against gathered weights; inside the described
+    chip's mesh, as the trainer's step traces them, they are the kernel pair of `ops/grouped.py`,
+    which leaves no copy of the weights a block and no weight gradient a block in the program."""
+    from distribuuuu_tpu.obs.monitors import MonitoringBridge
+    from distribuuuu_tpu.ops.interpret import set_pallas_interpret
+    from distribuuuu_tpu.parallel import moe
 
-    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
     t, e, held, d, f = 8192, 512, 8, 1024, 2688
-    rows = round_rows_for(t, 22, e, held)
+    rows = moe.round_rows_for(t, 22, e, held)
+    blocks = rows // moe.BLOCK
 
     def mix(x, logits, w1, w2):
-        idx, w = sigmoid_topk_route(logits, 22, jnp.zeros((e,)), 5.0)
-        y, counts = held_experts(x, idx, w, w1, w2, 0, rows)
+        idx, w = moe.sigmoid_topk_route(logits, 22, jnp.zeros((e,)), 5.0)
+        y, counts = moe.held_experts(x, idx, w, w1, w2, 0, rows)
         return y if not grad else jnp.sum(y)
 
     fn = jax.grad(mix, argnums=(0, 1, 2, 3)) if grad else mix
-    text = _compiles_without_kernels(fn, shape((t, d), jnp.bfloat16), shape((t, e), jnp.float32),
-                                     shape((held, d, f), jnp.float32), shape((held, f, d), jnp.float32))
+    sharding = one_chip
+    if route == "kernels":
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)  # as the trainer's steps are
+        sharding = NamedSharding(mesh, P())
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+    args = (shape((t, d), jnp.bfloat16), shape((t, e), jnp.float32),
+            shape((held, d, f), jnp.float32), shape((held, f, d), jnp.float32))
+    interpret = set_pallas_interpret(False)  # conftest asks for the interpreter; the chip's route does not
+    bridge = MonitoringBridge().install()
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        counters = bridge.snapshot()["counters"]
+    finally:
+        bridge.close()
+        set_pallas_interpret(interpret)
     assert "dtpu.moe_route" in text and "dtpu.moe_experts" in text
     assert text.count(" conditional(") >= 3  # groups of 1, 2, 4, ... rounds of 6400 rows beyond the first
+    copies = [f"[{blocks},{d},{f}]", f"[{blocks},{f},{d}]"]  # a block's own weights, or its own weight gradient
+    if route == "xla":
+        assert "tpu_custom_call" not in text and any(c in text for c in copies)
+        assert moe.GROUPED_CALLS_EVENT not in counters and moe.XLA_CALLS_EVENT not in counters  # no mesh: uncounted
+        return
+    assert counters.get(moe.GROUPED_CALLS_EVENT, 0) >= 1 and moe.XLA_CALLS_EVENT not in counters
+    assert "dtpu_moe_gmm" in text and ("dtpu_moe_tgmm" in text) is grad
+    assert not any(c in text for c in copies)
+    # every kernel call under the experts' scope, and no scatter-add of weight gradients left under the route's
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls and all("dtpu.moe_experts/" in line for line in calls)
+    held_weights = (f"f32[{held},{d},{f}]", f"f32[{held},{f},{d}]")
+    assert not [line for line in text.splitlines()
+                if "dtpu.moe_route" in line and "scatter" in line and any(w in line for w in held_weights)]
 
 
 # ops/moe_kernel.py is refused by Mosaic at every shape its VMEM guard admits

@@ -383,13 +383,18 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(kind):
 
 # -- (e) no token-expert slot is dropped ---------------------------------------------------------------
 
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
 @pytest.mark.parametrize("round_rows,block", [(8, 4), (16, 16), (256, 256)], ids=["five_rounds", "four_rounds", "roomy"])
-def test_no_slot_is_dropped_when_every_token_chooses_the_same_experts(round_rows, block, monkeypatch):
+def test_no_slot_is_dropped_when_every_token_chooses_the_same_experts(round_rows, block, kernels, monkeypatch):
+    """Through both realisations of a block's products: XLA's batched ones, and `ops/grouped.py`'s
+    kernel pair in the interpreter at widths it tiles (the route is steered here, not by an option)."""
     from distribuuuu_tpu.parallel import moe
 
     monkeypatch.setattr(moe, "BLOCK", block)
+    monkeypatch.setattr(moe, "_takes_the_kernels", lambda *_: kernels)
 
-    tokens, dim, width, held, k = 64, 16, 24, 4, 3
+    tokens, held, k = 64, 4, 3
+    dim, width = (128, 256) if kernels else (16, 24)
     ks = jax.random.split(jax.random.key(0), 4)
     x = jax.random.normal(ks[0], (tokens, dim))
     w1, w2 = 0.3 * jax.random.normal(ks[1], (held, dim, width)), 0.3 * jax.random.normal(ks[2], (held, width, dim))
@@ -424,6 +429,29 @@ def test_router_takes_top_k_of_score_plus_bias_and_scales_the_chosen_scores():
     assert moe.round_rows_for(8192, 22, 512, 8) == 6400 and moe.round_rows_for(8, 22, 512, 8) == 8 * 256
 
 
+@pytest.mark.parametrize("k", [1, 3, 8])
+def test_the_chosen_scores_are_the_gathered_ones_bit_for_bit(k):
+    """The router picks the chosen scores by comparison (one fused pass on a TPU); what it returns and
+    what it sends back to the logits is what `take_along_axis` would, to the last bit."""
+    from distribuuuu_tpu.parallel import moe
+
+    rng = np.random.default_rng(k)
+    logits = jnp.asarray(3.0 * rng.standard_normal((40, 16)), jnp.float32)
+    bias, weight = jnp.asarray(rng.standard_normal(16), jnp.float32), jnp.asarray(rng.standard_normal((40, k)), jnp.float32)
+
+    def gathered(logits):
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + bias, k)
+        chosen = jnp.take_along_axis(scores, idx, axis=-1)
+        return idx, 2.5 * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+
+    routed = lambda logits: moe.sigmoid_topk_route(logits, k, bias, 2.5)
+    for got, want in zip(routed(logits), gathered(logits)):
+        np.testing.assert_array_equal(got, want)
+    grad = lambda f: jax.grad(lambda x: jnp.sum(f(x)[1] * weight))(logits)
+    np.testing.assert_array_equal(grad(routed), grad(gathered))
+
+
 # -- (f) scopes in the compiled step, counters in the journal ------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -438,9 +466,9 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _lm_run(cfg, pattern: str = "EM*"):
+def _lm_run(cfg, pattern: str = "EM*", sizes: dict = SHARE):
     cfg.TRAIN.TASK, cfg.OPTIM.OPTIMIZER, cfg.LM.LOSS_BLOCK = "lm", "adafactor", 16
-    model = model_of(pattern, SHARE)
+    model = model_of(pattern, sizes)
     mesh = data_mesh(1)
     state, tx = trainer.create_train_state(model, jax.random.key(0), mesh, 0)
     return mesh, state, trainer.make_train_step(model, tx, mesh, topk=5)
@@ -458,6 +486,23 @@ def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compi
     assert any("jvp(dtpu.loss)" in n for n in names) and any("dtpu.optimizer" in n for n in names)
 
 
+def test_compiled_step_places_every_kernel_call_under_the_experts_scope(fresh_cfg, no_compile_cache, monkeypatch):
+    """With the kernel pair taken (interpreted here), forward and backward: the benchmark reads
+    `moe_experts_ms` by this scope, and a backward kernel outside it would be time it never sees."""
+    from distribuuuu_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_takes_the_kernels", lambda *_: True)
+    mesh, state, step = _lm_run(fresh_cfg, sizes=dict(SHARE, latent=128, expert_width=256))  # widths the kernels tile
+    batch = {"tokens": tokens_of(0, SHARE["vocab"])}
+    text = step.lower(state, batch, jnp.float32(0.1), jax.random.key(1)).as_text(debug_info=True)
+    calls = [n for n in re.findall(r'"(jit\([^"]*)"', text) if "/pallas_call" in n]
+    for kernel, passes in (("dtpu_moe_gmm", ("jvp(", "transpose(")), ("dtpu_moe_tgmm", ("transpose(",))):
+        of_kernel = [n for n in calls if f"/{kernel}/" in n]
+        assert of_kernel and all("/dtpu.moe_experts/" in n for n in of_kernel), kernel
+        for mark in passes:
+            assert any(mark in n for n in of_kernel), f"no {kernel} call under {mark}"
+
+
 class _Loader:
     def __init__(self, batches):
         self.batches = batches
@@ -473,6 +518,8 @@ class _Loader:
 
 
 def test_window_records_carry_the_routing_counters(fresh_cfg, tmp_path):
+    from distribuuuu_tpu.parallel import moe
+
     resilience.reset_run_stats()
     resilience.clear_preemption()
     fresh_cfg.OUT_DIR, fresh_cfg.TRAIN.PRINT_FREQ, fresh_cfg.TRAIN.BATCH_SIZE = str(tmp_path), 2, ROWS
@@ -491,6 +538,9 @@ def test_window_records_carry_the_routing_counters(fresh_cfg, tmp_path):
     for w in windows:
         assert 0.3 * slots < w["moe_slots_here"] < 3 * slots
         assert 1.0 <= w["moe_load_max_over_mean"] < SHARE["experts_held"] + 1e-6
+        # the rows computed: the slots in whole blocks of one expert, a window's mean step (two-step windows)
+        assert w["moe_slots_here"] <= w["moe_rows_here"] <= w["moe_slots_here"] + SHARE["experts_held"] * moe.BLOCK
+        assert (2 * w["moe_rows_here"]) % moe.BLOCK == 0
 
 
 def test_prefetch_ships_what_the_batch_holds(fresh_cfg):
