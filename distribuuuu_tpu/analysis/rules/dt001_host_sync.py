@@ -27,7 +27,7 @@ Whitelisted sync points (not flagged):
   (``it == len(loader) - 1``): that is the PRINT_FREQ batching idiom;
 * a *bare statement* ``jax.device_get(x)`` / ``block_until_ready(x)``
   whose value is discarded: a deliberate, self-documenting barrier (the
-  benchmark gating idiom — ``bench.py`` cadence loops);
+  benchmark gating idiom of a timed loop);
 * values already fetched via ``device_get`` (host-bound names).
 """
 

@@ -53,16 +53,9 @@ _C.MODEL.STEM_S2D = False
 # boundary through one VMEM-resident Pallas pass instead of XLA's separate
 # fusions. Bitwise-identical output/grads to the unfused path (oracle-
 # equality pinned in tests/test_epilogue.py; SyncBN/BN_DTYPE semantics
-# unchanged — stats stay in flax code). Tri-state: None (default) holds no
-# opinion and lets the perfdb verdict registry decide per shape class (off
-# until a soak-measured >1× flips it, `scripts/soak_fused_attn.py
-# --epilogue`); True/False pin the routing; the DTPU_FUSED_EPILOGUE env var
-# overrides this knob either way (the bench A/B arm).
-_C.MODEL.FUSED_EPILOGUE = None
-# Fused MoE dispatch/combine kernels (ops/moe_kernel.py) for switch_moe —
-# same tri-state contract as FUSED_EPILOGUE: None defers to the registry,
-# True/False pin, DTPU_FUSED_MOE env beats all of it.
-_C.MODEL.FUSED_MOE = None
+# unchanged — stats stay in flax code). Off until the kernels have an
+# end-to-end verdict on the chip (PERF.md).
+_C.MODEL.FUSED_EPILOGUE = False
 # Sequence-parallel attention formulation once MESH.SEQ > 1 (parallel/seq.py,
 # docs/PARALLELISM.md "The seq axis"): "ring" rotates K/V blocks over the seq
 # axis (P-1 ppermute neighbor hops, any head count, O(L_local²) memory);
@@ -369,12 +362,6 @@ _C.OBS.METRICS_PORT = 0
 _C.OBS.METRICS_HOST = "127.0.0.1"
 # Journal tail cadence for the live aggregators (sidecar / fleet / agent).
 _C.OBS.TAIL_INTERVAL_S = 2.0
-# Kernel-verdict registry path (obs/perfdb.py, docs/PERFORMANCE.md): where
-# switch_* routing looks up measured flip verdicts, autotuned block sizes,
-# and measured matmul ceilings at trace time. "" (default) = the committed
-# repo-local perfdb/registry.json; a gs:// path shares one registry across
-# a fleet; the DTPU_PERFDB env var beats this knob ("0"/"off" disables).
-_C.OBS.PERFDB = ""
 
 # In-job supervision (TPU addition; docs/FAULT_TOLERANCE.md "Supervised
 # runs"). `python -m distribuuuu_tpu.agent --cfg ...` launches the training

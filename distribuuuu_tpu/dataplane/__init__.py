@@ -1,8 +1,8 @@
 """dtpu-dataplane: disaggregated pod-scale input service (docs/DATA.md).
 
 The per-host thread-producer loader (data/loader.py) is a per-host ceiling:
-at the measured 2355 img/s/chip a v5e-16 pod needs ~38k decoded+augmented
-images/sec, more than one host's cores can decode. This package is the
+at `resnet50.train`'s 2622.2 img/s/chip (PERF_LEDGER.jsonl, PR 30) a v5e-16
+pod needs ~42k decoded+augmented images/sec, more than one host's cores can decode. This package is the
 tf.data-service-shaped answer (Audibert et al., 2023): decode once on a
 horizontally scalable CPU worker tier, serve many hosts, epochs and
 concurrent fleet-queue jobs from one cache.

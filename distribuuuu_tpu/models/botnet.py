@@ -113,7 +113,7 @@ class MHSA(nn.Module):
     dim_v: int = 128
     rel_pos_emb: bool = False
     dtype: Any = jnp.bfloat16
-    fuse: bool | None = None  # None = auto: Pallas kernel on TPU, XLA elsewhere
+    fuse: bool = False  # the Pallas kernels in place of XLA's einsums
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -136,26 +136,13 @@ class MHSA(nn.Module):
         pos = pos_cls(
             height=self.fmap_size[0], width=self.fmap_size[1], dim_head=dqk, name="pos_emb"
         )
-        fuse = self.fuse
-        if fuse is None:
-            # The 2026-07-31 on-chip A/B measured the Pallas kernel LOSING
-            # to XLA's fused attention at BoTNet shapes — abs-fused 0.77x in
-            # the soak, botnet50 end-to-end 1545 vs 1834 img/s
-            # (round-5 session #2); that verdict is
-            # seeded in the perfdb registry as flip=False for the L~196
-            # class. `switch_attention` resolves DTPU_FUSED_ATTN env > the
-            # registry's per-shape-class verdict > off, so a large-L soak
-            # win flips only its own shapes while L~196 stays on XLA.
-            from distribuuuu_tpu.ops.attention import switch_attention
-
-            fuse = switch_attention(h * w, dqk, dv)
         # the interpreter is something a process asks for (ops/interpret.py:
         # the CPU test suite does), never a consequence of the platform — a
         # fused route on a machine without its chip fails in the compiler
         from distribuuuu_tpu.ops.interpret import pallas_interpret
 
         interpret = pallas_interpret()
-        if fuse and not self.rel_pos_emb:
+        if self.fuse and not self.rel_pos_emb:
             # abs-bias fast path: hand the kernel the [L, dqk] table and let
             # it form q·embᵀ in VMEM — skips writing+reading the [B,N,L,L]
             # bias product through HBM (ops/attention.py, "Absolute-position
@@ -163,7 +150,7 @@ class MHSA(nn.Module):
             from distribuuuu_tpu.ops import fused_attention_abs
 
             out = fused_attention_abs(q, k, v, pos(q, return_table=True), interpret=interpret)
-        elif fuse:
+        elif self.fuse:
             out = fused_attention(q, k, v, pos(q), interpret=interpret)
         else:
             out = xla_attention(q, k, v, pos(q))

@@ -215,16 +215,12 @@ def bn_epilogue(
     The unfused default is *literally* the pre-existing block code
     (`batch_norm` + add + `nn.relu`) — zero semantic change when
     `ops.epilogue.switch_epilogue` says off (the shipping default). Fused
-    (``DTPU_FUSED_EPILOGUE=1`` / ``MODEL.FUSED_EPILOGUE`` / a perfdb
-    registry flip for this (rows, channels) shape class) swaps in
+    (``MODEL.FUSED_EPILOGUE``) swaps in
     :class:`EpilogueBatchNorm` under the same module ``name``, so the
     variable tree — and therefore checkpoints, the torch converter, and
     pretrained loading — is identical either way.
     """
-    rows = 1
-    for s in x.shape[:-1]:
-        rows *= int(s)
-    if not switch_epilogue(rows=rows, channels=int(x.shape[-1])):
+    if not switch_epilogue():
         y = batch_norm(
             train=train,
             axis_name=axis_name,

@@ -3,16 +3,6 @@
     python -m distribuuuu_tpu.obs summarize exp/telemetry.jsonl
     python -m distribuuuu_tpu.obs validate  exp/telemetry.jsonl
     python -m distribuuuu_tpu.obs export --out-dir exp --port 9100
-    python -m distribuuuu_tpu.obs perfdb show [--format md] [--registry P]
-    python -m distribuuuu_tpu.obs perfdb diff CANDIDATE [--against P] \
-        [--tolerance 0.9] [--no-calibrate]
-
-``perfdb`` is the kernel-verdict registry plane (obs/perfdb.py):
-``show`` renders the registry (``--format md`` emits the table
-docs/PERFORMANCE.md embeds); ``diff`` is the CI perf-regression gate —
-it compares a run's registry against the committed one with
-machine-speed calibration on absolute-unit (bench) rows and exits 1 on
-any regression beyond tolerance.
 
 ``export`` is the live-telemetry sidecar for plain training runs
 (docs/OBSERVABILITY.md "Live metrics"): it tails the journal incrementally,
@@ -60,29 +50,7 @@ def main(argv: list[str] | None = None) -> int:
                        help="journal tail cadence, seconds")
     p_exp.add_argument("--once", action="store_true",
                        help="poll everything, print metrics text, exit")
-    p_pdb = sub.add_parser(
-        "perfdb", help="kernel-verdict registry: show / diff (CI perf gate)"
-    )
-    pdb_sub = p_pdb.add_subparsers(dest="perfdb_command", required=True)
-    p_show = pdb_sub.add_parser("show", help="render the registry")
-    p_show.add_argument("--registry", default=None,
-                        help="registry path (default: active registry)")
-    p_show.add_argument("--format", choices=("text", "md"), default="text",
-                        help="md emits the PERFORMANCE.md verdict table")
-    p_diff = pdb_sub.add_parser(
-        "diff", help="gate a candidate registry against the committed one"
-    )
-    p_diff.add_argument("candidate", help="registry written by this run")
-    p_diff.add_argument("--against", default=None,
-                        help="committed registry (default: active registry)")
-    p_diff.add_argument("--tolerance", type=float, default=0.9,
-                        help="regression floor as a fraction (default 0.9)")
-    p_diff.add_argument("--no-calibrate", action="store_true",
-                        help="skip machine-speed calibration (scale=1)")
     args = ap.parse_args(argv)
-
-    if args.command == "perfdb":
-        return _perfdb_main(args)
 
     if args.command == "validate":
         errors = validate_journal(args.journal)
@@ -125,62 +93,6 @@ def main(argv: list[str] | None = None) -> int:
         print(f"cannot read journal: {exc}", file=sys.stderr)
         return 1
     sys.stdout.write(report)
-    return 0
-
-
-def _perfdb_main(args) -> int:
-    from distribuuuu_tpu.obs import perfdb
-
-    if args.perfdb_command == "show":
-        path = args.registry or perfdb.registry_path()
-        if path is None:
-            print("perfdb is disabled (DTPU_PERFDB)", file=sys.stderr)
-            return 1
-        try:
-            data = perfdb.load_registry(path)
-        except FileNotFoundError:
-            print(f"no registry at {path}", file=sys.stderr)
-            return 1
-        except perfdb.PerfDBError as exc:
-            print(f"cannot read registry: {exc}", file=sys.stderr)
-            return 1
-        render = perfdb.render_md if args.format == "md" else perfdb.render_text
-        sys.stdout.write(render(data))
-        return 0
-
-    # diff: the CI perf-regression gate
-    against = args.against or perfdb.registry_path()
-    if against is None:
-        print("perfdb is disabled (DTPU_PERFDB)", file=sys.stderr)
-        return 1
-    try:
-        committed = perfdb.load_registry(against)
-        candidate = perfdb.load_registry(args.candidate)
-    except (FileNotFoundError, perfdb.PerfDBError) as exc:
-        print(f"cannot read registry: {exc}", file=sys.stderr)
-        return 1
-    scale = 1.0 if args.no_calibrate else perfdb.machine_scale()
-    result = perfdb.diff_registries(
-        committed, candidate, tolerance=float(args.tolerance), scale=scale
-    )
-    for kind in ("new", "missing", "unchanged", "improvements"):
-        for line in result[kind]:
-            print(f"  [{kind[:-1] if kind.endswith('s') else kind}] {line}")
-    for line in result["regressions"]:
-        print(f"  [REGRESSION] {line}", file=sys.stderr)
-    n = len(result["regressions"])
-    if n:
-        print(
-            f"PERF REGRESSION: {n} entr(y/ies) below tolerance "
-            f"{args.tolerance} (machine scale {scale:.2f})",
-            file=sys.stderr,
-        )
-        return 1
-    print(
-        f"perfdb diff OK: {len(result['unchanged']) + len(result['improvements'])} "
-        f"within tolerance, {len(result['new'])} new, "
-        f"{len(result['missing'])} unmeasured (machine scale {scale:.2f})"
-    )
     return 0
 
 

@@ -1,9 +1,7 @@
 """Step-time attribution: the per-op trace folded into roofline buckets.
 
-Round 5 measured 45% of the resnet50 step outside the matmuls —
-a number produced once, by hand, from a profile export. This module makes it
-standing telemetry: the profiler's per-op table (`obs/traceparse.py`) is
-folded into five buckets —
+The share of a step outside the matmuls as standing telemetry: the
+profiler's per-op table (`obs/traceparse.py`) is folded into five buckets —
 
     matmul      convolution / dot / einsum fusions (MXU work)
     vector      everything else on the device tracks (VPU: BN, relu,
@@ -17,9 +15,7 @@ folded into five buckets —
 
 — journaled as a typed ``step_attribution`` record beside every ``profile``
 record, rendered by ``obs summarize`` as a roofline section, and exported as
-``dtpu_attr_*`` gauges. `scripts/stage_roofline.py` routes its closing
-share arithmetic through `attribute_parts` so the script and the in-run
-profiler agree on what "outside the matmuls" means.
+``dtpu_attr_*`` gauges.
 
 Classification is by substring on the fusion-category name (the op name
 with the ``.N`` instance suffix stripped, `traceparse.summarize_device_ops`
@@ -139,8 +135,9 @@ def attribution_record(
     trigger: str | None = None,
 ) -> dict:
     """Journal-ready ``step_attribution`` fields for one profiled window
-    (device kind + measured ceiling attached when a backend/registry has
-    them, so the roofline section can state MFU context inline)."""
+    (device kind + the peak of `obs/flops.peak_flops_per_device` attached
+    when the backend has them, so the roofline section can state MFU context
+    inline)."""
     rec = attribute_logdir(logdir, steps)
     rec["logdir"] = str(logdir)
     if gstep is not None:
@@ -150,29 +147,16 @@ def attribution_record(
     try:
         import jax
 
-        kind = jax.devices()[0].device_kind
-        rec["device_kind"] = kind
-        from distribuuuu_tpu.obs import perfdb
+        from distribuuuu_tpu.config import cfg
+        from distribuuuu_tpu.obs.flops import peak_flops_per_device
 
-        rec["ceiling_tflops"] = perfdb.measured_ceiling_tflops(kind)
+        device = jax.devices()[0]
+        rec["device_kind"] = device.device_kind
+        peak = peak_flops_per_device(device, cfg.OBS.PEAK_TFLOPS_PER_DEVICE)
+        rec["ceiling_tflops"] = None if peak is None else peak / 1e12
     except Exception:
         pass
     return rec
-
-
-def attribute_parts(parts: Mapping[str, float]) -> dict[str, float]:
-    """Named measured parts -> per-bucket totals (same units in as out).
-
-    The share-arithmetic dedupe path for scripts that measure components by
-    name instead of walking a trace (`stage_roofline.py`'s
-    ``{"conv s1 3x3": ms, ...}``): each part name is classified with the
-    same markers as trace ops, so script-side and trace-side attribution
-    can't drift apart.
-    """
-    out = {b: 0.0 for b in BUCKETS}
-    for name, value in parts.items():
-        out[classify_op(str(name))] += float(value)
-    return out
 
 
 def render_roofline(rec: Mapping[str, Any]) -> list[str]:
@@ -206,5 +190,5 @@ def render_roofline(rec: Mapping[str, Any]) -> list[str]:
             )
     ceiling = rec.get("ceiling_tflops")
     if ceiling:
-        lines.append(f"    measured matmul ceiling: {float(ceiling):g} TFLOP/s")
+        lines.append(f"    peak: {float(ceiling):g} TFLOP/s per device")
     return lines

@@ -2,7 +2,7 @@
 
 Model FLOPs utilization — achieved model FLOPs/s over the hardware's peak —
 is the standard single-number efficiency instrument for large accelerator
-runs (the PaLM-report convention). Three pieces live here:
+runs (the PaLM-report convention). Two pieces live here:
 
 - **Analytical step cost** (`lowered_step_cost`): the XLA cost model run on
   the *lowered, uncompiled* step (``jitted.lower(...).cost_analysis()``).
@@ -10,10 +10,6 @@ runs (the PaLM-report convention). Three pieces live here:
   trainer can price its own step without adding a compile (CompileGuard
   stays at exactly 1; pinned in tests/test_obs.py). The lowered module is
   the pre-partitioning *global* program, so its flops are per global step.
-- **Compiled step cost** (`compiled_step_cost`): the same query against the
-  compiled per-device executable — the path `scripts/cost_analysis.py`
-  prints; it compiles, so it is for offline analysis only, never the
-  training path.
 - **Peak-FLOPs table + `mfu`**: per-device peak dense bf16 FLOPs by
   ``device_kind`` (a JAX "device" is a core on v2/v3 and a chip from v4 on —
   the table is per *device* so the arithmetic never needs to know). Unknown
@@ -46,12 +42,8 @@ def peak_flops_per_device(device=None, override_tflops: float = 0.0) -> float | 
     """Peak dense FLOP/s for one JAX device, or None when unknown.
 
     ``override_tflops`` (``cfg.OBS.PEAK_TFLOPS_PER_DEVICE``) wins when > 0;
-    next a perfdb-measured matmul ceiling for this ``device_kind``
-    (`scripts/stage_roofline.py` writes it — MFU on a new chip is then
-    measured rather than fabricated, and on a known chip it is the
-    *achievable* ceiling, not the datasheet number); last the static table
-    (longest matching key, so "TPU v5 lite" resolves before "TPU v5").
-    CPU/unknown → None.
+    else the static table (longest matching key, so "TPU v5 lite" resolves
+    before "TPU v5"). CPU/unknown → None.
     """
     if override_tflops and override_tflops > 0:
         return float(override_tflops) * 1e12
@@ -59,16 +51,7 @@ def peak_flops_per_device(device=None, override_tflops: float = 0.0) -> float | 
         import jax
 
         device = jax.devices()[0]
-    raw_kind = getattr(device, "device_kind", "") or ""
-    try:  # the registry is optional context, never a failure mode for MFU
-        from distribuuuu_tpu.obs import perfdb
-
-        measured = perfdb.measured_ceiling_tflops(raw_kind)
-    except Exception:
-        measured = None
-    if measured:
-        return float(measured) * 1e12
-    kind = raw_kind.lower()
+    kind = (getattr(device, "device_kind", "") or "").lower()
     best = None
     for key, tflops in _PEAK_BF16_TFLOPS.items():
         if key in kind and (best is None or len(key) > len(best[0])):
@@ -100,20 +83,6 @@ def lowered_step_cost(step_fn, *args, **kwargs) -> dict[str, float] | None:
         return _normalize_cost(lowered.cost_analysis())
     except Exception as exc:  # any backend/version gap: MFU is optional
         logger.info(f"step cost analysis unavailable ({exc!r}); MFU disabled")
-        return None
-
-
-def compiled_step_cost(step_fn, *args, **kwargs) -> dict[str, float] | None:
-    """FLOPs/bytes of the compiled **per-device** executable.
-
-    This compiles (and on the training step would double-compile it) — it
-    exists for offline tools (`scripts/cost_analysis.py`), not the trainer.
-    """
-    try:
-        compiled = step_fn.lower(*args, **kwargs).compile()
-        return _normalize_cost(compiled.cost_analysis())
-    except Exception as exc:
-        logger.info(f"compiled cost analysis unavailable ({exc!r})")
         return None
 
 

@@ -601,28 +601,6 @@ SCHEMA: dict[str, tuple[dict[str, tuple], dict[str, tuple]]] = {
         {"gstep": _INT, "steps": _INT, "logdir": _STR},
         {"device_ms_per_step": _NUM_OR_NONE, "top_ops": _LIST, "trigger": _STR},
     ),
-    # one measured kernel verdict entering the perfdb registry
-    # (obs/perfdb.record_verdict): `transition` records whether this
-    # measurement flipped/unflipped the routing default for its
-    # (device_kind, kernel_family, shape_class) key
-    "kernel_verdict": (
-        {
-            "kernel_family": _STR,
-            "device_kind": _STR,
-            "shape_class": _STR,
-            "speedup": _NUM,
-            "flip": _BOOL,
-            "source": _STR,
-        },
-        {
-            "fused_ms": _NUM,
-            "baseline_ms": _NUM,
-            "interpret": _BOOL,
-            "transition": _STR,
-            "block": _INT,
-            "numerics": _STR,
-        },
-    ),
     # step time folded into matmul/vector/collective/infeed/host buckets
     # (obs/attribution) — the profiler's per-op table as standing roofline
     # telemetry, written beside each `profile` record

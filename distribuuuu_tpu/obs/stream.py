@@ -383,10 +383,6 @@ class LiveAggregator:
                         self._gauge(f"attr_{bucket}_ms", float(ms))
             if isinstance(r.get("matmul_pct"), (int, float)):
                 self._gauge("attr_matmul_pct", float(r["matmul_pct"]))
-        elif kind == "kernel_verdict":
-            self._count("kernel_verdicts_total")
-            if r.get("transition") in ("flip", "unflip"):
-                self._count("kernel_flips_total")
         elif kind == "ingress_start":
             # the router's own birth record (serve/ingress.py): role as a
             # gauge so dtpu_ingress_role flips 1→0 on a demotion

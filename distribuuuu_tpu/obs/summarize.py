@@ -795,26 +795,6 @@ def render(records: Iterable[dict]) -> str:
         for line in render_roofline(a):
             out(line)
 
-    # -- kernel verdicts (perfdb registry transitions) -----------------------
-    if by_kind["kernel_verdict"]:
-        flips = [
-            r for r in by_kind["kernel_verdict"]
-            if r.get("transition") in ("flip", "unflip")
-        ]
-        out("")
-        out(
-            f"kernel verdicts: {len(by_kind['kernel_verdict'])} recorded, "
-            f"{len(flips)} default transition(s)"
-        )
-        for r in by_kind["kernel_verdict"][-10:]:
-            trans = r.get("transition", "none")
-            mark = {"flip": " → FLIPPED ON", "unflip": " → UNFLIPPED"}.get(trans, "")
-            out(
-                f"  {r['kernel_family']} [{r['shape_class']}] on "
-                f"{r['device_kind']}: {r['speedup']:.3f}x "
-                f"({r.get('source', '?')}){mark}"
-            )
-
     return "\n".join(lines) + "\n"
 
 

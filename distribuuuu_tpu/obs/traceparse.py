@@ -4,9 +4,7 @@ TensorBoard isn't available on headless pods, so the per-op device-time
 breakdown is computed directly from the profiler's trace export
 (``plugins/profile/<run>/*.trace.json.gz``): aggregate complete ('X') events
 on device tracks by op name, fold instance suffixes into fusion categories.
-Lifted out of ``scripts/profile_step.py`` (which now imports from here) so
-the programmatic profiler windows (`obs/profiler.py`) can journal the same
-table the script prints.
+The programmatic profiler windows (`obs/profiler.py`) journal this table.
 """
 
 from __future__ import annotations

@@ -13,10 +13,9 @@ the v5e the pair took `vit_b16.train`'s twelve ``block*/attn`` from 109.9 to
 **Biased attention for BoTNet's MHSA** (reference
 `/root/reference/distribuuuu/models/botnet.py:193-215`):
 ``softmax(q·kᵀ + pos_bias)·v`` over L = H·W ≈ 196 tokens, one (batch, head)
-tile a grid step, opt-in through `switch_attention` (``DTPU_FUSED_ATTN`` or
-a perfdb verdict; off by default). What these kernels pay that the bias-free
-pair does not: a float32 ``[L, L]`` bias read from HBM per (batch, head) (as
-many bytes as one of the passes they save; the ``abs`` variant forms it
+tile a grid step, opt-in through ``MHSA(fuse=True)`` (off by default). What
+these kernels pay that the bias-free pair does not: a float32 ``[L, L]`` bias
+read from HBM per (batch, head) (as many bytes as one of the passes they save; the ``abs`` variant forms it
 in-kernel from the ``[L, D]`` table instead), q, k and v relaid to
 ``[B·H, L, d]`` before the call, and a backward that is XLA einsums in
 float32 (`_bwd`), which writes every L×L tensor back to HBM. An earlier
@@ -95,77 +94,18 @@ def _tile_vmem_bytes_blockwise(
     return 2 * (inputs + outputs) + intermediates
 
 
-def candidate_blocks(
-    l: int, d: int, dv: int, itemsize: int, bias_input: bool
-) -> list[int]:
-    """Every legal block size for this shape — sublane-aligned divisors of L
-    (≥2 blocks, ≤ _BLOCK_MAX) whose blockwise estimate fits the budget,
-    largest first. The greedy `_pick_block` takes the head; the autotune
-    soak (`perfdb.autotune` via ``soak_fused_attn.py --seq --autotune``)
-    measures the whole list on-chip and caches the winner, which is not
-    always the largest tile (a smaller block can pipeline better)."""
+def _pick_block(l: int, d: int, dv: int, itemsize: int, bias_input: bool):
+    """Block size for the blockwise re-tile: the largest sublane-aligned
+    divisor of L (≥2 blocks, ≤ _BLOCK_MAX) whose blockwise estimate fits the
+    budget; None when the shape can't re-tile (→ XLA fallback)."""
     budget = _VMEM_GUARD.budget_bytes()
     start = min(_BLOCK_MAX, l // 2)
     start -= start % _BLOCK_ALIGN  # walk aligned values only
-    out = []
     for b in range(start, _BLOCK_ALIGN - 1, -_BLOCK_ALIGN):
         if l % b == 0:
             if _tile_vmem_bytes_blockwise(b, b, d, dv, itemsize, bias_input) <= budget:
-                out.append(b)
-    return out
-
-
-def _pick_block(l: int, d: int, dv: int, itemsize: int, bias_input: bool):
-    """Block size for the blockwise re-tile: the registry's autotuned winner
-    for this shape class when one was measured (re-validated — it must still
-    divide L and fit the CURRENT budget), else the largest legal candidate;
-    None when the shape can't re-tile (→ XLA fallback)."""
-    from distribuuuu_tpu.obs import perfdb
-
-    win = perfdb.registry_block(
-        "attention_blk", perfdb.shape_class(l=l, d=d, dv=dv)
-    )
-    if (
-        win
-        and win % _BLOCK_ALIGN == 0
-        and 0 < win <= min(_BLOCK_MAX, l // 2)
-        and l % win == 0
-        and _tile_vmem_bytes_blockwise(win, win, d, dv, itemsize, bias_input)
-        <= _VMEM_GUARD.budget_bytes()
-    ):
-        return win
-    cands = candidate_blocks(l, d, dv, itemsize, bias_input)
-    return cands[0] if cands else None
-
-
-def switch_attention(
-    l: int,
-    d: int = 128,
-    dv: int | None = None,
-    *,
-    fuse: bool | None = None,
-) -> bool:
-    """The fused-attention routing decision for an (L, d, dv) geometry.
-
-    Precedence (`obs/perfdb.resolve_switch`): explicit ``fuse`` >
-    ``DTPU_FUSED_ATTN`` env (the original opt-in) > the verdict registry's
-    measured flip for this device and shape class > off. No cfg layer —
-    attention fusion never grew a YAML knob; the 2026-07-31 measured LOSS at
-    L~196 is seeded into the committed registry as flip=False, so the
-    registry keeps the kernel off at small L even if someone clears the env,
-    while a large-L soak win flips only its own shape class.
-    """
-    from distribuuuu_tpu.obs import perfdb
-
-    decision, _source = perfdb.resolve_switch(
-        "attention",
-        perfdb.shape_class(l=l, d=d, dv=dv if dv is not None else d),
-        explicit=fuse,
-        env_var="DTPU_FUSED_ATTN",
-        cfg=None,
-        default=False,
-    )
-    return decision
+                return b
+    return None
 
 
 def _within_vmem_budget(kind: str, l: int, d: int, dv: int, itemsize: int,

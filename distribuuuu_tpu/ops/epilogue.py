@@ -1,15 +1,12 @@
 """Fused conv-epilogue — Pallas TPU kernels for the resnet hot blocks.
 
-Round-5 on-chip isolation put the whole train step at
-58.2 true TFLOPs = 55% of the measured 107 TF matmul ceiling, with the
-remaining 45% smeared across the BN/ReLU/residual/data-movement edges — not
-concentrated in any single op. v5e resnet training is HBM-bound, and every
-conv→BN→relu→add boundary XLA leaves as separate ``fusion`` ops round-trips
-the conv output through HBM up to three times (BN read+write, add
-read+write, relu). These kernels fuse the whole epilogue — BN-apply
-(scale/shift from running *or* batch stats), the optional residual add, and
-the ReLU — into one VMEM-resident pass over the conv output: one HBM read
-of ``x`` (+ one of the residual), one write of the block output.
+Every conv→BN→relu→add boundary XLA leaves as separate ``fusion`` ops
+round-trips the conv output through HBM up to three times (BN read+write, add
+read+write, relu); PERF.md §5 says where a resnet50 step's time goes on the
+chip. These kernels fuse the whole epilogue — BN-apply (scale/shift from
+running *or* batch stats), the optional residual add, and the ReLU — into one
+VMEM-resident pass over the conv output: one HBM read of ``x`` (+ one of the
+residual), one write of the block output.
 
 The decomposition keeps BN *statistics* outside the kernel, exactly where
 flax computes them (`models/layers.EpilogueBatchNorm`): batch-stat
@@ -28,15 +25,13 @@ than its intermediates are to save), so gradients are exactly the unfused
 path's gradients; grads through the batch statistics flow through the
 unchanged stats code outside the kernel.
 
-Routing via `switch_epilogue` (``DTPU_FUSED_EPILOGUE=1`` env, or
-``MODEL.FUSED_EPILOGUE`` through the trainer, or — when neither holds an
-opinion — the perfdb verdict registry): interpret-verified
-(tests/test_epilogue.py), **off by default** until a >1× on-chip verdict
-from ``scripts/soak_fused_attn.py --epilogue`` lands in the registry and
-flips it — the attention row in docs/PERFORMANCE.md is the cautionary
-precedent. The kernels compile through Mosaic unless a caller asks for the
-Pallas interpreter (``interpret=True``, or `ops.interpret.set_pallas_interpret`
-for the CPU test suite); the platform never picks it.
+Routing via `switch_epilogue`: an explicit argument, else the run's
+``MODEL.FUSED_EPILOGUE`` (default off). Interpret-verified
+(tests/test_epilogue.py) and compiled for the chip (chip_smoke.py); it has no
+end-to-end verdict on the chip yet (PERF.md). The kernels compile through
+Mosaic unless a caller asks for the Pallas interpreter (``interpret=True``, or
+`ops.interpret.set_pallas_interpret` for the CPU test suite); the platform
+never picks it.
 """
 
 from __future__ import annotations
@@ -60,57 +55,25 @@ from distribuuuu_tpu.ops.vmem_guard import VmemBudgetGuard
 _VMEM_GUARD = VmemBudgetGuard("DTPU_EPILOGUE_VMEM_BUDGET_MB")
 
 # cfg.MODEL.FUSED_EPILOGUE lands here for the duration of a trainer run
-# (trainer._model_globals_scoped restores it on return). Tri-state: None
-# means the cfg holds no opinion and the routing falls through to the
-# perfdb verdict registry. Like the BN boundary dtype, the value is read at
-# *trace* time — flipping it requires re-jitting.
-_CFG_FUSED: bool | None = None
+# (trainer._model_globals_scoped restores it on return). Like the BN boundary
+# dtype, the value is read at *trace* time — flipping it requires re-jitting.
+_CFG_FUSED = False
 
 _BLOCK_ROWS_DEFAULT = 256
 
 
-def set_fused_epilogue_default(enabled: bool | None) -> None:
+def set_fused_epilogue_default(enabled: bool) -> None:
     global _CFG_FUSED
-    _CFG_FUSED = None if enabled is None else bool(enabled)
+    _CFG_FUSED = bool(enabled)
 
 
-def get_fused_epilogue_default() -> bool | None:
+def get_fused_epilogue_default() -> bool:
     return _CFG_FUSED
 
 
-def switch_epilogue(
-    fused: bool | None = None,
-    *,
-    rows: int | None = None,
-    channels: int | None = None,
-) -> bool:
-    """Resolve the fused-epilogue routing decision.
-
-    Precedence (`obs/perfdb.resolve_switch`): explicit argument >
-    ``DTPU_FUSED_EPILOGUE`` env var (the ``DTPU_FUSED_ATTN``/
-    ``DTPU_FUSED_MOE`` convention — how the bench/soak A/B arms flip without
-    touching YAMLs) > ``MODEL.FUSED_EPILOGUE`` via the trainer (tri-state;
-    None = no opinion) > the verdict registry's measured flip for this
-    device and (rows, channels) shape class > off. Callers that know the
-    tile geometry (`models/layers.bn_epilogue`) pass ``rows``/``channels``
-    so a soak-measured >1× flips exactly the shapes it measured.
-    """
-    from distribuuuu_tpu.obs import perfdb
-
-    cls = (
-        perfdb.shape_class(r=rows, c=channels)
-        if rows is not None and channels is not None
-        else None
-    )
-    decision, _source = perfdb.resolve_switch(
-        "epilogue",
-        cls,
-        explicit=fused,
-        env_var="DTPU_FUSED_EPILOGUE",
-        cfg=_CFG_FUSED,
-        default=False,
-    )
-    return decision
+def switch_epilogue(fused: bool | None = None) -> bool:
+    """``fused`` when given, else the run's ``MODEL.FUSED_EPILOGUE``."""
+    return _CFG_FUSED if fused is None else bool(fused)
 
 
 # ---------------------------------------------------------------------------
@@ -269,32 +232,6 @@ def _tile_vmem_bytes(t: int, c: int, x_item: int, id_item: int, out_item: int) -
     return 2 * blocks + intermediates + small
 
 
-def candidate_block_rows(
-    rows: int, channels: int, x_item: int, id_item: int, out_item: int
-) -> list[int]:
-    """Row-tile candidates the VMEM guard prices as compilable — the search
-    space `perfdb.autotune` measures on-chip through the soak harness."""
-    budget = _VMEM_GUARD.budget_bytes()
-    out = []
-    for t in (512, 256, 128, 64):
-        if t > rows:
-            continue
-        if _tile_vmem_bytes(t, channels, x_item, id_item, out_item) <= budget:
-            out.append(t)
-    return out
-
-
-def _resolve_block_rows(rows: int, channels: int) -> int:
-    """The autotuned winner for this shape class when the registry has one
-    (re-validated against the row count), else the static default."""
-    from distribuuuu_tpu.obs import perfdb
-
-    win = perfdb.registry_block("epilogue", perfdb.shape_class(r=rows, c=channels))
-    if win is not None and 0 < win:
-        return int(win)
-    return _BLOCK_ROWS_DEFAULT
-
-
 def fused_conv_epilogue(
     x,
     mean,
@@ -304,7 +241,7 @@ def fused_conv_epilogue(
     *,
     relu: bool = True,
     bn_dtype,
-    block_rows: int | None = None,
+    block_rows: int = _BLOCK_ROWS_DEFAULT,
     interpret: bool | None = None,
 ):
     """BN-apply → (+residual) → ReLU over a conv output, fused on TPU.
@@ -317,9 +254,7 @@ def fused_conv_epilogue(
     backward recomputes the oracle formulation with XLA, so gradients equal
     the unfused path's. A row tile too large for VMEM falls back to the
     numerically identical `oracle_epilogue` with a one-time warning instead
-    of failing opaquely inside Mosaic. ``block_rows=None`` (the default)
-    takes the registry's autotuned winner for this shape class when one was
-    measured, else 256.
+    of failing opaquely inside Mosaic.
     """
     if interpret is None:
         # traced from inside model code, where no caller can thread the flag
@@ -328,8 +263,6 @@ def fused_conv_epilogue(
         interpret = pallas_interpret()
     c = int(x.shape[-1])
     r = int(np.prod(x.shape[:-1]))
-    if block_rows is None:
-        block_rows = _resolve_block_rows(r, c)
     t = min(int(block_rows), r)
     out_dtype = (
         jnp.result_type(bn_dtype, identity.dtype) if identity is not None else bn_dtype

@@ -28,9 +28,8 @@ than to save), so gradients are exactly the einsum path's gradients.
 Oracle equality (fwd + grad, including the drop-at-capacity boundary) is
 pinned against the einsum formulation in tests/test_moe_kernel.py via the
 interpret-mode pattern every kernel in this repo uses. Opt-in from
-`switch_moe(..., fused=True)` or ``DTPU_FUSED_MOE=1`` (the
-`DTPU_FUSED_ATTN` convention): interpret-verified, soak on real hardware
-with ``scripts/soak_fused_attn.py --moe`` before flipping a default.
+`switch_moe(..., fused=True)`: interpret-verified only; Mosaic refuses both
+kernels at every shape tried (tests/test_chip_compile.py, ROADMAP.md Design 2).
 """
 
 from __future__ import annotations

@@ -32,8 +32,9 @@ pinned in tests/test_moe.py.
 The one-hot dispatch/combine contractions have a fused alternative: the
 Pallas kernels in `ops/moe_kernel.py` keep the ``[n, E, C]`` mask VMEM-
 resident per token tile instead of materializing it in HBM twice per step
-(``fused=True`` / ``DTPU_FUSED_MOE=1``; oracle-equal fwd + grad, pinned in
-tests/test_moe_kernel.py, soak with ``scripts/soak_fused_attn.py --moe``).
+(``fused=True``; oracle-equal fwd + grad in the interpreter, pinned in
+tests/test_moe_kernel.py; Mosaic has refused them at every shape so far,
+tests/test_chip_compile.py).
 
 Returns the combined output plus the switch load-balancing auxiliary loss
 ``E · Σ_e f_e · P_e`` computed on the LOCAL token shard (the standard
@@ -54,41 +55,6 @@ from jax import lax
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.grouped import grouped_product, grouped_product_fuses
 from distribuuuu_tpu.ops.interpret import pallas_interpret
-
-
-# cfg.MODEL.FUSED_MOE lands here for the duration of a trainer run
-# (trainer._model_globals_scoped restores it); tri-state like the epilogue
-# default — None means no opinion and the perfdb registry decides
-_CFG_FUSED: bool | None = None
-
-
-def set_fused_moe_default(enabled: bool | None) -> None:
-    global _CFG_FUSED
-    _CFG_FUSED = None if enabled is None else bool(enabled)
-
-
-def get_fused_moe_default() -> bool | None:
-    return _CFG_FUSED
-
-
-def resolve_moe_fused(
-    fused: bool | None, n: int, d: int, e: int, capacity: int
-) -> bool:
-    """The fused-dispatch routing decision for one (tokens, dim, experts,
-    capacity) geometry — precedence explicit arg > ``DTPU_FUSED_MOE`` env >
-    ``MODEL.FUSED_MOE`` cfg > the verdict registry's measured flip for this
-    device and shape class > off (`obs/perfdb.resolve_switch`)."""
-    from distribuuuu_tpu.obs import perfdb
-
-    decision, _source = perfdb.resolve_switch(
-        "moe",
-        perfdb.shape_class(n=n, d=d, e=e, c=capacity),
-        explicit=fused,
-        env_var="DTPU_FUSED_MOE",
-        cfg=_CFG_FUSED,
-        default=False,
-    )
-    return decision
 
 
 def token_slot_positions(onehot_e: jnp.ndarray) -> jnp.ndarray:
@@ -114,7 +80,7 @@ def switch_moe(
     *,
     capacity: int,
     axis_name: str = "expert",
-    fused: bool | None = None,
+    fused: bool = False,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Top-1 mixture-of-experts over ``axis_name``.
@@ -128,11 +94,9 @@ def switch_moe(
         Size it ``ceil(n / E) · capacity_factor`` with factor 1.25–2.
       fused: route dispatch/combine through the Pallas kernels in
         `ops/moe_kernel.py` (the ``[n, E, C]`` one-hot mask stays VMEM-
-        resident instead of round-tripping HBM twice). ``None`` (default)
-        resolves via `resolve_moe_fused` — ``DTPU_FUSED_MOE`` env >
-        ``MODEL.FUSED_MOE`` cfg > the perfdb verdict registry > off;
-        oracle equality (fwd + grad, incl. the capacity-drop boundary) is
-        pinned in tests/test_moe_kernel.py.
+        resident instead of round-tripping HBM twice); oracle equality
+        (fwd + grad, incl. the capacity-drop boundary) is pinned in
+        tests/test_moe_kernel.py.
       interpret: run the fused kernels in the Pallas interpreter (CPU
         tests); ignored on the einsum path.
 
@@ -156,7 +120,6 @@ def switch_moe(
             f"'{axis_name}' axis has {e} devices (one expert per device); "
             "tokens routed past the axis would be silently dropped"
         )
-    fused = resolve_moe_fused(fused, n, d, e, capacity)
     if fused:
         from distribuuuu_tpu.ops.moe_kernel import (
             fused_moe_dispatch,

@@ -689,21 +689,12 @@ def _build_cfg_model():
     if bn_dtype == "auto":
         bn_dtype = cfg.MODEL.DTYPE
     set_bn_compute_dtype(jnp.bfloat16 if bn_dtype == "bfloat16" else jnp.float32)
-    # fused-kernel routing defaults (ops/epilogue.py, parallel/moe.py): like
-    # the BN boundary dtype these are process-global reads at trace time,
-    # scoped to the run by _model_globals_scoped. Tri-state: None leaves the
-    # decision to the perfdb verdict registry; DTPU_FUSED_* env overrides.
+    # the fused-epilogue route (ops/epilogue.py): like the BN boundary dtype a
+    # process-global read at trace time, scoped to the run by
+    # _model_globals_scoped
     from distribuuuu_tpu.ops.epilogue import set_fused_epilogue_default
-    from distribuuuu_tpu.parallel.moe import set_fused_moe_default
 
     set_fused_epilogue_default(cfg.MODEL.FUSED_EPILOGUE)
-    set_fused_moe_default(cfg.MODEL.FUSED_MOE)
-    # registry location (OBS.PERFDB; "" = the committed repo-local default,
-    # DTPU_PERFDB env beats it) — consulted lazily at the switch sites
-    if cfg.OBS.PERFDB:
-        from distribuuuu_tpu.obs import perfdb
-
-        perfdb.set_registry_path(cfg.OBS.PERFDB)
     # SYNCBN spans every batch-bearing axis: on a ('data', 'fsdp') mesh the
     # batch shards over both, so stats pmean over the pair — a pure-dp run
     # and an fsdp run of the same device count normalize identically
@@ -1213,21 +1204,15 @@ def _model_globals_scoped(fn):
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         from distribuuuu_tpu.models import layers
-        from distribuuuu_tpu.obs import perfdb
         from distribuuuu_tpu.ops import epilogue
-        from distribuuuu_tpu.parallel import moe
 
         prev = layers.get_bn_compute_dtype()
         prev_fused = epilogue.get_fused_epilogue_default()
-        prev_moe = moe.get_fused_moe_default()
-        prev_perfdb = perfdb._CFG_PATH
         try:
             return fn(*args, **kwargs)
         finally:
             layers.set_bn_compute_dtype(prev)
             epilogue.set_fused_epilogue_default(prev_fused)
-            moe.set_fused_moe_default(prev_moe)
-            perfdb.set_registry_path(prev_perfdb)
 
     return wrapper
 

@@ -17,8 +17,9 @@ Usage: python scripts/bench_input_pipeline.py [--images 256] [--secs 6]
 ``--service`` benches the disaggregated dataplane instead (docs/DATA.md):
 synthetic tar shards → an in-host dtpu-dataplane service at 1/2/4 decode
 workers → client-side `ServiceLoader` img/s, vs the local `HostDataLoader`
-end-to-end rate, and prints the worker count needed for the ~38k img/s a
-v5e-16 pod consumes at the measured 2355 img/s/chip. Emits the same
+end-to-end rate, and prints the worker count needed for the ~42k img/s a
+v5e-16 pod consumes at `resnet50.train`'s 2622.2 img/s/chip (PERF_LEDGER.jsonl,
+PR 30). Emits the same
 one-line JSON blob contract as the default mode.
 """
 
@@ -123,7 +124,7 @@ def bench_loader(root: str, secs: float) -> float:
     return n / (time.perf_counter() - start)
 
 
-POD_IMG_PER_S = 38_000  # v5e-16 at the measured 2355 img/s/chip
+POD_IMG_PER_S = 42_000  # v5e-16 at resnet50.train's 2622.2 img/s/chip (PERF_LEDGER.jsonl, PR 30)
 
 
 def make_shards(root: str, src: str, shard_size: int = 64) -> str:
@@ -197,10 +198,10 @@ def run_service_mode(args) -> None:
         rows["local_e2e"] = round(local, 1)
         print(f"  local loader e2e:  {local:8.1f} img/s")
     rows["img_per_s_per_worker"] = round(per_worker, 1)
-    rows["workers_for_38k_pod"] = int(math.ceil(POD_IMG_PER_S / max(1.0, per_worker)))
+    rows["workers_for_pod"] = int(math.ceil(POD_IMG_PER_S / max(1.0, per_worker)))
     print(
         f"\nservice path: {per_worker:.0f} img/s/worker → "
-        f"{rows['workers_for_38k_pod']} worker(s) of this host's shape for "
+        f"{rows['workers_for_pod']} worker(s) of this host's shape for "
         f"{POD_IMG_PER_S / 1000:.0f}k img/s/pod"
     )
     print(json.dumps({"bench": "input_pipeline_service", **rows}))

@@ -9,10 +9,10 @@ Tiers:
   bitwise the UNFUSED (`nn.BatchNorm` + add + relu) path — eval forward,
   train-mode gradients, and the updated batch statistics — including the
   SyncBN pmean under a 2-device shard_map and the zero-init-residual BN.
-- **routing/guard** — `switch_epilogue` precedence (explicit > env >
-  default), the VMEM-budget fallback's identical numerics + counted
-  fallbacks, and fused/unfused variable-tree identity (checkpoints trained
-  one way load the other).
+- **routing/guard** — the run default routes the model (the rule itself is
+  tests/test_kernel_choice.py), the VMEM-budget fallback's identical
+  numerics + counted fallbacks, and fused/unfused variable-tree identity
+  (checkpoints trained one way load the other).
 """
 
 import numpy as np
@@ -27,7 +27,6 @@ from distribuuuu_tpu.ops.epilogue import (
     fused_conv_epilogue,
     oracle_epilogue,
     set_fused_epilogue_default,
-    switch_epilogue,
 )
 
 
@@ -279,32 +278,20 @@ def test_zero_init_residual_bn_fused_matches(fused_routing):
 # routing + guard
 # ---------------------------------------------------------------------------
 
-def test_switch_epilogue_precedence(monkeypatch):
-    monkeypatch.delenv("DTPU_FUSED_EPILOGUE", raising=False)
-    assert switch_epilogue() is False  # module default
-    assert switch_epilogue(True) is True  # explicit wins
-    monkeypatch.setenv("DTPU_FUSED_EPILOGUE", "1")
-    assert switch_epilogue() is True  # env over default
-    monkeypatch.setenv("DTPU_FUSED_EPILOGUE", "0")
-    set_fused_epilogue_default(True)
-    try:
-        assert switch_epilogue() is False  # env STILL wins over default
-    finally:
-        set_fused_epilogue_default(False)
-    assert switch_epilogue(False) is False
-
-
-def test_env_var_routes_model(monkeypatch):
-    """DTPU_FUSED_EPILOGUE=1 alone flips the model route (the bench A/B
-    arm) — and the output stays bitwise."""
+def test_cfg_routes_model():
+    """``MODEL.FUSED_EPILOGUE`` (as `set_fused_epilogue_default` holds it)
+    alone flips the model route — and the output stays bitwise."""
     from distribuuuu_tpu.convert import golden_inputs
 
     model, variables = _rn18()
     x = jnp.asarray(golden_inputs(2, 32, 5))
     plain = np.asarray(model.apply(variables, x, train=False))
-    monkeypatch.setenv("DTPU_FUSED_EPILOGUE", "1")
     fallbacks = _VMEM_GUARD.fallbacks
-    fused = np.asarray(model.apply(variables, x, train=False))
+    set_fused_epilogue_default(True)
+    try:
+        fused = np.asarray(model.apply(variables, x, train=False))
+    finally:
+        set_fused_epilogue_default(False)
     assert _VMEM_GUARD.fallbacks == fallbacks  # tiny tiles: kernel ran
     np.testing.assert_array_equal(fused, plain)
 

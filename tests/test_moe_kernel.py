@@ -3,8 +3,8 @@
 The oracle-equality pattern every kernel in this repo follows: the fused
 path must match the einsum formulation exactly — forward, gradients, the
 routing metadata, and the drop-at-capacity boundary — before any hardware
-verdict is even interesting (`scripts/soak_fused_attn.py --moe` is the
-on-chip half).
+verdict is even interesting (tests/test_chip_compile.py holds the compiler's
+refusal of both kernels).
 """
 
 import jax
@@ -269,40 +269,3 @@ def test_vmem_budget_guard_falls_back_to_einsum(monkeypatch):
     before = moe_kernel._VMEM_GUARD.fallbacks
     fused_moe_dispatch(x, gate, capacity=capacity, interpret=True)
     assert moe_kernel._VMEM_GUARD.fallbacks == before
-
-
-def test_env_opt_in_routes_to_fused(monkeypatch):
-    """``DTPU_FUSED_MOE=1`` routes switch_moe through the kernels — the
-    DTPU_FUSED_ATTN opt-in convention."""
-    calls = {"n": 0}
-    real = moe_kernel.fused_moe_dispatch
-
-    def counting(*args, **kwargs):
-        calls["n"] += 1
-        return real(*args, **kwargs)
-
-    monkeypatch.setattr(moe_kernel, "fused_moe_dispatch", counting)
-    monkeypatch.setenv("DTPU_FUSED_MOE", "1")
-    mesh = create_mesh({"expert": E})
-    params = _moe_params(jax.random.PRNGKey(3))
-    x = jnp.ones((E, 2, D), jnp.float32)
-
-    def body(experts, x_local):
-        out, _ = switch_moe(
-            x_local[0], params["gate"], jax.tree.map(lambda a: a[0], experts),
-            _expert_fn, capacity=2, axis_name="expert", interpret=True,
-        )
-        return out[None]
-
-    jax.shard_map(
-        body, mesh=mesh, in_specs=(P("expert"), P("expert")),
-        out_specs=P("expert"), check_vma=False,
-    )(params["experts"], x)
-    assert calls["n"] > 0, "env opt-in never reached the fused kernels"
-    monkeypatch.setenv("DTPU_FUSED_MOE", "0")
-    calls["n"] = 0
-    jax.shard_map(
-        body, mesh=mesh, in_specs=(P("expert"), P("expert")),
-        out_specs=P("expert"), check_vma=False,
-    )(params["experts"], x)
-    assert calls["n"] == 0, "DTPU_FUSED_MOE=0 must keep the einsum path"

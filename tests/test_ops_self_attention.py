@@ -108,12 +108,9 @@ def test_route_follows_device_and_shape(kind, l, heads, hd, fuses):
 
 
 def test_route_reads_nothing_but_device_and_shape(monkeypatch):
-    """No environment variable, registry row or setter reaches the route."""
-    for var in ("DTPU_FUSED_ATTN", "DTPU_ATTN_VMEM_BUDGET_MB"):
-        monkeypatch.setenv(var, "0")
+    """The biased family's VMEM budget knob does not reach the route."""
+    monkeypatch.setenv("DTPU_ATTN_VMEM_BUDGET_MB", "0")
     assert self_attention_fuses("TPU v5 lite", 197, 12, 64, 2) is True
-    monkeypatch.setenv("DTPU_FUSED_ATTN", "1")
-    assert self_attention_fuses("cpu", 197, 12, 64, 2) is False
 
 
 def _counted(fn):

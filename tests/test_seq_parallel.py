@@ -376,15 +376,23 @@ def test_seq_zero_steady_state_compiles(fresh_cfg, tmp_path):
     """After the first step compiles, further seq-sharded steps compile
     exactly zero new programs (CompileGuard exact=0 — static shapes, ring
     hops included)."""
+    from jax.sharding import NamedSharding
+
     from distribuuuu_tpu.analysis.guards import CompileGuard
-    from distribuuuu_tpu.benchutil import make_synthetic_batch
 
     _seq_cfg(fresh_cfg, tmp_path, data=2, seq_n=2, impl="ring")
     mesh = data_mesh(2, 1, 2)
     model = trainer._build_cfg_model()
     state, tx = trainer.create_train_state(model, jax.random.PRNGKey(0), mesh, 16)
     step = trainer.make_train_step(model, tx, mesh, topk=5, task="mae")
-    batch = make_synthetic_batch(mesh, _GLOBAL_BATCH, im_size=16)
+    rng = np.random.default_rng(0)
+    rows = NamedSharding(mesh, P("data"))
+    batch = {
+        "image": jax.device_put(
+            rng.integers(0, 256, (_GLOBAL_BATCH, 16, 16, 3), dtype=np.uint8), rows),
+        "label": jax.device_put(rng.integers(0, 1000, _GLOBAL_BATCH).astype(np.int32), rows),
+        "weight": jax.device_put(np.ones((_GLOBAL_BATCH,), np.float32), rows),
+    }
     lr = jnp.asarray(0.01, jnp.float32)
     key = jax.random.PRNGKey(1)
     state, m = step(state, batch, lr, key)
