@@ -12,7 +12,10 @@ TPU-native additions (new sections; absent keys in old YAMLs simply keep default
   statistics always stay float32.
 - ``MODEL.REMAT``: rematerialize (activation-checkpoint) each residual stage —
   the `jax.checkpoint` analog of the reference DenseNet's ``memory_efficient``
-  (`densenet.py:81-108`), available for every model.
+  (`densenet.py:81-108`), available for every model. The token model
+  (`models/nemotron_h.py`) checkpoints each layer under a policy: the values
+  its ``KEPT`` names (the routing and the large projections' results) are
+  stored, the rest of a layer is computed again in the backward pass.
 - ``MESH.*``: device-mesh shape. DATA=-1 means "all visible devices" on the
   data axis (the reference is DP-only, `trainer.py:134`).
 - ``CUDNN.*`` is kept for YAML compatibility and remapped: BENCHMARK is a no-op

@@ -30,6 +30,18 @@ stack it. The layers after the repeats are ``L<i>_<leaf>``; beside them
 `head_logits` maps hidden states to logits, so that a loss can take the
 vocabulary in blocks of tokens (trainer._forward_loss_lm).
 
+With ``remat`` every layer is one `jax.checkpoint` under a policy (`KEPT`): the
+values it names are stored on the way forward in the dtype they have (the
+router's float32 logits and the ids `top_k` chose, so that the routing runs
+once a step; the latent projection; the float32 results of the shared expert's
+first product and of the Mamba mixer's input projection), and everything else
+of the layer is computed again in the backward pass: the norms, `relu²`, the
+convolution, `silu` and the scan, attention's q, k, v and its kernel, the held
+experts' first product, the comparison that picks the chosen scores and the
+gather of the rows. The closing products (``shared2``, ``up``, ``out_proj``,
+``o``, the experts' second) never run twice: nothing of the backward pass reads
+their results. Without ``remat`` there is no checkpoint at all.
+
 Float32: parameters, RMSNorm statistics, the router (scores, top-k, weights),
 softmax, the scan's decays and state (ops/ssm.py). Matrix products and the
 residual stream: ``dtype``.
@@ -45,16 +57,30 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from distribuuuu_tpu.models.registry import register_model
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import self_attention
 from distribuuuu_tpu.ops.ssm import ssd_scan
-from distribuuuu_tpu.parallel.moe import BLOCK, held_experts, round_rows_for, sigmoid_topk_route
+from distribuuuu_tpu.parallel.moe import BLOCK, ROUTE_IDX, held_experts, round_rows_for, sigmoid_topk_route
 
 F32 = jnp.float32
 #: projections back into the residual stream: their init is scaled by 1/sqrt(2·layers_total)
 RESIDUAL_OUT = ("out_proj", "o", "w2", "shared2", "up")
+#: what a layer's checkpoint keeps for the backward pass (``remat=True``), each in the dtype it has; everything
+#: else of the layer is computed again there. One line a name: what keeping it saves, what it costs a token.
+#: The list is the longest that left the chip 0.85 GiB at the benchmark's 8192-token step (PERF.md §5, PR 32)
+KEPT = (
+    "moe_router_logits",  # the router's product at `highest`, the dearest a FLOP: float32, 4 B an expert of the router
+    ROUTE_IDX,            # `top_k`'s full sort over the experts: int32, 4 B a chosen expert (named in parallel/moe.py)
+    "moe_latent",         # `down`: the compute dtype, one element a latent width
+    "moe_shared1",        # the shared expert's first product, the largest of the unit: float32, 4 B a shared width
+    "mamba_in_proj",      # the Mamba mixer's input projection: float32, 4 B an element of z | x B C | dt
+)
+#: `jax.monitoring` event, one per layer traced under that policy (three a trace of ``EMEMEMEMEM*``: the
+#: scanned unit's two layers once each, then the attention layer); the journal's ``counters`` carry it
+REMAT_POLICY_EVENT = "remat_policy_layers"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,7 +204,8 @@ def _mm(x, kernel):
 def mamba_mixer(p: dict, u, s: Sizes):
     b, l, _ = u.shape
     inner, bc = s.mamba_heads * s.mamba_head_dim, s.mamba_groups * s.ssm_state
-    z, xbc, dt = jnp.split(_mm(u, p["in_proj"]), (inner, 2 * inner + 2 * bc), axis=-1)
+    projected = checkpoint_name(_mm(u, p["in_proj"]), "mamba_in_proj")
+    z, xbc, dt = jnp.split(projected, (inner, 2 * inner + 2 * bc), axis=-1)
     # causal depthwise convolution over time, then silu
     padded = jnp.pad(xbc, ((0, 0), (s.conv_kernel - 1, 0), (0, 0)))
     xbc = p["conv_b"] + sum(p["conv_w"][j] * padded[:, j:j + l] for j in range(s.conv_kernel))
@@ -204,12 +231,13 @@ def moe_mixer(p: dict, b_corr, u32, s: Sizes, dtype):
     u32 = u32.reshape(b * l, dim)
     u = u32.astype(dtype)
     with step_scope("moe_route"):
-        logits = jnp.dot(u32, p["router"], precision=lax.Precision.HIGHEST)
-        idx, weights = sigmoid_topk_route(logits, s.top_k, b_corr, s.routed_scale)
-    latent = _mm(u, p["down"]).astype(dtype)
+        logits = checkpoint_name(jnp.dot(u32, p["router"], precision=lax.Precision.HIGHEST), "moe_router_logits")
+        idx, weights = sigmoid_topk_route(logits, s.top_k, b_corr, s.routed_scale)  # names its `idx` itself
+    latent = checkpoint_name(_mm(u, p["down"]).astype(dtype), "moe_latent")
     rows = round_rows_for(b * l, s.top_k, s.experts, s.experts_held)
     mixed, counts = held_experts(latent, idx, weights, p["w1"], p["w2"], s.expert_first, rows)
-    shared = _mm(jnp.square(jax.nn.relu(_mm(u, p["shared1"]))).astype(dtype), p["shared2"])
+    shared = checkpoint_name(_mm(u, p["shared1"]), "moe_shared1")
+    shared = _mm(jnp.square(jax.nn.relu(shared)).astype(dtype), p["shared2"])
     return (_mm(mixed.astype(dtype), p["up"]) + shared).reshape(b, l, dim), counts
 
 
@@ -259,7 +287,22 @@ class NemotronH(nn.Module):
     def __call__(self, tokens, train: bool = False):
         del train  # no dropout, no running statistics
         s = self.sizes
-        one_layer = jax.checkpoint(layer, static_argnums=(0, 4)) if self.remat else layer
+        one_layer = scanned_layer = layer
+        if self.remat:
+            def under_policy(**options):
+                remat_layer = jax.checkpoint(layer, static_argnums=(0, 4),
+                                             policy=jax.checkpoint_policies.save_only_these_names(*KEPT), **options)
+
+                def counted(*args):
+                    jax.monitoring.record_event(REMAT_POLICY_EVENT)  # at trace time: once a layer traced
+                    return remat_layer(*args)
+
+                return counted
+
+            one_layer = under_policy()
+            # `lax.scan` already keeps the compiler from merging the recomputation with the forward pass; the
+            # barrier `prevent_cse` adds inside it cost 3.7 to 4.9 ms of a 280 ms step on the chip (PERF.md §5, PR 32)
+            scanned_layer = under_policy(prevent_cse=False)
 
         h = self.p["embed"][tokens].astype(self.dtype)
         loads = []
@@ -270,7 +313,7 @@ class NemotronH(nn.Module):
                 counts = []
                 for (prefix, kind), (leaves, b_corr) in zip(unit, leaves_and_buffers):
                     with jax.named_scope(prefix):
-                        h, c = one_layer(kind, leaves, b_corr, h, s)
+                        h, c = scanned_layer(kind, leaves, b_corr, h, s)
                     counts += [] if c is None else [c]
                 return h, counts
 

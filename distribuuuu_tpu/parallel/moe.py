@@ -51,6 +51,7 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.grouped import grouped_product, grouped_product_fuses
@@ -206,6 +207,10 @@ def switch_moe(
 BLOCK = 256
 
 
+#: the name `sigmoid_topk_route` gives the chosen ids, for a checkpoint policy to keep (an identity elsewhere)
+ROUTE_IDX = "moe_route_idx"
+
+
 def sigmoid_topk_route(logits, k: int, bias, scale: float):
     """Top-``k`` of ``E`` by sigmoid score, float32 throughout.
 
@@ -216,6 +221,9 @@ def sigmoid_topk_route(logits, k: int, bias, scale: float):
     """
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
+    # named before its first use: a layer checkpoint that keeps `ROUTE_IDX` (models/nemotron_h.KEPT) then
+    # runs the sort once; named on the way out, the comparison below would still hang on the unnamed value
+    idx = checkpoint_name(idx, ROUTE_IDX)
     # the chosen scores by comparison, not `take_along_axis`: the same numbers (a token's choices are
     # distinct, so each sum holds one score and zeros, forward and backward), and one fused pass over
     # [T, k, E] where the gather of T·k single elements took 1.8 ms a call on the chip (PERF.md §5, PR 30)

@@ -118,6 +118,71 @@ def test_loss_and_every_gradient_leaf_match_the_reference(fresh_cfg, pattern, dt
         assert rel(got[name], want_tree[name]) <= grad_tol, name
 
 
+# -- (a') the layer checkpoint's policy: the same numbers, and the routing and the kept products once ----
+
+@pytest.mark.parametrize("pattern", ["EMEMEMEMEM*", "EM*", "EMEM"], ids=["scanned_then_attention", "unscanned", "scanned"])
+def test_remat_under_the_policy_computes_what_no_remat_computes(fresh_cfg, pattern):
+    """A checkpoint chooses what is stored and what is computed again, and adds no cast: float32, the same
+    arithmetic, so the loss and every gradient leaf agree to rounding of the sums' order."""
+    fresh_cfg.LM.LOSS_BLOCK = 16
+    sizes = dict(SHARE, pattern=pattern)
+    params, stats = ref.init(jax.random.key(3), sizes), ref.init_stats(sizes)
+    tokens = tokens_of(5, SHARE["vocab"])
+    plain, remat = model_of(pattern, SHARE, remat=False), model_of(pattern, SHARE, remat=True)
+    want_loss, want = _program_loss_and_grads(plain, *to_program(params, stats, plain), tokens)
+    got_loss, got = _program_loss_and_grads(remat, *to_program(params, stats, remat), tokens)
+    assert abs(float(got_loss) - float(want_loss)) <= 1e-6 * abs(float(want_loss))
+    assert set(got) == set(want)
+    for name in want:
+        assert rel(got[name], want[name]) <= 1e-6, name
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (checkpoint, scan, cond, jit)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_gradient_under_the_policy_routes_once_and_reads_the_kept_values(fresh_cfg, capsys):
+    """The counts themselves, of one trace in this process (`jax.checkpoint` caches the trace of `layer`
+    by the function's identity, so a second variant traced beside it would read the first's jaxpr). With no
+    policy the gradient of one ``E`` layer holds `top_k` twice and four products at `HIGHEST` (the router's:
+    forward, recomputed, and the two of the backward pass). A name kept is named once, on the way forward:
+    the backward pass reads the stored value, where a value not kept would be named again in the recomputation."""
+    m = nh()
+    fresh_cfg.LM.LOSS_BLOCK = 16
+    tokens = tokens_of(5, SHARE["vocab"])
+
+    def gradient_of(pattern):
+        model = model_of(pattern, SHARE, remat=True)
+        tree, buffers = to_program(ref.init(jax.random.key(3), dict(SHARE, pattern=pattern)),
+                                   ref.init_stats(dict(SHARE, pattern=pattern)), model)
+        loss = lambda p: trainer._forward_loss_lm(model, p, buffers, {"tokens": tokens})[0]
+        return loss, tree, list(_equations(jax.make_jaxpr(jax.grad(loss))(tree).jaxpr))
+
+    _, _, eqns = gradient_of("E")
+    assert sum(e.primitive.name == "top_k" for e in eqns) == 1
+    assert sum(e.primitive.name == "dot_general" and "HIGHEST" in str(e.params["precision"]) for e in eqns) == 3
+    loss, tree, eqns = gradient_of("EM")  # no unit repeats: two layers, each under its own checkpoint
+    assert sorted(e.params["name"] for e in eqns if e.primitive.name == "name") == sorted(m.KEPT)  # each once
+    jax.ad_checkpoint.print_saved_residuals(loss, tree)
+    listed = capsys.readouterr().out.splitlines()
+    tokens_here = ROWS * LENGTH
+    # the chosen ids under their name; a float kept passes a `reduce_precision` that keeps the forward's own
+    # value from being merged with it, so it is listed by that op, at its line of the mixer
+    assert any(line.startswith(f"i32[{tokens_here},{SHARE['top_k']}] named '{m.ROUTE_IDX}'") for line in listed)
+    kept_floats = [line.split()[0] for line in listed if "reduce_precision" in line and "_mixer)" in line]
+    in_proj = m.layer_shapes("M", model_of("M", SHARE).sizes)["in_proj"][1]
+    assert sorted(kept_floats) == sorted(
+        [f"f32[{tokens_here},{width}]" for width in (SHARE["experts"], SHARE["latent"], SHARE["shared_width"])]
+        + [f"f32[{ROWS},{LENGTH},{in_proj}]"])
+
+
 def _lamb(params, grads, state, lr, hp):
     b1, b2, eps, wd = hp
     t = state["t"] + 1
@@ -466,9 +531,9 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _lm_run(cfg, pattern: str = "EM*", sizes: dict = SHARE):
+def _lm_run(cfg, pattern: str = "EM*", sizes: dict = SHARE, remat: bool = True):
     cfg.TRAIN.TASK, cfg.OPTIM.OPTIMIZER, cfg.LM.LOSS_BLOCK = "lm", "adafactor", 16
-    model = model_of(pattern, sizes)
+    model = model_of(pattern, sizes, remat=remat)
     mesh = data_mesh(1)
     state, tx = trainer.create_train_state(model, jax.random.key(0), mesh, 0)
     return mesh, state, trainer.make_train_step(model, tx, mesh, topk=5)
@@ -517,22 +582,27 @@ class _Loader:
         return iter(self.batches)
 
 
-def test_window_records_carry_the_routing_counters(fresh_cfg, tmp_path):
-    from distribuuuu_tpu.parallel import moe
-
+def _journal_of_a_toy_epoch(cfg, tmp_path, steps: int, print_freq: int, **lm_run) -> list[dict]:
+    """The records of one epoch of ``steps`` toy batches through `trainer.train_epoch`, journal validated."""
     resilience.reset_run_stats()
     resilience.clear_preemption()
-    fresh_cfg.OUT_DIR, fresh_cfg.TRAIN.PRINT_FREQ, fresh_cfg.TRAIN.BATCH_SIZE = str(tmp_path), 2, ROWS
-    mesh, state, step = _lm_run(fresh_cfg)
+    cfg.OUT_DIR, cfg.TRAIN.PRINT_FREQ, cfg.TRAIN.BATCH_SIZE = str(tmp_path), print_freq, ROWS
+    mesh, state, step = _lm_run(cfg, **lm_run)
     obs.start_run(str(tmp_path))
-    loader = _Loader([{"tokens": np.asarray(tokens_of(i, SHARE["vocab"]))} for i in range(3)])
+    loader = _Loader([{"tokens": np.asarray(tokens_of(i, SHARE["vocab"]))} for i in range(steps)])
     try:
         trainer.train_epoch(loader, mesh, step, state, 0, jax.random.key(2), True)
     finally:
         obs.end_run()
     journal = obs.journal_path(str(tmp_path))
     assert validate_journal(journal) == []
-    windows = [r for r in read_journal(journal) if r["kind"] == "window"]
+    return list(read_journal(journal))
+
+
+def test_window_records_carry_the_routing_counters(fresh_cfg, tmp_path):
+    from distribuuuu_tpu.parallel import moe
+
+    windows = [r for r in _journal_of_a_toy_epoch(fresh_cfg, tmp_path, steps=3, print_freq=2) if r["kind"] == "window"]
     assert len(windows) == 2 and all(w["loss"] > 0 for w in windows)
     slots = ROWS * LENGTH * SHARE["top_k"] * SHARE["experts_held"] / SHARE["experts"]  # the expected share
     for w in windows:
@@ -541,6 +611,17 @@ def test_window_records_carry_the_routing_counters(fresh_cfg, tmp_path):
         # the rows computed: the slots in whole blocks of one expert, a window's mean step (two-step windows)
         assert w["moe_slots_here"] <= w["moe_rows_here"] <= w["moe_slots_here"] + SHARE["experts_held"] * moe.BLOCK
         assert (2 * w["moe_rows_here"]) % moe.BLOCK == 0
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no_remat"])
+def test_counters_record_says_whether_the_checkpoint_policy_was_on(fresh_cfg, tmp_path, remat):
+    """One `remat_policy_layers` event a layer traced under the policy (a trace of ``EM*`` holds three, and a
+    run may trace its step more than once), in the journal's ``counters`` record of the run; none where
+    ``MODEL.REMAT`` is false."""
+    records = _journal_of_a_toy_epoch(fresh_cfg, tmp_path, steps=1, print_freq=1, remat=remat)
+    (of_run,) = [r for r in records if r["kind"] == "counters" and r["scope"] == "run"]
+    count = of_run["counters"].get(nh().REMAT_POLICY_EVENT, 0)
+    assert (count >= 3 and count % 3 == 0) if remat else count == 0
 
 
 def test_prefetch_ships_what_the_batch_holds(fresh_cfg):
