@@ -12,10 +12,11 @@ TPU-native additions (new sections; absent keys in old YAMLs simply keep default
   statistics always stay float32.
 - ``MODEL.REMAT``: rematerialize (activation-checkpoint) each residual stage —
   the `jax.checkpoint` analog of the reference DenseNet's ``memory_efficient``
-  (`densenet.py:81-108`), available for every model. The token model
-  (`models/nemotron_h.py`) checkpoints each layer under a policy: the values
-  its ``KEPT`` names (the routing and the large projections' results) are
-  stored, the rest of a layer is computed again in the backward pass.
+  (`densenet.py:81-108`), available for every model. The token models
+  (`models/nemotron_h.py`, `models/qwen3_next.py`) checkpoint each layer under
+  a policy: the values the family's ``KEPT`` names (the routing; nemotron_h's
+  large projections' results) are stored, the rest of a layer is computed
+  again in the backward pass.
 - ``MESH.*``: device-mesh shape. DATA=-1 means "all visible devices" on the
   data axis (the reference is DP-only, `trainer.py:134`).
 - ``CUDNN.*`` is kept for YAML compatibility and remapped: BENCHMARK is a no-op
@@ -172,14 +173,18 @@ _C.OPTIM.WEIGHT_DECAY = 5e-5
 
 # Token-sequence model (TRAIN.TASK "lm"): the section reaches the arch's
 # factory key by key in lower case; the factory lives in the module that
-# MODEL.MODULE names (models/nemotron_h.py takes these). Widths are the
-# model's; the *_HELD counts, KV/group counts and VOCAB are what this chip
-# holds of a layer shared over chips (all of it by default: the published
-# counts of config/nemotron3_super.yaml's source are in that file's comments).
+# MODEL.MODULE names and takes the keys its family's ``Sizes`` names
+# (models/nemotron_h.py, models/qwen3_next.py; a key both read is here once).
+# Widths are the model's; the *_HELD counts, KV/group counts and VOCAB are
+# what this chip holds of a layer shared over chips (all of it by default:
+# the published counts of config/nemotron3_super.yaml's and
+# config/qwen3_next.yaml's sources are in those files' comments).
 _C.LM = CN()
 _C.LM.SEQ_LEN = 8192        # tokens a row (the batch ships SEQ_LEN + 1: inputs and labels are one leaf shifted)
 _C.LM.VOCAB = 16384         # rows of embedding and head held; ids are drawn from 0 ... VOCAB - 1
-_C.LM.PATTERN = "EMEMEMEMEM*"  # one letter a layer: M Mamba-2, * attention, E latent experts
+# one letter a layer. nemotron_h: M Mamba-2, * attention, E latent experts; qwen3_next: G gated delta rule,
+# A gated attention, each followed by its expert block
+_C.LM.PATTERN = "EMEMEMEMEM*"
 _C.LM.LAYERS_TOTAL = 88     # depth of the whole model (scales the residual projections' init)
 _C.LM.DIM = 4096
 _C.LM.MAMBA_HEADS = 16
@@ -200,6 +205,13 @@ _C.LM.EXPERT_WIDTH = 2688
 _C.LM.SHARED_WIDTH = 5376
 _C.LM.ROUTED_SCALE = 5.0
 _C.LM.NORM_EPS = 1e-5
+# what qwen3_next reads beside the keys above (DIM, CONV_KERNEL, CHUNK, the attention's and the experts')
+_C.LM.LINEAR_KEY_HEADS = 16    # gated delta rule: key (and query) heads, each serving VALUE_HEADS / KEY_HEADS value heads
+_C.LM.LINEAR_VALUE_HEADS = 32
+_C.LM.LINEAR_KEY_DIM = 128
+_C.LM.LINEAR_VALUE_DIM = 128
+_C.LM.ROPE_SHARE = 0.25        # of a head's dimensions, the first, that the rotary embedding turns
+_C.LM.ROPE_THETA = 1.0e7
 # tokens a block of the loss: float32 logits exist for one block at a time
 _C.LM.LOSS_BLOCK = 2048
 

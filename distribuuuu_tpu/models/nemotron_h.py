@@ -18,17 +18,11 @@ one chip. Widths (``dim``, head sizes, state, latent, expert widths, the
 router's ``experts`` outputs and ``top_k``) are never a share.
 
 The parameters are one flat dict (`param_shapes`), the mixers pure functions
-of their layer's leaves. Where the pattern repeats a unit (``EMEMEMEMEM*`` is
-five times ``EM``, then ``*``), the repeats' parameters are one leaf with the
-repeats leading (``U<j>_<leaf> [repeats, ...]`` for the unit's layer ``j``) and
-run as one `lax.scan`, so the compiler sees a unit once: a third of the
-program and half of its compile time, and no copy of a parameter is made to
-stack it. The layers after the repeats are ``L<i>_<leaf>``; beside them
-``embed``, ``head``, ``norm_f``.
-
-``__call__`` returns the final-normed hidden states and the routing counters;
-`head_logits` maps hidden states to logits, so that a loss can take the
-vocabulary in blocks of tokens (trainer._forward_loss_lm).
+of their layer's leaves; the stack itself (the embedding, the repeated unit
+``EM`` of ``EMEMEMEMEM*`` as one `lax.scan` over stacked leaves: a third of the
+program and half of its compile time, the layer checkpoint, the final norm,
+the head in blocks and the routing counters) is `models/token_lm.TokenLM`,
+which the token models share.
 
 With ``remat`` every layer is one `jax.checkpoint` under a policy (`KEPT`): the
 values it names are stored on the way forward in the dtype they have (the
@@ -51,7 +45,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any
 
 import flax.linen as nn
 import jax
@@ -59,11 +52,16 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from distribuuuu_tpu.models import token_lm
 from distribuuuu_tpu.models.registry import register_model
+from distribuuuu_tpu.models.token_lm import mm as _mm
+from distribuuuu_tpu.models.token_lm import rms_norm
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import self_attention
 from distribuuuu_tpu.ops.ssm import ssd_scan
-from distribuuuu_tpu.parallel.moe import BLOCK, ROUTE_IDX, held_experts, round_rows_for, sigmoid_topk_route
+from distribuuuu_tpu.parallel.moe import (
+    ROUTE_IDX, held_experts, relu_squared, round_rows_for, sigmoid_topk_route,
+)
 
 F32 = jnp.float32
 #: projections back into the residual stream: their init is scaled by 1/sqrt(2·layers_total)
@@ -78,9 +76,6 @@ KEPT = (
     "moe_shared1",        # the shared expert's first product, the largest of the unit: float32, 4 B a shared width
     "mamba_in_proj",      # the Mamba mixer's input projection: float32, 4 B an element of z | x B C | dt
 )
-#: `jax.monitoring` event, one per layer traced under that policy (three a trace of ``EMEMEMEMEM*``: the
-#: scanned unit's two layers once each, then the attention layer); the journal's ``counters`` carry it
-REMAT_POLICY_EVENT = "remat_policy_layers"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,25 +129,15 @@ def layer_shapes(kind: str, s: Sizes) -> dict[str, tuple]:
 
 
 def layer_prefixes(s: Sizes) -> list[tuple[str, str, int]]:
-    """``(prefix, kind, repeats)`` of every group of leaves: ``U<j>`` for layer ``j`` of the repeated unit
-    (its leaves lead with the repeats), ``L<i>`` for each layer after the repeats (``repeats`` 0: no such axis)."""
-    unit, repeats = repeated_unit(s.pattern)
-    scanned = unit * repeats if repeats > 1 else 0
-    return ([(f"U{j}", s.pattern[j], repeats) for j in range(unit if scanned else 0)]
-            + [(f"L{i}", s.pattern[i], 0) for i in range(scanned, len(s.pattern))])
+    return token_lm.layer_prefixes(s.pattern)
 
 
 def param_shapes(s: Sizes) -> dict[str, tuple]:
-    out = {"embed": (s.vocab, s.dim)}
-    for prefix, kind, repeats in layer_prefixes(s):
-        lead = (repeats,) if repeats else ()
-        out.update({f"{prefix}_{leaf}": lead + shape for leaf, shape in layer_shapes(kind, s).items()})
-    out.update({"norm_f": (s.dim,), "head": (s.dim, s.vocab)})
-    return out
+    return token_lm.param_shapes(s, layer_shapes)
 
 
 def _initializer(name: str, s: Sizes):
-    leaf = name.split("_", 1)[-1] if name[0] in "LU" and name[1].isdigit() else name
+    leaf = token_lm.leaf_of(name)
     if leaf in ("norm", "norm_f", "gnorm", "d"):
         return nn.initializers.ones
     if leaf == "a_log":
@@ -170,36 +155,9 @@ def _initializer(name: str, s: Sizes):
     return nn.initializers.normal(0.02 / math.sqrt(2 * s.layers_total) if leaf in RESIDUAL_OUT else 0.02)
 
 
-def repeated_unit(pattern: str) -> tuple[int, int]:
-    """``(unit length, repeats)`` of the prefix that `NemotronH` scans: the unit and count, at least two,
-    that cover most of the pattern from its start; ``(len, 1)`` where nothing repeats."""
-    best = (len(pattern), 1)
-    covered = 0
-    for k in range(1, len(pattern) // 2 + 1):
-        r = 1
-        while pattern[r * k:(r + 1) * k] == pattern[:k]:
-            r += 1
-        if r >= 2 and k * r > covered:
-            best, covered = (k, r), k * r
-    return best
-
-
 # ---------------------------------------------------------------------------
 # the mixers: pure functions of one layer's leaves
 # ---------------------------------------------------------------------------
-
-def rms_norm(x, scale, eps: float, groups: int = 1):
-    """``x / sqrt(mean(x²) + eps) · scale`` in float32, the mean over each of ``groups`` slices of the width."""
-    x = x.astype(F32)
-    grouped = x.reshape(*x.shape[:-1], groups, x.shape[-1] // groups)
-    grouped = grouped * lax.rsqrt(jnp.mean(jnp.square(grouped), axis=-1, keepdims=True) + eps)
-    return grouped.reshape(x.shape) * scale.astype(F32)
-
-
-def _mm(x, kernel):
-    """``x @ kernel``, operands in ``x.dtype``, float32 out."""
-    return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=F32)
-
 
 def mamba_mixer(p: dict, u, s: Sizes):
     b, l, _ = u.shape
@@ -235,7 +193,8 @@ def moe_mixer(p: dict, b_corr, u32, s: Sizes, dtype):
         idx, weights = sigmoid_topk_route(logits, s.top_k, b_corr, s.routed_scale)  # names its `idx` itself
     latent = checkpoint_name(_mm(u, p["down"]).astype(dtype), "moe_latent")
     rows = round_rows_for(b * l, s.top_k, s.experts, s.experts_held)
-    mixed, counts = held_experts(latent, idx, weights, p["w1"], p["w2"], s.expert_first, rows)
+    # between an expert's two products stands `relu²`, as in the shared expert below
+    mixed, counts = held_experts(latent, idx, weights, p["w1"], p["w2"], s.expert_first, rows, between=relu_squared)
     shared = checkpoint_name(_mm(u, p["shared1"]), "moe_shared1")
     shared = _mm(jnp.square(jax.nn.relu(shared)).astype(dtype), p["shared2"])
     return (_mm(mixed.astype(dtype), p["up"]) + shared).reshape(b, l, dim), counts
@@ -254,86 +213,13 @@ def layer(kind: str, p: dict, b_corr, h, s: Sizes):
     return h + out.astype(h.dtype), counts
 
 
-class NemotronH(nn.Module):
-    sizes: Sizes
-    dtype: Any = jnp.bfloat16
-    remat: bool = False
-
-    def dummy_input(self, size: int):
-        """What `trainer.create_train_state` initialises with: parameter shapes do not depend on the length."""
-        del size
-        return jnp.zeros((1, 8), jnp.int32)
-
-    def setup(self):
-        s = self.sizes
-        self.p = {name: self.param(name, _initializer(name, s), shape, F32)
-                  for name, shape in param_shapes(s).items()}
-        # a buffer of the checkpoint, not a parameter: its update rule is the recipe's, not the model's
-        self.b_corr = {
-            prefix: self.variable("batch_stats", f"{prefix}_b_corr", jnp.zeros,
-                                  ((repeats,) if repeats else ()) + (s.experts,), F32)
-            for prefix, kind, repeats in layer_prefixes(s) if kind == "E"
-        }
-
-    def head_logits(self, hidden):
-        """Float32 logits over the held vocabulary slice of final-normed hidden states ``[..., dim]``."""
-        return _mm(hidden, self.p["head"])
-
-    def _leaves(self, prefix: str) -> tuple[dict, Any]:
-        """The leaves of one prefix by their short names, and its router's buffer (None where it has none)."""
-        leaves = {k[len(prefix) + 1:]: v for k, v in self.p.items() if k.startswith(prefix + "_")}
-        return leaves, self.b_corr[prefix].value if prefix in self.b_corr else None
-
-    def __call__(self, tokens, train: bool = False):
-        del train  # no dropout, no running statistics
-        s = self.sizes
-        one_layer = scanned_layer = layer
-        if self.remat:
-            def under_policy(**options):
-                remat_layer = jax.checkpoint(layer, static_argnums=(0, 4),
-                                             policy=jax.checkpoint_policies.save_only_these_names(*KEPT), **options)
-
-                def counted(*args):
-                    jax.monitoring.record_event(REMAT_POLICY_EVENT)  # at trace time: once a layer traced
-                    return remat_layer(*args)
-
-                return counted
-
-            one_layer = under_policy()
-            # `lax.scan` already keeps the compiler from merging the recomputation with the forward pass; the
-            # barrier `prevent_cse` adds inside it cost 3.7 to 4.9 ms of a 280 ms step on the chip (PERF.md §5, PR 32)
-            scanned_layer = under_policy(prevent_cse=False)
-
-        h = self.p["embed"][tokens].astype(self.dtype)
-        loads = []
-        groups = layer_prefixes(s)
-        unit = [(prefix, kind) for prefix, kind, repeats in groups if repeats]
-        if unit:
-            def one_unit(h, leaves_and_buffers):
-                counts = []
-                for (prefix, kind), (leaves, b_corr) in zip(unit, leaves_and_buffers):
-                    with jax.named_scope(prefix):
-                        h, c = scanned_layer(kind, leaves, b_corr, h, s)
-                    counts += [] if c is None else [c]
-                return h, counts
-
-            h, counts = lax.scan(one_unit, h, [self._leaves(prefix) for prefix, _ in unit])
-            loads += [c.astype(F32) for c in counts]  # each [repeats, held]
-        for prefix, kind, repeats in groups:
-            if not repeats:
-                with jax.named_scope(prefix):
-                    h, c = one_layer(kind, *self._leaves(prefix), h, s)
-                loads += [] if c is None else [c.astype(F32)[None]]
-        hidden = rms_norm(h, self.p["norm_f"], s.eps).astype(self.dtype)
-        counters = {}
-        if loads:
-            loads = jnp.concatenate(loads)  # [expert layers, held]
-            counters = {
-                "moe_slots_here": jnp.sum(loads),
-                "moe_rows_here": jnp.sum(jnp.ceil(loads / BLOCK) * BLOCK),  # what the rounds computed: the slots in whole blocks
-                "moe_load_max_over_mean": jnp.max(jnp.max(loads, axis=1) / jnp.maximum(jnp.mean(loads, axis=1), 1.0)),
-            }
-        return hidden, counters
+class NemotronH(token_lm.TokenLM):
+    layer_shapes = staticmethod(layer_shapes)
+    initializer = staticmethod(_initializer)
+    layer = staticmethod(layer)
+    final_norm = staticmethod(rms_norm)
+    kept = KEPT
+    buffered = "E"  # the routers' ``e_score_correction_bias``
 
 
 @register_model("nemotron_h")
@@ -346,4 +232,4 @@ def nemotron_h(num_classes=None, dtype=jnp.bfloat16, bn_axis_name=None, remat: b
     if not sizes:
         raise ValueError("MODEL.ARCH 'nemotron_h' maps token ids to hidden states and is sized "
                          "by the LM section: set TRAIN.TASK 'lm'")
-    return NemotronH(Sizes(eps=norm_eps, **sizes), dtype=dtype, remat=remat)
+    return NemotronH(token_lm.sizes_from(Sizes, dict(sizes, eps=norm_eps)), dtype=dtype, remat=remat)

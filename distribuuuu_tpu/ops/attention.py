@@ -46,6 +46,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from distribuuuu_tpu.ops.interpret import pallas_interpret
+from distribuuuu_tpu.ops.rows import rows_in_groups
 from distribuuuu_tpu.ops.vmem_guard import DEFAULT_VMEM_BUDGET_MB, VmemBudgetGuard
 
 # VMEM-budget guard: the single-tile kernels keep a whole (batch·head) tile
@@ -759,7 +760,16 @@ def xla_causal_attention(qkv, num_heads: int, kv_heads: int, block: int = CAUSAL
     Blocks of `CAUSAL_BLOCK` query rows, each against the keys up to its own
     last row only, so the products above the diagonal are never formed (half
     of them at large L) and no ``L x L`` tensor exists; each block is
-    rematerialised in the backward pass, so what is kept for it is q, k, v."""
+    rematerialised in the backward pass, so what is kept for it is q, k, v.
+    The rows go through `ops.rows.rows_in_groups` by the last block's float32
+    scores (16 heads at 8192 keys: 512 MiB a row, so a row at a time there)."""
+    _, l, _ = qkv.shape
+    score_bytes = 4 * num_heads * min(block, l) * l
+    return rows_in_groups(lambda rows: _causal_attention_of_rows(rows, num_heads, kv_heads, block), (qkv,), score_bytes)
+
+
+def _causal_attention_of_rows(qkv, num_heads: int, kv_heads: int, block: int):
+    """`xla_causal_attention` for rows whose blocks' scores all stand at once."""
     b, l, width = qkv.shape
     hd = width // (num_heads + 2 * kv_heads)
     q, k, v = jnp.split(qkv, (num_heads * hd, (num_heads + kv_heads) * hd), axis=-1)
@@ -771,6 +781,19 @@ def xla_causal_attention(qkv, num_heads: int, kv_heads: int, block: int = CAUSAL
         for start in range(0, l, block)
     ]
     return jnp.concatenate(out, axis=1).reshape(b, l, num_heads * hd)
+
+
+def partial_rotary(x, rotary_dim: int, theta: float, first_position: int = 0):
+    """Rotary position embedding on the first ``rotary_dim`` of each head of ``x [B, L, H, hd]``, the
+    rest untouched. The rotated dimensions pair as ``(i, i + rotary_dim/2)`` ("rotate half"), pair
+    ``i`` turning by ``position · theta^(-2i/rotary_dim)``; positions count from ``first_position``.
+    Angles, sines and cosines in float32; returns ``x.dtype``."""
+    length, half = x.shape[1], rotary_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = (first_position + jnp.arange(length, dtype=jnp.float32))[:, None] * inv_freq  # [L, half]
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    x1, x2, rest = jnp.split(x.astype(jnp.float32), (half, rotary_dim), axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1).astype(x.dtype)
 
 
 def self_attention(qkv, num_heads: int, *, kv_heads: int | None = None, causal: bool = False):

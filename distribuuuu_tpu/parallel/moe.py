@@ -211,6 +211,14 @@ BLOCK = 256
 ROUTE_IDX = "moe_route_idx"
 
 
+def _chosen(scores, idx):
+    """``scores [T, E]`` at the chosen ``idx [T, k]``, by comparison and not `take_along_axis`: the same numbers
+    (a token's choices are distinct, so each sum holds one score and zeros, forward and backward), and one
+    fused pass over ``[T, k, E]`` where the gather of T·k single elements took 1.8 ms a call on the chip
+    (PERF.md §5, PR 30)."""
+    return jnp.sum(jnp.where(idx[:, :, None] == jnp.arange(scores.shape[-1]), scores[:, None, :], 0.0), axis=-1)
+
+
 def sigmoid_topk_route(logits, k: int, bias, scale: float):
     """Top-``k`` of ``E`` by sigmoid score, float32 throughout.
 
@@ -224,11 +232,24 @@ def sigmoid_topk_route(logits, k: int, bias, scale: float):
     # named before its first use: a layer checkpoint that keeps `ROUTE_IDX` (models/nemotron_h.KEPT) then
     # runs the sort once; named on the way out, the comparison below would still hang on the unnamed value
     idx = checkpoint_name(idx, ROUTE_IDX)
-    # the chosen scores by comparison, not `take_along_axis`: the same numbers (a token's choices are
-    # distinct, so each sum holds one score and zeros, forward and backward), and one fused pass over
-    # [T, k, E] where the gather of T·k single elements took 1.8 ms a call on the chip (PERF.md §5, PR 30)
-    chosen = jnp.sum(jnp.where(idx[:, :, None] == jnp.arange(scores.shape[-1]), scores[:, None, :], 0.0), axis=-1)
+    chosen = _chosen(scores, idx)
     return idx, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+
+
+def softmax_topk_route(logits, k: int):
+    """Top-``k`` of ``E`` by softmax probability, float32 throughout.
+
+    ``logits [T, E]``; ``p = softmax(logits)`` over all ``E``, the choice its
+    ``k`` largest (the lower id wins a tie, as `lax.top_k` orders them), the
+    mixture weights the chosen probabilities normalised over the choice:
+    ``w_i = p_i / Σ_choice p_j``. Returns ``(idx [T, k] int32, w [T, k])``;
+    the ids go under `ROUTE_IDX`, as `sigmoid_topk_route` names them.
+    """
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, idx = lax.top_k(probs, k)
+    idx = checkpoint_name(idx, ROUTE_IDX)  # before its first use: see `sigmoid_topk_route`
+    chosen = _chosen(probs, idx)
+    return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
 
 
 def round_rows_for(tokens: int, k: int, experts: int, held: int, room: float = 1.5) -> int:
@@ -246,25 +267,41 @@ GROUPED_CALLS_EVENT = "moe_grouped_calls"
 XLA_CALLS_EVENT = "moe_xla_calls"
 
 
-def _takes_the_kernels(dim: int, width: int, dtype) -> bool:
-    """The realisation of a block's products, from what the trace can observe: `ops/grouped.py`'s
-    kernels where a mesh of TPUs is in use (the described chips of a compile-only test count as what
-    they describe) and `grouped_product_fuses` admits the shapes; outside any mesh (``model.init``,
-    shape inference) XLA's batched products, uncounted."""
+def _takes_the_kernels(products, dtype) -> bool:
+    """The realisation of a block's products ``[(k, n), ...]``, from what the trace can observe:
+    `ops/grouped.py`'s kernels where a mesh of TPUs is in use (the described chips of a compile-only
+    test count as what they describe) and `grouped_product_fuses` admits the shapes of each; outside
+    any mesh (``model.init``, shape inference) XLA's batched products, uncounted."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return False
-    fuses = grouped_product_fuses(mesh.abstract_device.device_kind, BLOCK, dim, width, jnp.dtype(dtype).itemsize)
+    fuses = all(grouped_product_fuses(mesh.abstract_device.device_kind, BLOCK, k, n, jnp.dtype(dtype).itemsize)
+                for k, n in products)
     jax.monitoring.record_event(GROUPED_CALLS_EVENT if fuses else XLA_CALLS_EVENT)
     return fuses
 
 
-def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
-    """The held experts' part of the mixture ``Σ_i w_i · W2_i relu(W1_i x)²``.
+def relu_squared(hidden):
+    """``relu(h)²``: what stands between the two products of an ungated expert."""
+    return jnp.square(jax.nn.relu(hidden))
+
+
+def silu_gated(hidden):
+    """``silu(gate) ⊙ up`` over a first product of twice the width, ``[gate | up]``: a gated expert's."""
+    gate, up = jnp.split(hidden, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+def held_experts(x, idx, w, w1, w2, first: int, round_rows: int, *, between):
+    """The held experts' part of the mixture ``Σ_i w_i · W2_i between(W1_i x)``.
 
     ``x [T, D]``; ``idx, w [T, K]`` from the router (ids over all experts);
-    ``w1 [H, D, F]``, ``w2 [H, F, D]`` the experts ``first … first + H - 1``;
-    ``round_rows`` a multiple of `BLOCK` (`round_rows_for`).
+    ``w1 [H, D, F1]``, ``w2 [H, F2, D]`` the experts ``first … first + H - 1``;
+    ``between``: what stands between the two products, elementwise on the
+    float32 result of the first, ``[..., F1] -> [..., F2]`` (`relu_squared`
+    with ``F2 = F1`` for an ungated expert; `silu_gated` with ``F1 = 2·F2``
+    for a gated one, whose gate and up projections are one first weight side
+    by side); ``round_rows`` a multiple of `BLOCK` (`round_rows_for`).
     Returns ``(y [T, D] float32, counts [H] int32)``: the sum over the slots
     that landed here, and how many landed on each held expert.
 
@@ -273,11 +310,11 @@ def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
     that lists the hits by expert and then by token, the layout of that list
     in blocks of `BLOCK` rows, one expert a block (an expert's last block
     padded), the gather of a round's rows and the weighted scatter-add back.
-    Under ``dtpu.moe_experts``: the two products of a round, every block with
-    its own expert's weights: `ops.grouped.grouped_product` (forward and
-    backward kernels; no copy of the weights a block, no weight gradient a
-    block) or, where `_takes_the_kernels` says no, a batch of blocks against
-    gathered copies of the weights.
+    Under ``dtpu.moe_experts``: the two products of a round and ``between``,
+    every block with its own expert's weights: `ops.grouped.grouped_product`
+    (forward and backward kernels; no copy of the weights a block, no weight
+    gradient a block) or, where `_takes_the_kernels` says no, a batch of
+    blocks against gathered copies of the weights.
 
     Round 0 takes the first ``round_rows`` rows of the layout and is
     straight-line code; the rows beyond go in groups of one, two, four …
@@ -291,7 +328,7 @@ def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
     tokens, dim = x.shape
     held = w1.shape[0]
     f32 = jnp.float32
-    kernels = _takes_the_kernels(dim, w1.shape[2], x.dtype)
+    kernels = _takes_the_kernels([w1.shape[1:], w2.shape[1:]], x.dtype)
     with step_scope("moe_route"):
         hits = idx[:, :, None] == (first + jnp.arange(held))[None, None, :]       # [T, K, H]
         hit = jnp.any(hits, axis=1).T                                             # [H, T]
@@ -325,11 +362,11 @@ def held_experts(x, idx, w, w1, w2, first: int, round_rows: int):
         with step_scope("moe_experts"):
             if kernels:
                 hidden = grouped_product(picked, expert, live_blocks, w1, False, pallas_interpret())
-                hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
+                hidden = between(hidden).astype(x.dtype)
                 out = grouped_product(hidden, expert, live_blocks, w2, False, pallas_interpret())
             else:
                 hidden = jnp.einsum("brd,bdf->brf", picked, w1_of, preferred_element_type=f32)
-                hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
+                hidden = between(hidden).astype(x.dtype)
                 out = jnp.einsum("brf,bfd->brd", hidden, w2_of, preferred_element_type=f32)
         with step_scope("moe_route"):
             return jnp.zeros((tokens, dim), f32).at[token].add(out.reshape(rows, dim) * weight[:, None], mode="drop")

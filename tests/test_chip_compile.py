@@ -240,7 +240,7 @@ def test_held_experts_compile_for_v5e_at_the_cells_sizes(topo, one_chip, route, 
 
     def mix(x, logits, w1, w2):
         idx, w = moe.sigmoid_topk_route(logits, 22, jnp.zeros((e,)), 5.0)
-        y, counts = moe.held_experts(x, idx, w, w1, w2, 0, rows)
+        y, counts = moe.held_experts(x, idx, w, w1, w2, 0, rows, between=moe.relu_squared)
         return y if not grad else jnp.sum(y)
 
     fn = jax.grad(mix, argnums=(0, 1, 2, 3)) if grad else mix
@@ -276,6 +276,69 @@ def test_held_experts_compile_for_v5e_at_the_cells_sizes(topo, one_chip, route, 
     held_weights = (f"f32[{held},{d},{f}]", f"f32[{held},{f},{d}]")
     assert not [line for line in text.splitlines()
                 if "dtpu.moe_route" in line and "scatter" in line and any(w in line for w in held_weights)]
+
+
+# -- the second token model at the widths of config/qwen3_next.yaml: the delta rule is XLA's alone; its expert
+# layer's gated products are the same kernel pair; the cell's whole step fits the chip at two rows -------------
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_chunked_delta_rule_compiles_for_v5e_at_the_cells_sizes(one_chip, grad):
+    """One row of 8192 positions, 32 value heads of 128 by 128, chunks of 64."""
+    from distribuuuu_tpu.ops.gdn import gated_delta_rule
+
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    b, l, h, k, v = 1, 8192, 32, 128, 128
+    args = (shape((b, l, h, k), jnp.bfloat16), shape((b, l, h, k), jnp.bfloat16), shape((b, l, h, v), jnp.bfloat16),
+            shape((b, l, h), jnp.float32), shape((b, l, h), jnp.float32))
+    rule = lambda *a: gated_delta_rule(*a, 64)
+    fn = jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)) if grad else rule
+    assert "dtpu.gdn_scan" in _compiles_without_kernels(fn, *args)
+
+
+def test_qwen3_next_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(topo, one_chip, fresh_cfg):
+    """The cell's own step (config/qwen3_next.yaml: two rows of 8192 tokens, 626 M parameters, Adafactor,
+    the layer checkpoint) compiled for the described chip: the compiler accepts it, and by its own count the
+    step's peak (the state and what it holds beside it) leaves room on the chip's 15.75 GiB for the
+    benchmark's second copy of the weights; the held experts' gated products are the kernel
+    pair, forward and backward; every model scope stands in it. Its arguments are the state: parameters and
+    a few MB."""
+    from distribuuuu_tpu import optim, trainer
+    from distribuuuu_tpu.ops.interpret import set_pallas_interpret
+
+    cfg = fresh_cfg  # restores the config and the model-trace globals the build sets from it (bfloat16 norms)
+    cfg.merge_from_file(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                                     "config", "qwen3_next.yaml"))
+    interpret = set_pallas_interpret(False)  # conftest asks for the interpreter; the chip's route does not
+    try:
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        model = trainer._build_cfg_model()
+        tx = optim.construct_optimizer()
+
+        def init(key):
+            variables = model.init(key, model.dummy_input(0), train=False)
+            return trainer.TrainState(params=variables["params"], batch_stats={}, opt_state=tx.init(variables["params"]))
+
+        replicated = NamedSharding(mesh, P())
+        struct = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated)
+        state = jax.tree.map(struct, jax.eval_shape(init, jax.random.key(0)))
+        rows = cfg.TRAIN.BATCH_SIZE
+        batch = {"tokens": jax.ShapeDtypeStruct((rows, cfg.LM.SEQ_LEN + 1), jnp.int32,
+                                                sharding=NamedSharding(mesh, P("data", None)))}
+        step = trainer.make_train_step(model, tx, mesh, topk=5)
+        compiled = step.lower(state, batch, struct(jnp.float32(0.0)), struct(jax.eval_shape(lambda: jax.random.key(1)))).compile()
+    finally:
+        set_pallas_interpret(interpret)
+    memory = compiled.memory_analysis()
+    weights = 4 * sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state.params))
+    assert rows == 2 and weights == 4 * 625_667_136
+    assert weights <= memory.argument_size_in_bytes <= weights + 64 * 2**20  # the state: Adafactor's is rows and columns
+    assert memory.alias_size_in_bytes >= weights  # donated: the new state takes the old one's place
+    peak = memory.peak_memory_in_bytes  # the state and, beside it, the most the step holds at a time
+    assert peak + weights <= 15.75 * 2**30, f"{peak / 2**30:.2f} GiB beside a second copy of {weights / 2**30:.2f}"
+    text = compiled.as_text()
+    assert "dtpu_moe_gmm" in text and "dtpu_moe_tgmm" in text
+    for scope in ("dtpu.gdn_scan", "dtpu.moe_route", "dtpu.moe_experts", "dtpu.optimizer", "dtpu.loss"):
+        assert scope in text, scope
 
 
 # ops/moe_kernel.py is refused by Mosaic at every shape its VMEM guard admits

@@ -6,6 +6,7 @@ counters reach the compiled step and the journal. CPU, toy widths, seeded weight
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import os
 import re
@@ -16,6 +17,7 @@ import numpy as np
 import pytest
 
 from distribuuuu_tpu import obs, optim, resilience, trainer
+from distribuuuu_tpu.models import token_lm
 from distribuuuu_tpu.obs import trace as obs_trace
 from distribuuuu_tpu.obs.journal import read_journal, validate_journal
 from distribuuuu_tpu.runtime import data_mesh
@@ -58,7 +60,7 @@ def to_program(params: dict, stats: dict, model) -> tuple[dict, dict]:
     """The reference's per-layer leaves (``L3.w1``) in the program's flat tree (``U1_w1 [repeats, ...]``)."""
     m = nh()
     s = model.sizes
-    unit, repeats = m.repeated_unit(s.pattern)
+    unit, repeats = token_lm.repeated_unit(s.pattern)
     layers_of = {f"U{j}": [r * unit + j for r in range(repeats)] for j in range(unit)} if repeats > 1 else {}
     layers_of.update({f"L{i}": i for i in range(unit * repeats if repeats > 1 else 0, len(s.pattern))})
 
@@ -234,7 +236,7 @@ def _from_program(tree: dict, model) -> dict:
     """The program's leaves back as the reference's per-layer ones."""
     m = nh()
     s = model.sizes
-    unit, repeats = m.repeated_unit(s.pattern)
+    unit, repeats = token_lm.repeated_unit(s.pattern)
     out = {}
     for name, value in tree.items():
         prefix, _, short = name.partition("_")
@@ -473,11 +475,12 @@ def test_no_slot_is_dropped_when_every_token_chooses_the_same_experts(round_rows
             y = y + (jnp.square(jax.nn.relu(x @ w1[e])) @ w2[e]) * gate[:, None]
         return y
 
-    y, counts = jax.jit(lambda *a: moe.held_experts(a[0], idx, *a[1:], 4, round_rows))(x, w, w1, w2)
+    held_experts = functools.partial(moe.held_experts, between=moe.relu_squared)
+    y, counts = jax.jit(lambda *a: held_experts(a[0], idx, *a[1:], 4, round_rows))(x, w, w1, w2)
     assert counts.tolist() == [tokens, tokens, 0, 0]  # every slot on a held expert is counted
     np.testing.assert_allclose(y, dense(x, w, w1, w2), rtol=2e-4, atol=2e-5)
     grads = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))), argnums=(0, 1, 2, 3)))(x, w, w1, w2)
-    for got, want in zip(grads(lambda *a: moe.held_experts(a[0], idx, *a[1:], 4, round_rows)[0]), grads(dense)):
+    for got, want in zip(grads(lambda *a: held_experts(a[0], idx, *a[1:], 4, round_rows)[0]), grads(dense)):
         assert rel(got, want) <= 2e-4
 
 
@@ -544,7 +547,9 @@ def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compi
     batch = {"tokens": tokens_of(0, SHARE["vocab"])}
     text = step.lower(state, batch, jnp.float32(0.1), jax.random.key(1)).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
-    for scope in obs_trace.MODEL_SCOPES:
+    scopes = ("ssm_scan", "moe_route", "moe_experts")  # this family's of `MODEL_SCOPES` (the delta rule's: test_qwen3_next.py)
+    assert set(scopes) <= set(obs_trace.MODEL_SCOPES)
+    for scope in scopes:
         under = [n for n in names if f"/dtpu.{scope}/" in n]
         assert any("transpose(" in n for n in under), f"no backward op under dtpu.{scope}"
         assert any("transpose(" not in n for n in under), f"no forward op under dtpu.{scope}"
@@ -620,7 +625,7 @@ def test_counters_record_says_whether_the_checkpoint_policy_was_on(fresh_cfg, tm
     ``MODEL.REMAT`` is false."""
     records = _journal_of_a_toy_epoch(fresh_cfg, tmp_path, steps=1, print_freq=1, remat=remat)
     (of_run,) = [r for r in records if r["kind"] == "counters" and r["scope"] == "run"]
-    count = of_run["counters"].get(nh().REMAT_POLICY_EVENT, 0)
+    count = of_run["counters"].get(token_lm.REMAT_POLICY_EVENT, 0)
     assert (count >= 3 and count % 3 == 0) if remat else count == 0
 
 
@@ -677,7 +682,7 @@ def test_the_token_model_needs_its_task_and_the_task_its_synthetic_rows(fresh_cf
 @pytest.mark.parametrize("pattern,want", [("EMEMEMEMEM*", (2, 5)), ("M", (1, 1)), ("EM*", (3, 1)), ("MMMM", (1, 4)),
                                          ("MEMEMEM*EMEMEMEM*", (2, 3))])
 def test_repeated_unit_of_a_pattern(pattern, want):
-    assert nh().repeated_unit(pattern) == want
+    assert token_lm.repeated_unit(pattern) == want
 
 
 def test_shipped_yaml_builds_the_configurations_701m_parameters(fresh_cfg):
