@@ -20,9 +20,12 @@ and ``S_0`` the state entering it, the written values ``u_t`` obey
 
 so they take the inverse of a unit lower-triangular ``Q x Q`` matrix. ``A`` is
 strictly lower triangular, so nilpotent: ``(I + A)⁻¹ = Π_{j<log2 Q} (I + N^(2^j))``
-with ``N = −A``, which is ``log2 Q − 1`` squarings and as many products, all
-matrix products of all chunks at once (`unit_lower_inverse`; no substitution,
-which would be Q dependent steps of vector work). Then, a chunk after the other
+with ``N = −A``, which is ``log2 Q − 1`` squarings and as many products
+(`unit_lower_inverse`; no substitution, which would be Q dependent steps of
+vector work): inside the trainer's steps on TPUs a kernel pair that holds a
+chunk's tile in VMEM through the whole chain, forward and backward
+(`ops/gdn_inverse.py`), elsewhere XLA's batched products of all chunks at
+once, each crossing HBM. Then, a chunk after the other
 (a `lax.scan` over the L/Q chunks, the only sequential part)::
 
     U = T(βV) − T(βγK) S_0                                   T = (I + A)⁻¹
@@ -48,27 +51,61 @@ which the chunked form is tested against, values and gradients.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from distribuuuu_tpu.obs.trace import step_scope
+from distribuuuu_tpu.ops import gdn_inverse
+from distribuuuu_tpu.ops.interpret import pallas_interpret
 from distribuuuu_tpu.ops.rows import rows_in_groups
 
 _F32 = jnp.float32
-#: the inverse's products are float32's own, as the configuration's `precision` states. On the chip the ten of a
-#: chunk's inverse are bound by their 64 x 64 tiles' traffic and not by passes (three read the same time: PERF.md §5)
+#: the inverse's products are float32's own, as the configuration's `precision` states, in the kernels and in XLA's
+#: body alike. As XLA's batched products the ten of a chunk's inverse cross HBM one by one (PERF.md §5)
 _HI = lax.Precision.HIGHEST
 #: tensors of a float32 ``[L/Q, H, Q, Q]``'s size that the chunks of a row hold at once
 CHUNK_TENSORS = 12
+#: `jax.monitoring` events, one a traced `unit_lower_inverse` inside a mesh: which realisation it took. The
+#: journal's ``counters`` records carry them (obs/monitors.py)
+KERNEL_CALLS_EVENT = "gdn_inverse_kernel_calls"
+XLA_CALLS_EVENT = "gdn_inverse_xla_calls"
 
 
-@jax.custom_vjp
+def _takes_the_kernels(a) -> bool:
+    """The realisation of the inverse of ``a [..., Q, Q]``, from what the trace can observe:
+    `ops/gdn_inverse.py`'s kernels where a mesh of TPUs is in use (the described chips of a compile-only test
+    count as what they describe) and `inverse_fits` admits the tile; outside any mesh (``model.init``, shape
+    inference, a test's plain call) XLA's products, uncounted."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return False
+    fits = gdn_inverse.inverse_fits(mesh.abstract_device.device_kind, a.shape[-1], a.dtype)
+    jax.monitoring.record_event(KERNEL_CALLS_EVENT if fits else XLA_CALLS_EVENT)
+    return fits
+
+
 def unit_lower_inverse(a):
     """``(I + a)⁻¹`` for ``a [..., Q, Q]`` strictly lower triangular, float32, by the squarings of the
     nilpotent ``−a``: ``(I − n)⁻¹ = (I + n)(I + n²)(I + n⁴) …`` up to the power that vanishes. Its backward pass
     is the inverse's own, ``da = −Tᵀ dT Tᵀ``: two products, and ``T`` alone kept for them (autodiff's of the
-    squarings would keep every power and partial product: ten ``Q x Q`` tensors a chunk)."""
+    squarings would keep every power and partial product: ten ``Q x Q`` tensors a chunk). One algorithm, two
+    realisations, picked by `_takes_the_kernels` where the call is traced."""
+    return _inverse(a, _takes_the_kernels(a), pallas_interpret())
+
+
+def _tile_by_tile(kernel, *operands, interpret: bool):
+    """A kernel over ``[N, Q, Q]`` for operands ``[..., Q, Q]``: the leading axes flattened around the call."""
+    shape = operands[0].shape
+    return kernel(*(t.reshape(-1, *shape[-2:]) for t in operands), interpret=interpret).reshape(shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _inverse(a, kernels: bool, interpret: bool):
+    if kernels:
+        return _tile_by_tile(gdn_inverse.inverse, a, interpret=interpret)
     q = a.shape[-1]
     power = -a
     inverse = jnp.eye(q, dtype=_F32) + power
@@ -80,17 +117,19 @@ def unit_lower_inverse(a):
     return inverse
 
 
-def _inverse_fwd(a):
-    inverse = unit_lower_inverse(a)
+def _inverse_fwd(a, kernels, interpret):
+    inverse = _inverse(a, kernels, interpret)
     return inverse, inverse
 
 
-def _inverse_bwd(inverse, d_inverse):
+def _inverse_bwd(kernels, interpret, inverse, d_inverse):
+    if kernels:
+        return (_tile_by_tile(gdn_inverse.inverse_bwd, inverse, d_inverse, interpret=interpret),)
     transposed = jnp.swapaxes(inverse, -1, -2)
     return (-jnp.matmul(jnp.matmul(transposed, d_inverse, precision=_HI), transposed, precision=_HI),)
 
 
-unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):

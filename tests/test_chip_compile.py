@@ -16,6 +16,7 @@ or collection time, start a child that needs libtpu, or be ``autouse``.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -278,21 +279,67 @@ def test_held_experts_compile_for_v5e_at_the_cells_sizes(topo, one_chip, route, 
                 if "dtpu.moe_route" in line and "scatter" in line and any(w in line for w in held_weights)]
 
 
-# -- the second token model at the widths of config/qwen3_next.yaml: the delta rule is XLA's alone; its expert
-# layer's gated products are the same kernel pair; the cell's whole step fits the chip at two rows -------------
+# -- the second token model at the widths of config/qwen3_next.yaml: the delta rule's chunk inverse is a kernel
+# pair inside a mesh of TPUs and XLA's outside; its expert layer's gated products are the grouped pair; the
+# cell's whole step fits the chip at two rows -------------------------------------------------------------------
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_chunked_delta_rule_compiles_for_v5e_at_the_cells_sizes(one_chip, grad):
-    """One row of 8192 positions, 32 value heads of 128 by 128, chunks of 64."""
-    from distribuuuu_tpu.ops.gdn import gated_delta_rule
+@pytest.mark.parametrize("route", ["xla", "kernels"])
+def test_chunked_delta_rule_compiles_for_v5e_at_the_cells_sizes(topo, one_chip, route, grad):
+    """One row of 8192 positions, 32 value heads of 128 by 128, chunks of 64. Outside any mesh the chunks'
+    inverses are XLA's batched products; inside the described chip's mesh, as the trainer's step traces them,
+    the kernel pair of `ops/gdn_inverse.py`, which leaves no product of 4096 tiles of 64 x 64 float32 in the
+    program."""
+    from distribuuuu_tpu.obs.monitors import MonitoringBridge
+    from distribuuuu_tpu.ops import gdn
+    from distribuuuu_tpu.ops.interpret import set_pallas_interpret
 
-    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
     b, l, h, k, v = 1, 8192, 32, 128, 128
+    rule = lambda *a: gdn.gated_delta_rule(*a, 64)
+    fn = jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)) if grad else rule
+    sharding = one_chip
+    if route == "kernels":
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)  # as the trainer's steps are
+        sharding = NamedSharding(mesh, P())
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
     args = (shape((b, l, h, k), jnp.bfloat16), shape((b, l, h, k), jnp.bfloat16), shape((b, l, h, v), jnp.bfloat16),
             shape((b, l, h), jnp.float32), shape((b, l, h), jnp.float32))
-    rule = lambda *a: gated_delta_rule(*a, 64)
-    fn = jax.grad(lambda *a: jnp.sum(rule(*a).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)) if grad else rule
-    assert "dtpu.gdn_scan" in _compiles_without_kernels(fn, *args)
+    interpret = set_pallas_interpret(False)  # conftest asks for the interpreter; the chip's route does not
+    bridge = MonitoringBridge().install()
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        counters = bridge.snapshot()["counters"]
+    finally:
+        bridge.close()
+        set_pallas_interpret(interpret)
+    assert "dtpu.gdn_scan" in text
+    # float32 products whose result is a tile a chunk and head (XLA prints a batched product as a convolution)
+    tile_products = re.findall(rf"= f32\[(?:{l // 64 * h}|{l // 64},{h}|{l // 64},{b},{h}),64,64\]\S* (?:dot|convolution)\(", text)
+    if route == "xla":
+        assert "tpu_custom_call" not in text and len(tile_products) >= 10  # an inverse's ten and the few around it
+        assert gdn.KERNEL_CALLS_EVENT not in counters and gdn.XLA_CALLS_EVENT not in counters  # no mesh: uncounted
+        return
+    assert counters.get(gdn.KERNEL_CALLS_EVENT, 0) >= 1 and gdn.XLA_CALLS_EVENT not in counters
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls and all("dtpu.gdn_scan" in line for line in calls)
+    named = lambda name: [line for line in calls if f"/{name}/pallas_call" in line]
+    assert named("dtpu_gdn_inverse") and bool(named("dtpu_gdn_inverse_bwd")) is grad
+    # left with a tile's shape: k·kᵀ and q·kᵀ (and in the gradient the one that goes back through the decays)
+    assert len(tile_products) <= 3
+
+
+@pytest.mark.parametrize("tiles, q", [(4096, 64), (100, 8), (100, 16), (100, 48), (20, 128)],
+                         ids=lambda v: str(v))
+def test_chunk_inverse_kernels_compile_for_v5e_at_the_widths_the_choice_admits(one_chip, tiles, q):
+    """The cell's own call (a row's 128 chunks x 32 heads of 64 x 64) and the ends of what `inverse_fits`
+    admits, with tile counts that are no whole number of grid steps."""
+    from distribuuuu_tpu.ops import gdn_inverse
+
+    assert gdn_inverse.inverse_fits(next(iter(one_chip.device_set)).device_kind, q, jnp.float32)
+    a = _struct((tiles, q, q), jnp.float32)
+    assert "dtpu_gdn_inverse" in _compile(lambda a: gdn_inverse.inverse(a), [a], one_chip, grad=False)
+    assert "dtpu_gdn_inverse_bwd" in _compile(lambda t, d: gdn_inverse.inverse_bwd(t, d), [a, a], one_chip, grad=False)
 
 
 def test_qwen3_next_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(topo, one_chip, fresh_cfg):
