@@ -174,16 +174,18 @@ _C.OPTIM.WEIGHT_DECAY = 5e-5
 # Token-sequence model (TRAIN.TASK "lm"): the section reaches the arch's
 # factory key by key in lower case; the factory lives in the module that
 # MODEL.MODULE names and takes the keys its family's ``Sizes`` names
-# (models/nemotron_h.py, models/qwen3_next.py; a key both read is here once).
+# (models/nemotron_h.py, models/qwen3_next.py, models/deepseek_v3.py; a key that two read is here once).
 # Widths are the model's; the *_HELD counts, KV/group counts and VOCAB are
 # what this chip holds of a layer shared over chips (all of it by default:
 # the published counts of config/nemotron3_super.yaml's and
-# config/qwen3_next.yaml's sources are in those files' comments).
+# config/qwen3_next.yaml's and config/kanana2_30b.yaml's sources are in those
+# files' comments).
 _C.LM = CN()
 _C.LM.SEQ_LEN = 8192        # tokens a row (the batch ships SEQ_LEN + 1: inputs and labels are one leaf shifted)
 _C.LM.VOCAB = 16384         # rows of embedding and head held; ids are drawn from 0 ... VOCAB - 1
 # one letter a layer. nemotron_h: M Mamba-2, * attention, E latent experts; qwen3_next: G gated delta rule,
-# A gated attention, each followed by its expert block
+# A gated attention, each followed by its expert block; deepseek_v3: every layer latent attention, then D a dense
+# feed-forward or E an expert block
 _C.LM.PATTERN = "EMEMEMEMEM*"
 _C.LM.LAYERS_TOTAL = 88     # depth of the whole model (scales the residual projections' init)
 _C.LM.DIM = 4096
@@ -212,6 +214,12 @@ _C.LM.LINEAR_KEY_DIM = 128
 _C.LM.LINEAR_VALUE_DIM = 128
 _C.LM.ROPE_SHARE = 0.25        # of a head's dimensions, the first, that the rotary embedding turns
 _C.LM.ROPE_THETA = 1.0e7
+# what deepseek_v3 reads beside the keys above (DIM, ATTN_HEADS, ROPE_THETA, ROUTED_SCALE, the experts')
+_C.LM.KV_LATENT = 512          # latent attention: the normed latent that keys and values are expanded from
+_C.LM.QK_NOPE_DIM = 128        # a head's query/key dimensions that carry no position ...
+_C.LM.QK_ROPE_DIM = 64         # ... and its rotary ones; the key's are one head that all heads share
+_C.LM.V_HEAD_DIM = 128
+_C.LM.DENSE_WIDTH = 6144       # the gated feed-forward of a layer that has no experts
 # tokens a block of the loss: float32 logits exist for one block at a time
 _C.LM.LOSS_BLOCK = 2048
 

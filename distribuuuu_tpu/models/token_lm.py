@@ -1,17 +1,19 @@
 """What the token-sequence models share: a stack of residual layers over token embeddings.
 
-A family (`models/nemotron_h.py`, `models/qwen3_next.py`) gives its sizes, the
+A family (`models/nemotron_h.py`, `models/qwen3_next.py`, `models/deepseek_v3.py`) gives its sizes, the
 leaves of one layer of each kind of its pattern string, their initialisers
 and the pure function that runs one layer on its own leaves; `TokenLM` is the
 rest: token embedding in, the layers, a final RMSNorm and an untied head out.
 
 The parameters are one flat dict (`param_shapes`). Where the pattern repeats
 a unit (``EMEMEMEMEM*`` is five times ``EM``, then ``*``; ``GGGA`` three
-times ``G``, then ``A``), the repeats' parameters are one leaf with the
-repeats leading (``U<j>_<leaf> [repeats, ...]`` for the unit's layer ``j``)
-and run as one `lax.scan`, so the compiler sees a unit once and no copy of a
-parameter is made to stack it. The layers after the repeats are
-``L<i>_<leaf>``; beside them ``embed``, ``head``, ``norm_f``.
+times ``G``, then ``A``; ``DEEEE`` is ``D``, then four times ``E``), the
+repeats' parameters are one leaf with the repeats leading (``U<j>_<leaf>
+[repeats, ...]`` for the unit's layer ``j``) and run as one `lax.scan`, so
+the compiler sees a unit once and no copy of a parameter is made to stack it.
+The stack takes layers before the repeats as well as after them, each on its
+own: ``L<i>_<leaf>`` for layer ``i`` of the pattern; beside them ``embed``,
+``head``, ``norm_f``.
 
 ``__call__`` returns the final-normed hidden states and the routing
 counters; `head_logits` maps hidden states to logits, so that a loss can take
@@ -53,27 +55,30 @@ def mm(x, kernel):
     return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=F32)
 
 
-def repeated_unit(pattern: str) -> tuple[int, int]:
-    """``(unit length, repeats)`` of the prefix that `TokenLM` scans: the unit and count, at least two,
-    that cover most of the pattern from its start; ``(len, 1)`` where nothing repeats."""
-    best = (len(pattern), 1)
-    covered = 0
-    for k in range(1, len(pattern) // 2 + 1):
-        r = 1
-        while pattern[r * k:(r + 1) * k] == pattern[:k]:
-            r += 1
-        if r >= 2 and k * r > covered:
-            best, covered = (k, r), k * r
+def repeated_unit(pattern: str) -> tuple[int, int, int]:
+    """``(first, unit length, repeats)`` of the stretch that `TokenLM` scans: the unit and count, at least two,
+    that cover most of the pattern, the layers ``0 … first - 1`` before it (the earliest of equal stretches, the
+    shortest unit of one stretch: ``DEEEE`` is ``D``, then ``E`` four times); ``(0, len, 1)`` where nothing repeats."""
+    best, covered = (0, len(pattern), 1), 0
+    for first in range(len(pattern)):
+        for k in range(1, (len(pattern) - first) // 2 + 1):
+            r = 1
+            while pattern[first + r * k:first + (r + 1) * k] == pattern[first:first + k]:
+                r += 1
+            if r >= 2 and k * r > covered:
+                best, covered = (first, k, r), k * r
     return best
 
 
 def layer_prefixes(pattern: str) -> list[tuple[str, str, int]]:
-    """``(prefix, kind, repeats)`` of every group of leaves: ``U<j>`` for layer ``j`` of the repeated unit
-    (its leaves lead with the repeats), ``L<i>`` for each layer after the repeats (``repeats`` 0: no such axis)."""
-    unit, repeats = repeated_unit(pattern)
+    """``(prefix, kind, repeats)`` of every group of leaves, in the order the layers run: ``L<i>`` for each layer
+    before the repeats (``repeats`` 0: no such axis), ``U<j>`` for layer ``j`` of the repeated unit (its leaves lead
+    with the repeats), ``L<i>`` for each layer after them."""
+    first, unit, repeats = repeated_unit(pattern)
     scanned = unit * repeats if repeats > 1 else 0
-    return ([(f"U{j}", pattern[j], repeats) for j in range(unit if scanned else 0)]
-            + [(f"L{i}", pattern[i], 0) for i in range(scanned, len(pattern))])
+    single = lambda layers: [(f"L{i}", pattern[i], 0) for i in layers]
+    return (single(range(first)) + [(f"U{j}", pattern[first + j], repeats) for j in range(unit if scanned else 0)]
+            + single(range(first + scanned, len(pattern))))
 
 
 def param_shapes(s, layer_shapes: Callable) -> dict[str, tuple]:
@@ -163,22 +168,23 @@ class TokenLM(nn.Module):
         loads = []
         groups = layer_prefixes(s.pattern)
         unit = [(prefix, kind) for prefix, kind, repeats in groups if repeats]
-        if unit:
-            def one_unit(h, leaves_and_buffers):
-                counts = []
-                for (prefix, kind), (leaves, b_corr) in zip(unit, leaves_and_buffers):
-                    with jax.named_scope(prefix):
-                        h, c = scanned_layer(kind, leaves, b_corr, h, s)
-                    counts += [] if c is None else [c]
-                return h, counts
 
-            h, counts = lax.scan(one_unit, h, [self._leaves(prefix) for prefix, _ in unit])
-            loads += [c.astype(F32) for c in counts]  # each [repeats, held]
-        for prefix, kind, repeats in groups:
+        def one_unit(h, leaves_and_buffers):
+            counts = []
+            for (prefix, kind), (leaves, b_corr) in zip(unit, leaves_and_buffers):
+                with jax.named_scope(prefix):
+                    h, c = scanned_layer(kind, leaves, b_corr, h, s)
+                counts += [] if c is None else [c]
+            return h, counts
+
+        for prefix, kind, repeats in groups:  # in the order the layers run: those before the repeats, the repeats, the rest
             if not repeats:
                 with jax.named_scope(prefix):
                     h, c = one_layer(kind, *self._leaves(prefix), h, s)
                 loads += [] if c is None else [c.astype(F32)[None]]
+            elif prefix == unit[0][0]:
+                h, counts = lax.scan(one_unit, h, [self._leaves(name) for name, _ in unit])
+                loads += [c.astype(F32) for c in counts]  # each [repeats, held]
         hidden = self.final_norm(h, self.p["norm_f"], s.eps).astype(self.dtype)
         counters = {}
         if loads:

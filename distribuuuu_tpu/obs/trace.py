@@ -75,10 +75,12 @@ HOST_PHASES = {
 }
 #: inside a model, what flax's module paths cannot tell apart: the scan
 #: proper of a state-space mixer (ops/ssm.py), the recurrence proper of a
-#: delta-rule linear-attention mixer (ops/gdn.py), and an expert layer's
-#: routing (scores, top-k, weights, sorting tokens to experts) and grouped
-#: products over the experts held (parallel/moe.py)
-MODEL_SCOPES = ("ssm_scan", "gdn_scan", "moe_route", "moe_experts")
+#: delta-rule linear-attention mixer (ops/gdn.py), the causal core of a
+#: latent-attention mixer (scores, mask, softmax, weighted values; put round
+#: `ops.attention.latent_causal_attention` by models/deepseek_v3.py), and an
+#: expert layer's routing (scores, top-k, weights, sorting tokens to experts)
+#: and grouped products over the experts held (parallel/moe.py)
+MODEL_SCOPES = ("ssm_scan", "gdn_scan", "latent_attn", "moe_route", "moe_experts")
 #: what follows the gradient inside the jitted train step, the loss outside
 #: the module (trainer.make_train_step), and `MODEL_SCOPES`
 STEP_SCOPES = ("grad_sync", "optimizer", "guard", "metrics", "loss") + MODEL_SCOPES

@@ -743,7 +743,8 @@ CAUSAL_BLOCK = 1024
 
 def _causal_block(q, k, v, start: int):
     """One block of queries ``q [B, Q, G, R, hd]`` (rows ``start …``) against
-    the keys and values ``[B, K, G, hd]`` of rows ``0 … start + Q - 1``."""
+    the keys ``[B, K, G, hd]`` and values ``[B, K, G, dv]`` of rows
+    ``0 … start + Q - 1``; the scale is that of the query/key width."""
     head_dim = q.shape[-1]
     s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k, preferred_element_type=jnp.float32)
     rows = start + jnp.arange(q.shape[1])[:, None]
@@ -754,8 +755,11 @@ def _causal_block(q, k, v, start: int):
 
 def xla_causal_attention(qkv, num_heads: int, kv_heads: int, block: int = CAUSAL_BLOCK):
     """Causal grouped-query attention over packed ``qkv [B, L, (H + 2·G)·hd]``
-    (``H`` query heads, then ``G`` key heads, then ``G`` value heads; query
-    heads ``g·H/G …`` read key/value head ``g``) → ``[B, L, H·hd]``.
+    (``H`` query heads, then ``G`` key heads, then ``G`` value heads, all of
+    one width; query heads ``g·H/G …`` read key/value head ``g``) →
+    ``[B, L, H·hd]``. Heads whose values are not as wide as their keys, or
+    whose keys share a part, go through `latent_causal_attention`, the same
+    blocks.
 
     Blocks of `CAUSAL_BLOCK` query rows, each against the keys up to its own
     last row only, so the products above the diagonal are never formed (half
@@ -768,6 +772,17 @@ def xla_causal_attention(qkv, num_heads: int, kv_heads: int, block: int = CAUSAL
     return rows_in_groups(lambda rows: _causal_attention_of_rows(rows, num_heads, kv_heads, block), (qkv,), score_bytes)
 
 
+def _causal_blocks(q, k, v, block: int):
+    """``q [B, L, G, R, hd]`` against ``k [B, L, G, hd]``, ``v [B, L, G, dv]`` → ``[B, L, G, R, dv]``, for
+    rows whose blocks' scores all stand at once."""
+    one_block = jax.checkpoint(_causal_block, static_argnums=(3,))
+    out = [
+        one_block(q[:, start:start + block], k[:, :start + block], v[:, :start + block], start)
+        for start in range(0, q.shape[1], block)
+    ]
+    return jnp.concatenate(out, axis=1)
+
+
 def _causal_attention_of_rows(qkv, num_heads: int, kv_heads: int, block: int):
     """`xla_causal_attention` for rows whose blocks' scores all stand at once."""
     b, l, width = qkv.shape
@@ -775,12 +790,31 @@ def _causal_attention_of_rows(qkv, num_heads: int, kv_heads: int, block: int):
     q, k, v = jnp.split(qkv, (num_heads * hd, (num_heads + kv_heads) * hd), axis=-1)
     q = q.reshape(b, l, kv_heads, num_heads // kv_heads, hd)
     k, v = k.reshape(b, l, kv_heads, hd), v.reshape(b, l, kv_heads, hd)
-    one_block = jax.checkpoint(_causal_block, static_argnums=(3,))
-    out = [
-        one_block(q[:, start:start + block], k[:, :start + block], v[:, :start + block], start)
-        for start in range(0, l, block)
-    ]
-    return jnp.concatenate(out, axis=1).reshape(b, l, num_heads * hd)
+    return _causal_blocks(q, k, v, block).reshape(b, l, num_heads * hd)
+
+
+def latent_causal_attention(q, k_own, k_shared, v, block: int = CAUSAL_BLOCK):
+    """The causal core of multi-head latent attention in its expanded (training) form: ``q [B, L, H, dk + dr]``
+    against keys that are per head in their first ``dk`` dimensions (``k_own [B, L, H, dk]``, expanded from the
+    latent) and one head given to all ``H`` in their last ``dr`` (``k_shared [B, L, dr]``, the rotary part), and
+    values of a width of their own (``v [B, L, H, dv]``) → ``[B, L, H·dv]``. Scores of head ``i``:
+    ``(q_i[:dk] · k_own_i + q_i[dk:] · k_shared) / sqrt(dk + dr)``, causal softmax in float32.
+
+    The keys are formed whole a head (the shared part copied to each, for the rows of one group at a time) and go
+    through the blocks of `xla_causal_attention`: `CAUSAL_BLOCK` query rows against the keys up to their last
+    row, each block rematerialised, the rows through `ops.rows.rows_in_groups` by the last block's float32 scores
+    (32 heads at 8192 keys: 1 GiB a row, so a row at a time there). The absorbed form (scores against the latent
+    itself) is a decoder's and is not here."""
+    _, l, heads, _ = q.shape
+    score_bytes = 4 * heads * min(block, l) * l
+
+    def of_rows(q, k_own, k_shared, v):
+        shared = jnp.broadcast_to(k_shared[:, :, None, :], (*k_own.shape[:-1], k_shared.shape[-1]))
+        k = jnp.concatenate([k_own, shared], axis=-1)
+        out = _causal_blocks(q[:, :, :, None, :], k, v, block)  # every head its own key/value head
+        return out.reshape(*out.shape[:2], heads * v.shape[-1])
+
+    return rows_in_groups(of_rows, (q, k_own, k_shared, v), score_bytes)
 
 
 def partial_rotary(x, rotary_dim: int, theta: float, first_position: int = 0):
@@ -810,8 +844,11 @@ def self_attention(qkv, num_heads: int, *, kv_heads: int | None = None, causal: 
     a Mosaic call could not be partitioned.
 
     Causal, grouped-query (``causal=True``, ``kv_heads`` key/value heads;
-    ``qkv [B, L, (H + 2·G)·hd]``): `xla_causal_attention` everywhere; no
-    kernel computes it yet."""
+    ``qkv [B, L, (H + 2·G)·hd]``, one width for queries, keys and values):
+    `xla_causal_attention` everywhere; no kernel computes it yet. Latent
+    attention's heads (keys wider than values, a key part that all heads
+    share) are not packed and take the same blocks through
+    `latent_causal_attention`."""
     if causal:
         return xla_causal_attention(qkv, num_heads, num_heads if kv_heads is None else kv_heads)
     if kv_heads not in (None, num_heads):

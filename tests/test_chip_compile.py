@@ -388,6 +388,61 @@ def test_qwen3_next_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(to
         assert scope in text, scope
 
 
+# -- the third token model at the widths of config/kanana2_30b.yaml: latent attention's causal core is XLA's
+# blocks, the expert layers' gated products the grouped pair, the leading dense layer stands before one scanned
+# unit; the cell's whole step fits the chip at two rows -----------------------------------------------------
+
+def test_kanana2_30b_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(topo, one_chip, fresh_cfg):
+    """The cell's own step (config/kanana2_30b.yaml: two rows of 8192 tokens, 576 M parameters, Adafactor, the
+    layer checkpoint) compiled for the described chip: the compiler accepts it, and by its own count the step's
+    peak (the state and what it holds beside it) leaves room on the chip's 15.75 GiB for the benchmark's second
+    copy of the weights; the four expert layers are one loop's body, so the step holds the core's scope in two
+    layers' worth of ops and not five; the held experts' gated products are the kernel pair; every model scope
+    stands in it."""
+    from distribuuuu_tpu import optim, trainer
+    from distribuuuu_tpu.ops.interpret import set_pallas_interpret
+
+    cfg = fresh_cfg  # restores the config and the model-trace globals the build sets from it (bfloat16 norms)
+    cfg.merge_from_file(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                                     "config", "kanana2_30b.yaml"))
+    interpret = set_pallas_interpret(False)  # conftest asks for the interpreter; the chip's route does not
+    try:
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        model = trainer._build_cfg_model()
+        tx = optim.construct_optimizer()
+
+        def init(key):
+            variables = model.init(key, model.dummy_input(0), train=False)
+            return trainer.TrainState(params=variables["params"], batch_stats=variables["batch_stats"],
+                                      opt_state=tx.init(variables["params"]))
+
+        replicated = NamedSharding(mesh, P())
+        struct = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated)
+        state = jax.tree.map(struct, jax.eval_shape(init, jax.random.key(0)))
+        rows = cfg.TRAIN.BATCH_SIZE
+        batch = {"tokens": jax.ShapeDtypeStruct((rows, cfg.LM.SEQ_LEN + 1), jnp.int32,
+                                                sharding=NamedSharding(mesh, P("data", None)))}
+        step = trainer.make_train_step(model, tx, mesh, topk=5)
+        compiled = step.lower(state, batch, struct(jnp.float32(0.0)), struct(jax.eval_shape(lambda: jax.random.key(1)))).compile()
+    finally:
+        set_pallas_interpret(interpret)
+    memory = compiled.memory_analysis()
+    weights = 4 * sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state.params))
+    assert rows == 2 and weights == 4 * 575_955_456
+    assert {k: v.shape for k, v in state.batch_stats.items()} == {"U0_b_corr": (4, 128)}
+    assert weights <= memory.argument_size_in_bytes <= weights + 64 * 2**20  # the state: Adafactor's is rows and columns
+    assert memory.alias_size_in_bytes >= weights  # donated: the new state takes the old one's place
+    peak = memory.peak_memory_in_bytes  # the state and, beside it, the most the step holds at a time
+    assert peak + weights <= 15.75 * 2**30, f"{peak / 2**30:.2f} GiB beside a second copy of {weights / 2**30:.2f}"
+    text = compiled.as_text()
+    assert "dtpu_moe_gmm" in text and "dtpu_moe_tgmm" in text
+    for scope in ("dtpu.latent_attn", "dtpu.moe_route", "dtpu.moe_experts", "dtpu.optimizer", "dtpu.loss"):
+        assert scope in text, scope
+    # the core's products stand under L0 (the leading layer, unrolled) and U0 (the unit, once) and under no other layer
+    layers_of_the_core = set(re.findall(r"/(L\d+|U\d+)/dtpu\.latent_attn/", text))
+    assert layers_of_the_core == {"L0", "U0"}
+
+
 # ops/moe_kernel.py is refused by Mosaic at every shape its VMEM guard admits
 # (larger ones hand over to the einsum formulation before the kernel is
 # reached). No trainer path calls it; repair or deletion belongs to the first

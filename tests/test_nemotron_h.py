@@ -22,6 +22,8 @@ from distribuuuu_tpu.obs import trace as obs_trace
 from distribuuuu_tpu.obs.journal import read_journal, validate_journal
 from distribuuuu_tpu.runtime import data_mesh
 
+import _token_layers
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -60,9 +62,7 @@ def to_program(params: dict, stats: dict, model) -> tuple[dict, dict]:
     """The reference's per-layer leaves (``L3.w1``) in the program's flat tree (``U1_w1 [repeats, ...]``)."""
     m = nh()
     s = model.sizes
-    unit, repeats = token_lm.repeated_unit(s.pattern)
-    layers_of = {f"U{j}": [r * unit + j for r in range(repeats)] for j in range(unit)} if repeats > 1 else {}
-    layers_of.update({f"L{i}": i for i in range(unit * repeats if repeats > 1 else 0, len(s.pattern))})
+    layers_of = _token_layers.layers_of(s.pattern)
 
     def leaf(name, source):
         prefix, _, short = name.partition("_")
@@ -224,30 +224,12 @@ def test_three_lamb_steps_through_the_trainer_match_the_reference(fresh_cfg, pat
         state, metrics = step(state, {"tokens": tokens}, jnp.float32(0.01), jax.random.key(1))
         loss, grads = ref_grads(flat, tokens)
         ref_params, ref_state = _lamb(ref_params, to_program(grads, stats, model)[0], ref_state, 0.01, hp)
-        flat = _from_program(ref_params, model)
+        flat = _token_layers.from_program(ref_params, model.sizes.pattern)
         assert float(metrics["loss_sum"] / metrics["n"]) == pytest.approx(float(loss), rel=2e-5)
         if "E" in pattern:
             assert set(obs.WINDOW_COUNTERS) <= set(metrics)
     for name, value in ref_params.items():
         assert rel(state.params[name], value) <= 2e-4, name
-
-
-def _from_program(tree: dict, model) -> dict:
-    """The program's leaves back as the reference's per-layer ones."""
-    m = nh()
-    s = model.sizes
-    unit, repeats = token_lm.repeated_unit(s.pattern)
-    out = {}
-    for name, value in tree.items():
-        prefix, _, short = name.partition("_")
-        if prefix.startswith("U") and prefix[1:].isdigit():
-            for r in range(repeats):
-                out[f"L{r * unit + int(prefix[1:])}.{short}"] = value[r]
-        elif prefix.startswith("L") and prefix[1:].isdigit():
-            out[f"{prefix}.{short}"] = value
-        else:
-            out[name] = value
-    return out
 
 
 def _adafactor(params, grads, state, lr, min_dim):
@@ -679,8 +661,8 @@ def test_the_token_model_needs_its_task_and_the_task_its_synthetic_rows(fresh_cf
     assert batch["tokens"].shape == (2, 17) and batch["tokens"].max() < 32
 
 
-@pytest.mark.parametrize("pattern,want", [("EMEMEMEMEM*", (2, 5)), ("M", (1, 1)), ("EM*", (3, 1)), ("MMMM", (1, 4)),
-                                         ("MEMEMEM*EMEMEMEM*", (2, 3))])
+@pytest.mark.parametrize("pattern,want", [("EMEMEMEMEM*", (0, 2, 5)), ("M", (0, 1, 1)), ("EM*", (0, 3, 1)), ("MMMM", (0, 1, 4)),
+                                         ("MEMEMEM*EMEMEMEM*", (8, 2, 4))])  # the longest stretch: `EM` four times after eight layers
 def test_repeated_unit_of_a_pattern(pattern, want):
     assert token_lm.repeated_unit(pattern) == want
 

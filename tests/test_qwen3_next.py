@@ -21,6 +21,8 @@ import pytest
 from distribuuuu_tpu import obs, optim, trainer
 from distribuuuu_tpu.runtime import data_mesh
 
+import _token_layers
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -56,18 +58,9 @@ def model_of(pattern: str, sizes: dict, dtype=jnp.float32, remat: bool = True):
     return m.Qwen3Next(m.Sizes(pattern=pattern, **sizes), dtype=dtype, remat=remat)
 
 
-def _layers_of(pattern: str) -> dict:
-    from distribuuuu_tpu.models import token_lm
-
-    unit, repeats = token_lm.repeated_unit(pattern)
-    layers_of = {f"U{j}": [r * unit + j for r in range(repeats)] for j in range(unit)} if repeats > 1 else {}
-    layers_of.update({f"L{i}": i for i in range(unit * repeats if repeats > 1 else 0, len(pattern))})
-    return layers_of
-
-
 def to_program(params: dict, model) -> dict:
     """The reference's per-layer leaves (``L1.w1``) in the program's flat tree (``U0_w1 [repeats, ...]``)."""
-    layers_of = _layers_of(model.sizes.pattern)
+    layers_of = _token_layers.layers_of(model.sizes.pattern)
 
     def leaf(name):
         prefix, _, short = name.partition("_")
@@ -79,19 +72,6 @@ def to_program(params: dict, model) -> dict:
         return params[f"L{where}.{short}"]
 
     return {name: leaf(name) for name in qn().param_shapes(model.sizes)}
-
-
-def from_program(tree: dict, model) -> dict:
-    layers_of = _layers_of(model.sizes.pattern)
-    out = {}
-    for name, value in tree.items():
-        prefix, _, short = name.partition("_")
-        where = layers_of.get(prefix)
-        if isinstance(where, list):
-            out.update({f"L{i}.{short}": value[r] for r, i in enumerate(where)})
-        else:
-            out[name if where is None else f"L{where}.{short}"] = value
-    return out
 
 
 def seeded(sizes: dict, seed: int = 3) -> dict:
@@ -375,7 +355,7 @@ def test_three_adafactor_steps_through_the_trainer_match_the_reference(fresh_cfg
         state, metrics = step(state, {"tokens": tokens}, jnp.float32(0.01), jax.random.key(1))
         loss, grads = ref_grads(flat, tokens)
         ref_params, ref_state = _adafactor(ref_params, to_program(grads, model), ref_state, 0.01, 16)
-        flat = from_program(ref_params, model)
+        flat = _token_layers.from_program(ref_params, model.sizes.pattern)
         assert float(metrics["loss_sum"] / metrics["n"]) == pytest.approx(float(loss), rel=2e-5)
         assert set(obs.WINDOW_COUNTERS) <= set(metrics)
     # Adafactor divides a gradient by its own size, entry by entry or row and column: where the true gradient is
