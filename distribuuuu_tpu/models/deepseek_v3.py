@@ -61,7 +61,7 @@ from distribuuuu_tpu.models import token_lm
 from distribuuuu_tpu.models.registry import register_model
 from distribuuuu_tpu.models.token_lm import mm, rms_norm
 from distribuuuu_tpu.obs.trace import step_scope
-from distribuuuu_tpu.ops.attention import latent_causal_attention, partial_rotary
+from distribuuuu_tpu.ops.attention import CAUSAL_LSE, CAUSAL_OUT, latent_causal_attention, partial_rotary
 from distribuuuu_tpu.parallel.moe import ROUTE_IDX, held_experts, round_rows_for, sigmoid_topk_route, silu_gated
 
 #: what a layer's checkpoint keeps for the backward pass (``remat=True``), each in the dtype it has; everything
@@ -69,8 +69,9 @@ from distribuuuu_tpu.parallel.moe import ROUTE_IDX, held_experts, round_rows_for
 KEPT = (
     "moe_router_logits",  # the router's product at `highest`, the dearest a FLOP: float32, 4 B an expert of the router
     ROUTE_IDX,            # `top_k`'s full sort over the experts: int32, 4 B a chosen expert (named in parallel/moe.py)
-    "latent_attn_out",    # the causal core's output: its blocks are rematerialised themselves (ops/attention.py), so the
-                          # layer's recomputation would run its forward pass a third time: the compute dtype, heads x value width
+    CAUSAL_OUT,           # the causal core's output and, on the kernels' route, the rows' log-sum-exp (float32, 4 B a head
+    CAUSAL_LSE,           # and row): with both kept the layer's recomputation never runs the core's forward again, and the
+                          # kernels' backward reads them as they are (ops/attention.causal_attention names them)
 )
 
 
@@ -145,7 +146,7 @@ def latent_attention_mixer(p: dict, u, s: Sizes):
     k_n, v = jnp.split(mm(latent, p["kv_b"]).astype(u.dtype).reshape(b, l, h, dn + dv), (dn,), axis=-1)
     with step_scope("latent_attn"):
         out = latent_causal_attention(q, k_n, k_r, v)
-    return mm(checkpoint_name(out, "latent_attn_out"), p["o"])
+    return mm(out, p["o"])
 
 
 def dense_feed_forward(p: dict, u):

@@ -1,6 +1,13 @@
 """Fused attention kernels (Pallas TPU) with their XLA formulations.
 
-Two families, sorted apart on purpose:
+Three families, sorted apart on purpose:
+
+**Causal softmax attention** (the token models; near the bottom of this file): `causal_attention` is the
+one entry point of grouped-query heads (`self_attention(causal=True)`, packed) and of latent attention's
+heads (`latent_causal_attention`: keys wider than values, a key part all heads share). It takes the flash
+pair of `ops/causal_attention.py` (`dtpu_causal_attn_fwd`, `dtpu_causal_attn_bwd`: the scores in VMEM,
+the causal half only) where the step is traced for TPUs at shapes the pair tiles, and XLA's blocks
+(`xla_causal_core`) elsewhere; device and shape decide, nothing else.
 
 **Bias-free self-attention from packed qkv** (ViT, MAE; bottom of this file):
 `self_attention` is what `models/vit.py` calls. It takes one fused forward
@@ -43,8 +50,11 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from distribuuuu_tpu.ops import causal_attention as causal_attention_kernels
+from distribuuuu_tpu.ops.causal_attention import nn, nt, tn
 from distribuuuu_tpu.ops.interpret import pallas_interpret
 from distribuuuu_tpu.ops.rows import rows_in_groups
 from distribuuuu_tpu.ops.vmem_guard import DEFAULT_VMEM_BUDGET_MB, VmemBudgetGuard
@@ -569,18 +579,6 @@ def _cols(part: int, group: int, d_model: int) -> slice:
     return slice(start, start + 128)
 
 
-def _nt(a, b):  # a·bᵀ, float32 accumulation
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-
-def _nn(a, b):
-    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-
-def _tn(a, b):  # aᵀ·b
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-
 def _self_attn_fwd_kernel(qkv_ref, o_ref, lse_ref, *, num_heads: int, head_dim: int):
     d_model = num_heads * head_dim
     scale = head_dim**-0.5
@@ -591,12 +589,12 @@ def _self_attn_fwd_kernel(qkv_ref, o_ref, lse_ref, *, num_heads: int, head_dim: 
         q, k, v = (qkv_ref[0, :, _cols(part, g, d_model)] for part in range(3))
         out = None
         for j, mask in enumerate(masks):
-            s = _nt(_keep(mask, q), k) * scale  # [L, L] float32
+            s = nt(_keep(mask, q), k) * scale  # [L, L] float32
             m = jnp.max(s, axis=-1, keepdims=True)
             e = jnp.exp(s - m)
             l = jnp.sum(e, axis=-1, keepdims=True)
             p = (e * (1.0 / l)).astype(v.dtype)
-            o = _nn(p, v)  # [L, 128]: this head's lanes hold its output
+            o = nn(p, v)  # [L, 128]: this head's lanes hold its output
             out = o if out is None else jnp.where(mask, o, out)
             lse_all = jnp.where(head_lane == g * len(masks) + j, m + jnp.log(l), lse_all)
         o_ref[0, :, _cols(0, g, d_model)] = out.astype(o_ref.dtype)
@@ -621,13 +619,13 @@ def _self_attn_bwd_kernel(
             head = head_lane == g * len(masks) + j
             lse = jnp.sum(jnp.where(head, lse_all, 0.0), axis=-1, keepdims=True)
             qj, doj = _keep(mask, q), _keep(mask, do)
-            p = jnp.exp(_nt(qj, k) * scale - lse)  # the forward's weights, float32
-            dp = _nt(doj, v)
+            p = jnp.exp(nt(qj, k) * scale - lse)  # the forward's weights, float32
+            dp = nt(doj, v)
             delta = jnp.sum(_keep(mask, row), axis=-1, keepdims=True)
             ds = (p * (dp - delta) * scale).astype(q.dtype)
-            dv = dv + _tn(p.astype(v.dtype), doj)
-            dq = dq + _nn(ds, _keep(mask, k))
-            dk = dk + _tn(ds, qj)
+            dv = dv + tn(p.astype(v.dtype), doj)
+            dq = dq + nn(ds, _keep(mask, k))
+            dk = dk + tn(ds, qj)
         for part, grad in enumerate((dq, dk, dv)):
             dqkv_ref[0, :, _cols(part, g, d_model)] = grad.astype(dqkv_ref.dtype)
 
@@ -736,9 +734,18 @@ def self_attention_fuses(
     )
 
 
-#: query rows a block of `xla_causal_attention`: the float32 scores of one
-#: block against its keys are what goes through HBM at a time
+#: query rows a block of `xla_causal_core`: the float32 scores of one block against its keys are what goes
+#: through HBM at a time
 CAUSAL_BLOCK = 1024
+#: `jax.monitoring` events of `causal_attention`, one per call traced for a mesh: which route it took. The
+#: journal's ``counters`` records carry them (obs/monitors.py)
+CAUSAL_FUSED_EVENT = "causal_attn_fused_calls"
+CAUSAL_XLA_EVENT = "causal_attn_xla_calls"
+#: what `causal_attention` names for a layer checkpoint, on either route: its output, and on the kernels' route
+#: the rows' log-sum-exp, the one other value their backward reads that is not an input. A model whose
+#: checkpoint keeps both never runs the core's forward a second time (`models/deepseek_v3.KEPT`)
+CAUSAL_OUT = "causal_attn_out"
+CAUSAL_LSE = "causal_attn_lse"
 
 
 def _causal_block(q, k, v, start: int):
@@ -753,25 +760,6 @@ def _causal_block(q, k, v, start: int):
     return jnp.einsum("bgrqk,bkgd->bqgrd", w.astype(v.dtype), v)
 
 
-def xla_causal_attention(qkv, num_heads: int, kv_heads: int, block: int = CAUSAL_BLOCK):
-    """Causal grouped-query attention over packed ``qkv [B, L, (H + 2·G)·hd]``
-    (``H`` query heads, then ``G`` key heads, then ``G`` value heads, all of
-    one width; query heads ``g·H/G …`` read key/value head ``g``) →
-    ``[B, L, H·hd]``. Heads whose values are not as wide as their keys, or
-    whose keys share a part, go through `latent_causal_attention`, the same
-    blocks.
-
-    Blocks of `CAUSAL_BLOCK` query rows, each against the keys up to its own
-    last row only, so the products above the diagonal are never formed (half
-    of them at large L) and no ``L x L`` tensor exists; each block is
-    rematerialised in the backward pass, so what is kept for it is q, k, v.
-    The rows go through `ops.rows.rows_in_groups` by the last block's float32
-    scores (16 heads at 8192 keys: 512 MiB a row, so a row at a time there)."""
-    _, l, _ = qkv.shape
-    score_bytes = 4 * num_heads * min(block, l) * l
-    return rows_in_groups(lambda rows: _causal_attention_of_rows(rows, num_heads, kv_heads, block), (qkv,), score_bytes)
-
-
 def _causal_blocks(q, k, v, block: int):
     """``q [B, L, G, R, hd]`` against ``k [B, L, G, hd]``, ``v [B, L, G, dv]`` → ``[B, L, G, R, dv]``, for
     rows whose blocks' scores all stand at once."""
@@ -783,38 +771,116 @@ def _causal_blocks(q, k, v, block: int):
     return jnp.concatenate(out, axis=1)
 
 
-def _causal_attention_of_rows(qkv, num_heads: int, kv_heads: int, block: int):
-    """`xla_causal_attention` for rows whose blocks' scores all stand at once."""
+def xla_causal_core(q, k, v, k_shared=None, block: int = CAUSAL_BLOCK):
+    """`causal_attention` in XLA: blocks of `CAUSAL_BLOCK` query rows, each against the keys up to its own
+    last row only, so the products above the diagonal are never formed (half of them at large L) and no
+    ``L x L`` tensor exists; each block is rematerialised in the backward pass, so what is kept for it is q,
+    k, v. A shared key part is copied to every key head, for the rows of one group at a time. The rows go
+    through `ops.rows.rows_in_groups` by the last block's float32 scores (32 heads at 8192 keys: 1 GiB a
+    row, so a row at a time there). The route off the chip and the kernels' reference in the tests."""
+    _, l, heads, width = q.shape
+    groups = k.shape[2]
+    score_bytes = 4 * heads * min(block, l) * l
+
+    def of_rows(q, k, v, *shared):
+        if shared:
+            k = jnp.concatenate([k, jnp.broadcast_to(shared[0][:, :, None, :], (*k.shape[:-1], shared[0].shape[-1]))],
+                                axis=-1)
+        rows = q.shape[0]
+        out = _causal_blocks(q.reshape(rows, l, groups, heads // groups, width), k, v, block)
+        return out.reshape(rows, l, heads, v.shape[-1])
+
+    operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
+    return rows_in_groups(of_rows, operands, score_bytes)
+
+
+def xla_causal_attention(qkv, num_heads: int, kv_heads: int, block: int = CAUSAL_BLOCK):
+    """`xla_causal_core` over packed ``qkv [B, L, (H + 2·G)·hd]`` (``H`` query heads, then ``G`` key heads,
+    then ``G`` value heads, all of one width; query heads ``g·H/G …`` read key/value head ``g``) →
+    ``[B, L, H·hd]``."""
+    q, k, v = _unpacked(qkv, num_heads, kv_heads)
+    out = xla_causal_core(q, k, v, block=block)
+    return out.reshape(*out.shape[:2], -1)
+
+
+def _unpacked(qkv, num_heads: int, kv_heads: int):
     b, l, width = qkv.shape
     hd = width // (num_heads + 2 * kv_heads)
     q, k, v = jnp.split(qkv, (num_heads * hd, (num_heads + kv_heads) * hd), axis=-1)
-    q = q.reshape(b, l, kv_heads, num_heads // kv_heads, hd)
-    k, v = k.reshape(b, l, kv_heads, hd), v.reshape(b, l, kv_heads, hd)
-    return _causal_blocks(q, k, v, block).reshape(b, l, num_heads * hd)
+    return q.reshape(b, l, num_heads, hd), k.reshape(b, l, kv_heads, hd), v.reshape(b, l, kv_heads, hd)
 
 
-def latent_causal_attention(q, k_own, k_shared, v, block: int = CAUSAL_BLOCK):
+def _heads_first(x):
+    """``[B, L, N, d]`` <-> ``[B, N, L, d]``."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash_causal(q, k, v, k_shared, interpret: bool):
+    return _flash_causal_fwd(q, k, v, k_shared, interpret)[0]
+
+
+def _flash_causal_fwd(q, k, v, k_shared, interpret):
+    kv = _heads_first(jnp.concatenate([k, v], axis=-1))  # a group's keys and values side by side: one operand
+    out, lse = causal_attention_kernels.forward(_heads_first(q), kv, k_shared, interpret=interpret)
+    # named here, where they are the residuals themselves: a checkpoint that keeps them keeps what the backward reads
+    out, lse = checkpoint_name(_heads_first(out), CAUSAL_OUT), checkpoint_name(lse, CAUSAL_LSE)
+    return out, (q, k, v, k_shared, out, lse)
+
+
+def _flash_causal_bwd(interpret, res, d_out):
+    q, k, v, k_shared, out, lse = res
+    b, l, heads, _ = q.shape
+    groups, dk = k.shape[2], k.shape[-1]
+    delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, L, H]
+    stats = jnp.concatenate([lse, jnp.swapaxes(delta, 1, 2)[:, :, None, :]], axis=2)  # [B, H, 2, L]
+    kv = _heads_first(jnp.concatenate([k, v], axis=-1))
+    dq, dkv, dk_r = causal_attention_kernels.backward(_heads_first(q), kv, k_shared, _heads_first(d_out), stats,
+                                                      interpret=interpret)
+    if groups != heads:  # a query head's share of its group's gradient, float32: summed here
+        dkv = dkv.reshape(b, groups, heads // groups, l, dkv.shape[-1]).sum(axis=2)
+    dk_, dv = jnp.split(_heads_first(dkv), (dk,), axis=-1)
+    d_shared = None if dk_r is None else jnp.sum(dk_r, axis=1).astype(k_shared.dtype)
+    return _heads_first(dq), dk_.astype(k.dtype), dv.astype(v.dtype), d_shared
+
+
+_flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
+
+
+def causal_attention(q, k, v, k_shared=None):
+    """Causal softmax attention, the one entry point of both causal callers: ``q [B, L, H, dk + dr]`` against
+    keys ``k [B, L, G, dk]`` (query heads ``g·H/G …`` read key head ``g``) and, where all heads share one,
+    a key part ``k_shared [B, L, dr]``; values ``v [B, L, G, dv]`` of a width of their own → ``[B, L, H,
+    dv]``. Scores ``(q[:dk]·k + q[dk:]·k_shared) / sqrt(dk + dr)``, causal softmax in float32.
+
+    The kernel pair of `ops/causal_attention.py` (``dtpu_causal_attn_fwd``, ``dtpu_causal_attn_bwd``: the
+    scores in VMEM, the causal half only) where its `fits` admits the call for the devices of the mesh in use
+    (inside the trainer's `shard_map`'d steps; the described chips of a compile-only test count as what they
+    describe), else `xla_causal_core`. Traced outside any mesh it is XLA's, uncounted:
+    ``model.init``, shape inference, a test's plain call. The output is named `CAUSAL_OUT` on either route,
+    and on the kernels' the rows' log-sum-exp `CAUSAL_LSE`, for a layer checkpoint to keep."""
+    mesh = jax.sharding.get_abstract_mesh()
+    fused = False
+    if not mesh.empty:
+        _, l, heads, width = q.shape
+        dr = 0 if k_shared is None else k_shared.shape[-1]
+        fused = causal_attention_kernels.fits(mesh.abstract_device.device_kind, l, heads, k.shape[2], width - dr, dr,
+                                              v.shape[-1], np.dtype(q.dtype).itemsize)
+        jax.monitoring.record_event(CAUSAL_FUSED_EVENT if fused else CAUSAL_XLA_EVENT)
+    if fused:
+        return _flash_causal(q, k, v, k_shared, pallas_interpret())
+    return checkpoint_name(xla_causal_core(q, k, v, k_shared), CAUSAL_OUT)
+
+
+def latent_causal_attention(q, k_own, k_shared, v):
     """The causal core of multi-head latent attention in its expanded (training) form: ``q [B, L, H, dk + dr]``
     against keys that are per head in their first ``dk`` dimensions (``k_own [B, L, H, dk]``, expanded from the
     latent) and one head given to all ``H`` in their last ``dr`` (``k_shared [B, L, dr]``, the rotary part), and
-    values of a width of their own (``v [B, L, H, dv]``) → ``[B, L, H·dv]``. Scores of head ``i``:
-    ``(q_i[:dk] · k_own_i + q_i[dk:] · k_shared) / sqrt(dk + dr)``, causal softmax in float32.
-
-    The keys are formed whole a head (the shared part copied to each, for the rows of one group at a time) and go
-    through the blocks of `xla_causal_attention`: `CAUSAL_BLOCK` query rows against the keys up to their last
-    row, each block rematerialised, the rows through `ops.rows.rows_in_groups` by the last block's float32 scores
-    (32 heads at 8192 keys: 1 GiB a row, so a row at a time there). The absorbed form (scores against the latent
-    itself) is a decoder's and is not here."""
-    _, l, heads, _ = q.shape
-    score_bytes = 4 * heads * min(block, l) * l
-
-    def of_rows(q, k_own, k_shared, v):
-        shared = jnp.broadcast_to(k_shared[:, :, None, :], (*k_own.shape[:-1], k_shared.shape[-1]))
-        k = jnp.concatenate([k_own, shared], axis=-1)
-        out = _causal_blocks(q[:, :, :, None, :], k, v, block)  # every head its own key/value head
-        return out.reshape(*out.shape[:2], heads * v.shape[-1])
-
-    return rows_in_groups(of_rows, (q, k_own, k_shared, v), score_bytes)
+    values of a width of their own (``v [B, L, H, dv]``) → ``[B, L, H·dv]``: `causal_attention`, whose kernels
+    read the shared part once for every head, and whose XLA blocks copy it to each. The absorbed form (scores
+    against the latent itself) is a decoder's and is not here."""
+    out = causal_attention(q, k_own, v, k_shared)
+    return out.reshape(*out.shape[:2], -1)
 
 
 def partial_rotary(x, rotary_dim: int, theta: float, first_position: int = 0):
@@ -845,12 +911,13 @@ def self_attention(qkv, num_heads: int, *, kv_heads: int | None = None, causal: 
 
     Causal, grouped-query (``causal=True``, ``kv_heads`` key/value heads;
     ``qkv [B, L, (H + 2·G)·hd]``, one width for queries, keys and values):
-    `xla_causal_attention` everywhere; no kernel computes it yet. Latent
-    attention's heads (keys wider than values, a key part that all heads
-    share) are not packed and take the same blocks through
-    `latent_causal_attention`."""
+    `causal_attention`, whose kernel pair takes it on TPUs at shapes it tiles
+    and XLA's blocks elsewhere. Latent attention's heads (keys wider than
+    values, a key part that all heads share) are not packed and reach the same
+    entry through `latent_causal_attention`."""
     if causal:
-        return xla_causal_attention(qkv, num_heads, num_heads if kv_heads is None else kv_heads)
+        out = causal_attention(*_unpacked(qkv, num_heads, num_heads if kv_heads is None else kv_heads))
+        return out.reshape(*out.shape[:2], -1)
     if kv_heads not in (None, num_heads):
         raise ValueError("grouped-query attention is implemented for causal=True only")
     mesh = jax.sharding.get_abstract_mesh()

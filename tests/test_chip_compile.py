@@ -384,12 +384,13 @@ def test_qwen3_next_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(to
     assert peak + weights <= 15.75 * 2**30, f"{peak / 2**30:.2f} GiB beside a second copy of {weights / 2**30:.2f}"
     text = compiled.as_text()
     assert "dtpu_moe_gmm" in text and "dtpu_moe_tgmm" in text
+    assert "dtpu_causal_attn_fwd" in text and "dtpu_causal_attn_bwd" in text  # the gated attention's core
     for scope in ("dtpu.gdn_scan", "dtpu.moe_route", "dtpu.moe_experts", "dtpu.optimizer", "dtpu.loss"):
         assert scope in text, scope
 
 
-# -- the third token model at the widths of config/kanana2_30b.yaml: latent attention's causal core is XLA's
-# blocks, the expert layers' gated products the grouped pair, the leading dense layer stands before one scanned
+# -- the third token model at the widths of config/kanana2_30b.yaml: latent attention's causal core is the causal
+# pair, the expert layers' gated products the grouped pair, the leading dense layer stands before one scanned
 # unit; the cell's whole step fits the chip at two rows -----------------------------------------------------
 
 def test_kanana2_30b_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(topo, one_chip, fresh_cfg):
@@ -397,8 +398,9 @@ def test_kanana2_30b_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(t
     layer checkpoint) compiled for the described chip: the compiler accepts it, and by its own count the step's
     peak (the state and what it holds beside it) leaves room on the chip's 15.75 GiB for the benchmark's second
     copy of the weights; the four expert layers are one loop's body, so the step holds the core's scope in two
-    layers' worth of ops and not five; the held experts' gated products are the kernel pair; every model scope
-    stands in it."""
+    layers' worth of ops and not five; the core is the causal pair under its scope, the forward once a layer
+    (`KEPT` keeps its output and log-sum-exp), and the held experts' gated products the grouped pair; every
+    model scope stands in it."""
     from distribuuuu_tpu import optim, trainer
     from distribuuuu_tpu.ops.interpret import set_pallas_interpret
 
@@ -441,6 +443,28 @@ def test_kanana2_30b_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(t
     # the core's products stand under L0 (the leading layer, unrolled) and U0 (the unit, once) and under no other layer
     layers_of_the_core = set(re.findall(r"/(L\d+|U\d+)/dtpu\.latent_attn/", text))
     assert layers_of_the_core == {"L0", "U0"}
+    calls = [re.search(r'op_name="[^"]*/dtpu\.latent_attn/(dtpu_causal_attn_\w+)/pallas_call"', line)
+             for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and "dtpu_causal" in line]
+    assert sorted(c.group(1) for c in calls) == ["dtpu_causal_attn_bwd"] * 2 + ["dtpu_causal_attn_fwd"] * 2
+    # five operands at the most: the benchmark's reader of kernel calls takes no list XLA marks /*index=5*/
+    assert not any("/*index=" in re.search(r"custom-call\(([^)]*)\)", c.string).group(1) for c in calls)
+
+
+def test_causal_pair_compiles_for_v5e_at_the_grouped_cores_smallest_shape(topo, one_chip):
+    """`nemotron3_super.train`'s core (one row of 8192 tokens, 4 query heads of 128 over one key head), through
+    the entry point inside a mesh of the described chip: the route takes the pair, forward and backward."""
+    from distribuuuu_tpu.ops import attention
+    from distribuuuu_tpu.ops.interpret import set_pallas_interpret
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    args = [_struct((1, 8192, heads, 128), jnp.bfloat16) for heads in (4, 1, 1)]
+    interpret = set_pallas_interpret(False)
+    try:
+        with jax.set_mesh(mesh):
+            text = _compile(attention.causal_attention, args, NamedSharding(mesh, P()), grad=True)
+    finally:
+        set_pallas_interpret(interpret)
+    assert "dtpu_causal_attn_fwd" in text and "dtpu_causal_attn_bwd" in text
 
 
 # ops/moe_kernel.py is refused by Mosaic at every shape its VMEM guard admits

@@ -115,7 +115,7 @@ def test_latent_core_matches_the_whole_head_form_forward_and_gradient(length, ro
     args = _core_inputs(rows, length)
     if grouped:  # one row's scores of a block and no more: a group is a row
         monkeypatch.setattr(rows_module, "GROUP_BYTES", 4 * 4 * min(8, length) * length)
-    core = jax.jit(lambda *a: attention.latent_causal_attention(*a, block=8))
+    core = jax.jit(lambda q, k_own, k_shared, v: attention.xla_causal_core(q, k_own, v, k_shared, block=8).reshape(rows, length, -1))
     assert ("while" in core.lower(*args).as_text()) is (grouped and rows > 1)
     got, want = core(*args), _whole_head(*args)
     assert got.shape == (rows, length, 4 * 6)  # heads x the value's width, not the key's
@@ -148,7 +148,7 @@ def test_packed_causal_attention_is_the_latent_core_with_equal_widths_and_no_sha
 
     q, k, v = (jax.random.normal(jax.random.key(i), (2, 12, 4, 8)) for i in range(3))
     packed = attention.xla_causal_attention(jnp.concatenate([t.reshape(2, 12, 32) for t in (q, k, v)], axis=-1), 4, 4, block=8)
-    latent = attention.latent_causal_attention(q, k, jnp.zeros((2, 12, 0)), v, block=8)
+    latent = attention.xla_causal_core(q, k, v, jnp.zeros((2, 12, 0)), block=8).reshape(2, 12, -1)
     np.testing.assert_allclose(latent, packed, rtol=1e-6, atol=1e-7)
 
 
@@ -356,7 +356,9 @@ def test_gradient_under_the_policy_routes_once_and_runs_the_core_once_beside_its
     """A name kept is named once, on the way forward: the backward pass reads the stored value, where a value not
     kept would be named again in the layer's recomputation. So the router's product and `top_k` stand once in the
     gradient, and the core's masked softmax twice (forward, and each block's own rematerialisation) where a layer
-    that did not keep the core's output would hold it three times."""
+    that did not keep the core's output would hold it three times. XLA's blocks name no log-sum-exp."""
+    from distribuuuu_tpu.ops.attention import CAUSAL_LSE
+
     m = dv3()
     fresh_cfg.LM.LOSS_BLOCK = 16
     tokens = tokens_of(5, SHARE["vocab"])
@@ -364,11 +366,35 @@ def test_gradient_under_the_policy_routes_once_and_runs_the_core_once_beside_its
     params, stats = seeded(dict(SHARE, pattern="E"))
     loss = lambda p: trainer._forward_loss_lm(model, p, to_program(stats, "E"), {"tokens": tokens})[0]
     eqns = list(_equations(jax.make_jaxpr(jax.grad(loss))(to_program(params, "E")).jaxpr))
-    assert sorted(e.params["name"] for e in eqns if e.primitive.name == "name") == sorted(m.KEPT)  # each once
+    assert sorted(e.params["name"] for e in eqns if e.primitive.name == "name") == sorted(set(m.KEPT) - {CAUSAL_LSE})
     assert sum(e.primitive.name == "top_k" for e in eqns) == 1
     assert sum(e.primitive.name == "dot_general" and "HIGHEST" in str(e.params["precision"]) for e in eqns) == 3
     # the core's softmax is the only reduction over keys to a maximum: one a block (one block at this length), twice
     assert sum(e.primitive.name == "reduce_max" and e.invars[0].aval.ndim == 5 for e in eqns) == 2
+
+
+@pytest.mark.parametrize("keeps_lse", [True, False], ids=["kept", "lse_recomputed"])
+def test_on_the_kernels_route_the_forward_kernel_runs_once_a_layer_under_the_policy(fresh_cfg, monkeypatch, keeps_lse):
+    """The cell's pattern (a leading dense layer, then the expert layers as one scanned unit) at toy widths and 128
+    tokens, the route forced to the kernel pair inside a mesh: `KEPT` holds the core's output and log-sum-exp,
+    which are the residuals the backward kernel reads, so the gradient's program holds the forward kernel once a
+    layer (the leading one and the unit's body) and never in a recomputation; without the log-sum-exp kept the
+    layers' recomputations run it again."""
+    from distribuuuu_tpu.ops import attention, causal_attention
+
+    m = dv3()
+    monkeypatch.setattr(causal_attention, "fits", lambda *a: True)
+    monkeypatch.setattr(m.DeepseekV3, "kept", m.KEPT if keeps_lse else tuple(set(m.KEPT) - {attention.CAUSAL_LSE}))
+    fresh_cfg.LM.LOSS_BLOCK = 64
+    model = model_of("DEEEE", SHARE, remat=True)
+    params, stats = seeded(dict(SHARE, pattern="DEEEE"))
+    tokens = tokens_of(5, SHARE["vocab"], rows=1, length=128)
+    loss = lambda p: trainer._forward_loss_lm(model, p, to_program(stats, "DEEEE"), {"tokens": tokens})[0]
+    with jax.set_mesh(data_mesh(1)):
+        eqns = list(_equations(jax.make_jaxpr(jax.grad(loss))(to_program(params, "DEEEE")).jaxpr))
+    kernels = [e.params["name"] for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernels.count("dtpu_causal_attn_bwd") == 2
+    assert kernels.count("dtpu_causal_attn_fwd") == (2 if keeps_lse else 4)
 
 
 def _adafactor(params, grads, state, lr, min_dim):
