@@ -59,7 +59,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from distribuuuu_tpu.models import token_lm
 from distribuuuu_tpu.models.registry import register_model
-from distribuuuu_tpu.models.token_lm import mm, rms_norm
+from distribuuuu_tpu.models.token_lm import mixer_proj, mm, rms_norm
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import CAUSAL_LSE, CAUSAL_OUT, latent_causal_attention, partial_rotary
 from distribuuuu_tpu.parallel.moe import ROUTE_IDX, held_experts, round_rows_for, sigmoid_topk_route, silu_gated
@@ -138,19 +138,20 @@ def latent_attention_mixer(p: dict, u, s: Sizes):
     b, l, _ = u.shape
     h, dn, dr, dv = s.attn_heads, s.qk_nope_dim, s.qk_rope_dim, s.v_head_dim
     rotary = lambda t: partial_rotary(t, dr, s.rope_theta)  # float32 in, every dimension given turns
-    q_n, q_r = jnp.split(mm(u, p["q"]).reshape(b, l, h, dn + dr), (dn,), axis=-1)
+    q_n, q_r = jnp.split(mixer_proj(u, p["q"]).reshape(b, l, h, dn + dr), (dn,), axis=-1)
     q = jnp.concatenate([q_n, rotary(q_r)], axis=-1).astype(u.dtype)
-    latent, k_r = jnp.split(mm(u, p["kv_a"]), (s.kv_latent,), axis=-1)
+    latent, k_r = jnp.split(mixer_proj(u, p["kv_a"]), (s.kv_latent,), axis=-1)
     k_r = rotary(k_r[:, :, None, :])[:, :, 0].astype(u.dtype)  # one head, which every head reads
     latent = rms_norm(latent, p["kv_norm"], s.eps).astype(u.dtype)
-    k_n, v = jnp.split(mm(latent, p["kv_b"]).astype(u.dtype).reshape(b, l, h, dn + dv), (dn,), axis=-1)
+    k_n, v = jnp.split(mixer_proj(latent, p["kv_b"]).astype(u.dtype).reshape(b, l, h, dn + dv), (dn,), axis=-1)
     with step_scope("latent_attn"):
         out = latent_causal_attention(q, k_n, k_r, v)
-    return mm(out, p["o"])
+    return mixer_proj(out, p["o"])
 
 
 def dense_feed_forward(p: dict, u):
-    return mm(silu_gated(mm(u, p["ff1"])).astype(u.dtype), p["ff2"])
+    with step_scope("dense_ffn"):
+        return mm(silu_gated(mm(u, p["ff1"])).astype(u.dtype), p["ff2"])
 
 
 def expert_block(p: dict, b_corr, u32, s: Sizes, dtype):
@@ -165,7 +166,8 @@ def expert_block(p: dict, b_corr, u32, s: Sizes, dtype):
     rows = round_rows_for(b * l, s.top_k, s.experts, s.experts_held)
     # between an expert's two products stands `silu(gate) ⊙ up`, as in the shared experts below
     mixed, counts = held_experts(u, idx, weights, p["w1"], p["w2"], s.expert_first, rows, between=silu_gated)
-    shared = mm(silu_gated(mm(u, p["shared1"])).astype(dtype), p["shared2"])
+    with step_scope("dense_ffn"):
+        shared = mm(silu_gated(mm(u, p["shared1"])).astype(dtype), p["shared2"])
     return (mixed + shared).reshape(b, l, dim), counts
 
 

@@ -55,7 +55,7 @@ from jax.ad_checkpoint import checkpoint_name
 from distribuuuu_tpu.models import token_lm
 from distribuuuu_tpu.models.registry import register_model
 from distribuuuu_tpu.models.token_lm import mm as _mm
-from distribuuuu_tpu.models.token_lm import rms_norm
+from distribuuuu_tpu.models.token_lm import mixer_proj, rms_norm
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import self_attention
 from distribuuuu_tpu.ops.ssm import ssd_scan
@@ -162,7 +162,7 @@ def _initializer(name: str, s: Sizes):
 def mamba_mixer(p: dict, u, s: Sizes):
     b, l, _ = u.shape
     inner, bc = s.mamba_heads * s.mamba_head_dim, s.mamba_groups * s.ssm_state
-    projected = checkpoint_name(_mm(u, p["in_proj"]), "mamba_in_proj")
+    projected = checkpoint_name(mixer_proj(u, p["in_proj"]), "mamba_in_proj")
     z, xbc, dt = jnp.split(projected, (inner, 2 * inner + 2 * bc), axis=-1)
     # causal depthwise convolution over time, then silu
     padded = jnp.pad(xbc, ((0, 0), (s.conv_kernel - 1, 0), (0, 0)))
@@ -174,12 +174,12 @@ def mamba_mixer(p: dict, u, s: Sizes):
         p["d"], s.chunk,
     ).reshape(b, l, inner)
     y = rms_norm(y.astype(F32) * jax.nn.silu(z), p["gnorm"], s.eps, groups=s.mamba_groups)
-    return _mm(y.astype(u.dtype), p["out_proj"])
+    return mixer_proj(y.astype(u.dtype), p["out_proj"])
 
 
 def attention_mixer(p: dict, u, s: Sizes):
-    qkv = jnp.concatenate([_mm(u, p[name]).astype(u.dtype) for name in "qkv"], axis=-1)
-    return _mm(self_attention(qkv, s.attn_heads, kv_heads=s.kv_heads, causal=True), p["o"])
+    qkv = jnp.concatenate([mixer_proj(u, p[name]).astype(u.dtype) for name in "qkv"], axis=-1)
+    return mixer_proj(self_attention(qkv, s.attn_heads, kv_heads=s.kv_heads, causal=True), p["o"])
 
 
 def moe_mixer(p: dict, b_corr, u32, s: Sizes, dtype):
@@ -191,13 +191,14 @@ def moe_mixer(p: dict, b_corr, u32, s: Sizes, dtype):
     with step_scope("moe_route"):
         logits = checkpoint_name(jnp.dot(u32, p["router"], precision=lax.Precision.HIGHEST), "moe_router_logits")
         idx, weights = sigmoid_topk_route(logits, s.top_k, b_corr, s.routed_scale)  # names its `idx` itself
-    latent = checkpoint_name(_mm(u, p["down"]).astype(dtype), "moe_latent")
+    latent = checkpoint_name(mixer_proj(u, p["down"]).astype(dtype), "moe_latent")
     rows = round_rows_for(b * l, s.top_k, s.experts, s.experts_held)
     # between an expert's two products stands `relu²`, as in the shared expert below
     mixed, counts = held_experts(latent, idx, weights, p["w1"], p["w2"], s.expert_first, rows, between=relu_squared)
-    shared = checkpoint_name(_mm(u, p["shared1"]), "moe_shared1")
-    shared = _mm(jnp.square(jax.nn.relu(shared)).astype(dtype), p["shared2"])
-    return (_mm(mixed.astype(dtype), p["up"]) + shared).reshape(b, l, dim), counts
+    with step_scope("dense_ffn"):
+        shared = checkpoint_name(_mm(u, p["shared1"]), "moe_shared1")
+        shared = _mm(jnp.square(jax.nn.relu(shared)).astype(dtype), p["shared2"])
+    return (mixer_proj(mixed.astype(dtype), p["up"]) + shared).reshape(b, l, dim), counts
 
 
 def layer(kind: str, p: dict, b_corr, h, s: Sizes):

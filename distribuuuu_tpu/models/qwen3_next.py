@@ -58,7 +58,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from distribuuuu_tpu.models import token_lm
 from distribuuuu_tpu.models.registry import register_model
-from distribuuuu_tpu.models.token_lm import mm, rms_norm
+from distribuuuu_tpu.models.token_lm import mixer_proj, mm, rms_norm
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import partial_rotary, self_attention
 from distribuuuu_tpu.ops.gdn import gated_delta_rule
@@ -153,8 +153,8 @@ def delta_mixer(p: dict, u, s: Sizes):
     b, l, _ = u.shape
     hk, hv, dk, dv = s.linear_key_heads, s.linear_value_heads, s.linear_key_dim, s.linear_value_dim
     keys, values = hk * dk, hv * dv
-    qkv, z = jnp.split(mm(u, p["in_qkvz"]).astype(u.dtype), (2 * keys + values,), axis=-1)
-    beta, a = jnp.split(mm(u, p["in_ba"]), 2, axis=-1)                     # float32 [B, L, Hv] each
+    qkv, z = jnp.split(mixer_proj(u, p["in_qkvz"]).astype(u.dtype), (2 * keys + values,), axis=-1)
+    beta, a = jnp.split(mixer_proj(u, p["in_ba"]), 2, axis=-1)             # float32 [B, L, Hv] each
     # causal depthwise convolution over time (float32 sums), then silu
     padded = jnp.pad(qkv, ((0, 0), (s.conv_kernel - 1, 0), (0, 0)))
     qkv = jax.nn.silu(sum(p["conv_w"][j] * padded[:, j:j + l] for j in range(s.conv_kernel)))
@@ -167,20 +167,20 @@ def delta_mixer(p: dict, u, s: Sizes):
     o = gated_delta_rule(q, k, v.astype(u.dtype).reshape(b, l, hv, dv), log_alpha, jax.nn.sigmoid(beta), s.chunk)
     o = checkpoint_name(o, "gdn_out")
     y = rms_norm(o, p["gnorm"], s.eps) * jax.nn.silu(z.reshape(b, l, hv, dv).astype(F32))
-    return mm(y.astype(u.dtype).reshape(b, l, values), p["out"])
+    return mixer_proj(y.astype(u.dtype).reshape(b, l, values), p["out"])
 
 
 def attention_mixer(p: dict, u, s: Sizes):
     b, l, _ = u.shape
     h, g, hd = s.attn_heads, s.kv_heads, s.head_dim
-    query, gate = jnp.split(mm(u, p["q"]).reshape(b, l, h, 2 * hd), 2, axis=-1)
-    k = mm(u, p["k"]).reshape(b, l, g, hd)
+    query, gate = jnp.split(mixer_proj(u, p["q"]).reshape(b, l, h, 2 * hd), 2, axis=-1)
+    k = mixer_proj(u, p["k"]).reshape(b, l, g, hd)
     rotary = lambda t: partial_rotary(t, int(hd * s.rope_share), s.rope_theta)
     query = rotary(centred_norm(query, p["q_norm"], s.eps)).astype(u.dtype)
     k = rotary(centred_norm(k, p["k_norm"], s.eps)).astype(u.dtype)
-    qkv = jnp.concatenate([query.reshape(b, l, h * hd), k.reshape(b, l, g * hd), mm(u, p["v"]).astype(u.dtype)], axis=-1)
+    qkv = jnp.concatenate([query.reshape(b, l, h * hd), k.reshape(b, l, g * hd), mixer_proj(u, p["v"]).astype(u.dtype)], axis=-1)
     out = self_attention(qkv, h, kv_heads=g, causal=True).astype(F32) * jax.nn.sigmoid(gate.reshape(b, l, h * hd))
-    return mm(out.astype(u.dtype), p["o"])
+    return mixer_proj(out.astype(u.dtype), p["o"])
 
 
 def expert_block(p: dict, u32, s: Sizes, dtype):
@@ -195,9 +195,10 @@ def expert_block(p: dict, u32, s: Sizes, dtype):
     rows = round_rows_for(b * l, s.top_k, s.experts, s.experts_held)
     # between an expert's two products stands `silu(gate) ⊙ up`, as in the shared expert below
     mixed, counts = held_experts(u, idx, weights, p["w1"], p["w2"], s.expert_first, rows, between=silu_gated)
-    shared = mm(silu_gated(mm(u, p["shared1"])).astype(dtype), p["shared2"])
-    shared_gate = jax.nn.sigmoid(jnp.sum(u32 * p["shared_gate"], axis=-1, keepdims=True))
-    return (mixed + shared_gate * shared).reshape(b, l, dim), counts
+    with step_scope("dense_ffn"):
+        shared = mm(silu_gated(mm(u, p["shared1"])).astype(dtype), p["shared2"])
+        shared = jax.nn.sigmoid(jnp.sum(u32 * p["shared_gate"], axis=-1, keepdims=True)) * shared
+    return (mixed + shared).reshape(b, l, dim), counts
 
 
 def layer(kind: str, p: dict, b_corr, h, s: Sizes):

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.parallel.moe import BLOCK
 
 F32 = jnp.float32
@@ -53,6 +54,12 @@ def rms_norm(x, scale, eps: float, groups: int = 1):
 def mm(x, kernel):
     """``x @ kernel``, operands in ``x.dtype``, float32 out."""
     return jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=F32)
+
+
+def mixer_proj(x, kernel):
+    """`mm` of a product that carries the stream into or out of a mixer, under the step scope ``dtpu.mixer_proj``."""
+    with step_scope("mixer_proj"):
+        return mm(x, kernel)
 
 
 def repeated_unit(pattern: str) -> tuple[int, int, int]:
@@ -135,8 +142,10 @@ class TokenLM(nn.Module):
         }
 
     def head_logits(self, hidden):
-        """Float32 logits over the held vocabulary slice of final-normed hidden states ``[..., dim]``."""
-        return mm(hidden, self.p["head"])
+        """Float32 logits over the held vocabulary slice of final-normed hidden states ``[..., dim]``, under the
+        step scope ``dtpu.lm_head``."""
+        with step_scope("lm_head"):
+            return mm(hidden, self.p["head"])
 
     def _leaves(self, prefix: str) -> tuple[dict, Any]:
         """The leaves of one prefix by their short names, and its router's buffer (None where it has none)."""

@@ -17,8 +17,8 @@ each boundary where the work happens (docs/OBSERVABILITY.md "Tracing"):
 - `step_scope` names what follows the gradient inside the jitted train step
   (`STEP_SCOPES`) in the executable's own metadata, so a device trace splits
   the step by phase. The model's forward and backward keep the module paths
-  flax gives them; `MODEL_SCOPES` name the parts of a mixer those paths
-  cannot tell apart.
+  flax gives them; `MODEL_SCOPES` name the parts of a layer and of the head
+  those paths cannot tell apart.
 
 Propagation contract (docs/OBSERVABILITY.md "Tracing"):
 
@@ -73,14 +73,24 @@ HOST_PHASES = {
     "fetch_wait": "fetch_wait_s",      # loop: device_get at the window boundary
     "checkpoint": None,                # loop: epoch-end save dispatch
 }
-#: inside a model, what flax's module paths cannot tell apart: the scan
-#: proper of a state-space mixer (ops/ssm.py), the recurrence proper of a
-#: delta-rule linear-attention mixer (ops/gdn.py), the causal core of a
-#: latent-attention mixer (scores, mask, softmax, weighted values; put round
-#: `ops.attention.latent_causal_attention` by models/deepseek_v3.py), and an
-#: expert layer's routing (scores, top-k, weights, sorting tokens to experts)
-#: and grouped products over the experts held (parallel/moe.py)
-MODEL_SCOPES = ("ssm_scan", "gdn_scan", "latent_attn", "moe_route", "moe_experts")
+#: inside a model, what flax's module paths cannot tell apart, one scope a
+#: kind of work and each put where that work is done (docs/OBSERVABILITY.md
+#: "Tracing" has the table of who puts it and which metric reads it):
+#: ``ssm_scan`` the scan proper of a state-space mixer (ops/ssm.py);
+#: ``gdn_scan`` the recurrence proper of a delta-rule mixer (ops/gdn.py);
+#: ``latent_attn`` latent attention's core, put round
+#: `ops.attention.latent_causal_attention` by models/deepseek_v3.py;
+#: ``causal_attn`` every family's causal core, the kernel pair or XLA's blocks
+#: and the layout copies around them (`ops.attention.causal_attention`; inside
+#: ``latent_attn`` there); ``mixer_proj`` the products that carry the stream
+#: into and out of a mixer (`models.token_lm.mixer_proj`); ``dense_ffn`` the
+#: dense feed-forwards: shared experts, a leading dense layer; ``moe_route``
+#: an expert layer's routing (scores, top-k, weights, sorting tokens to
+#: experts) and ``moe_experts`` its grouped products over the experts held
+#: (parallel/moe.py); ``lm_head`` the head's product in the loss's blocks
+#: (`models.token_lm.TokenLM.head_logits`, inside ``loss``)
+MODEL_SCOPES = ("ssm_scan", "gdn_scan", "latent_attn", "causal_attn", "mixer_proj", "dense_ffn",
+                "moe_route", "moe_experts", "lm_head")
 #: what follows the gradient inside the jitted train step, the loss outside
 #: the module (trainer.make_train_step), and `MODEL_SCOPES`
 STEP_SCOPES = ("grad_sync", "optimizer", "guard", "metrics", "loss") + MODEL_SCOPES

@@ -53,6 +53,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops import causal_attention as causal_attention_kernels
 from distribuuuu_tpu.ops.causal_attention import nn, nt, tn
 from distribuuuu_tpu.ops.interpret import pallas_interpret
@@ -821,27 +822,29 @@ def _flash_causal(q, k, v, k_shared, interpret: bool):
 
 
 def _flash_causal_fwd(q, k, v, k_shared, interpret):
-    kv = _heads_first(jnp.concatenate([k, v], axis=-1))  # a group's keys and values side by side: one operand
-    out, lse = causal_attention_kernels.forward(_heads_first(q), kv, k_shared, interpret=interpret)
-    # named here, where they are the residuals themselves: a checkpoint that keeps them keeps what the backward reads
-    out, lse = checkpoint_name(_heads_first(out), CAUSAL_OUT), checkpoint_name(lse, CAUSAL_LSE)
+    with step_scope("causal_attn"):  # the rules take the scope themselves, as ops/grouped.py's do
+        kv = _heads_first(jnp.concatenate([k, v], axis=-1))  # a group's keys and values side by side: one operand
+        out, lse = causal_attention_kernels.forward(_heads_first(q), kv, k_shared, interpret=interpret)
+        # named here, where they are the residuals themselves: a checkpoint that keeps them keeps what the backward reads
+        out, lse = checkpoint_name(_heads_first(out), CAUSAL_OUT), checkpoint_name(lse, CAUSAL_LSE)
     return out, (q, k, v, k_shared, out, lse)
 
 
 def _flash_causal_bwd(interpret, res, d_out):
-    q, k, v, k_shared, out, lse = res
-    b, l, heads, _ = q.shape
-    groups, dk = k.shape[2], k.shape[-1]
-    delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, L, H]
-    stats = jnp.concatenate([lse, jnp.swapaxes(delta, 1, 2)[:, :, None, :]], axis=2)  # [B, H, 2, L]
-    kv = _heads_first(jnp.concatenate([k, v], axis=-1))
-    dq, dkv, dk_r = causal_attention_kernels.backward(_heads_first(q), kv, k_shared, _heads_first(d_out), stats,
-                                                      interpret=interpret)
-    if groups != heads:  # a query head's share of its group's gradient, float32: summed here
-        dkv = dkv.reshape(b, groups, heads // groups, l, dkv.shape[-1]).sum(axis=2)
-    dk_, dv = jnp.split(_heads_first(dkv), (dk,), axis=-1)
-    d_shared = None if dk_r is None else jnp.sum(dk_r, axis=1).astype(k_shared.dtype)
-    return _heads_first(dq), dk_.astype(k.dtype), dv.astype(v.dtype), d_shared
+    with step_scope("causal_attn"):
+        q, k, v, k_shared, out, lse = res
+        b, l, heads, _ = q.shape
+        groups, dk = k.shape[2], k.shape[-1]
+        delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, L, H]
+        stats = jnp.concatenate([lse, jnp.swapaxes(delta, 1, 2)[:, :, None, :]], axis=2)  # [B, H, 2, L]
+        kv = _heads_first(jnp.concatenate([k, v], axis=-1))
+        dq, dkv, dk_r = causal_attention_kernels.backward(_heads_first(q), kv, k_shared, _heads_first(d_out), stats,
+                                                          interpret=interpret)
+        if groups != heads:  # a query head's share of its group's gradient, float32: summed here
+            dkv = dkv.reshape(b, groups, heads // groups, l, dkv.shape[-1]).sum(axis=2)
+        dk_, dv = jnp.split(_heads_first(dkv), (dk,), axis=-1)
+        d_shared = None if dk_r is None else jnp.sum(dk_r, axis=1).astype(k_shared.dtype)
+        return _heads_first(dq), dk_.astype(k.dtype), dv.astype(v.dtype), d_shared
 
 
 _flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
@@ -858,18 +861,20 @@ def causal_attention(q, k, v, k_shared=None):
     (inside the trainer's `shard_map`'d steps; the described chips of a compile-only test count as what they
     describe), else `xla_causal_core`. Traced outside any mesh it is XLA's, uncounted:
     ``model.init``, shape inference, a test's plain call. The output is named `CAUSAL_OUT` on either route,
-    and on the kernels' the rows' log-sum-exp `CAUSAL_LSE`, for a layer checkpoint to keep."""
-    mesh = jax.sharding.get_abstract_mesh()
-    fused = False
-    if not mesh.empty:
-        _, l, heads, width = q.shape
-        dr = 0 if k_shared is None else k_shared.shape[-1]
-        fused = causal_attention_kernels.fits(mesh.abstract_device.device_kind, l, heads, k.shape[2], width - dr, dr,
-                                              v.shape[-1], np.dtype(q.dtype).itemsize)
-        jax.monitoring.record_event(CAUSAL_FUSED_EVENT if fused else CAUSAL_XLA_EVENT)
-    if fused:
-        return _flash_causal(q, k, v, k_shared, pallas_interpret())
-    return checkpoint_name(xla_causal_core(q, k, v, k_shared), CAUSAL_OUT)
+    and on the kernels' the rows' log-sum-exp `CAUSAL_LSE`, for a layer checkpoint to keep. Either route
+    stands under the step scope ``dtpu.causal_attn``, forward, recomputed and backward."""
+    with step_scope("causal_attn"):
+        mesh = jax.sharding.get_abstract_mesh()
+        fused = False
+        if not mesh.empty:
+            _, l, heads, width = q.shape
+            dr = 0 if k_shared is None else k_shared.shape[-1]
+            fused = causal_attention_kernels.fits(mesh.abstract_device.device_kind, l, heads, k.shape[2], width - dr, dr,
+                                                  v.shape[-1], np.dtype(q.dtype).itemsize)
+            jax.monitoring.record_event(CAUSAL_FUSED_EVENT if fused else CAUSAL_XLA_EVENT)
+        if fused:
+            return _flash_causal(q, k, v, k_shared, pallas_interpret())
+        return checkpoint_name(xla_causal_core(q, k, v, k_shared), CAUSAL_OUT)
 
 
 def latent_causal_attention(q, k_own, k_shared, v):

@@ -312,13 +312,13 @@ def make_train_step(
         )
         return loss, logits, new_stats, grads
 
-    # The name is the compiled module's (``jit_step_training``) and part of
-    # the persistent compile cache's key, which a scope alone is not (debug
-    # info is stripped before hashing, so a step that differs from a cached
-    # one in its scopes alone loads the older build's metadata: whoever moves
+    # The name is the compiled module's (``jit_step_scoped``) and part of the
+    # persistent compile cache's key, which a scope alone is not (debug info
+    # is stripped before hashing, so a step that differs from a cached one in
+    # its scopes alone loads the older build's metadata: whoever moves or adds
     # a scope renames the step). benchmark/xplane finds the train step by the
     # ``jit_step`` prefix, and tests/test_trace_phases.py pins it.
-    def step_training(state: TrainState, batch, lr, rng):
+    def step_scoped(state: TrainState, batch, lr, rng):
         # distinct dropout stream per device (rng arrives replicated); on a
         # 2-D mesh the fold uses the linearized device index so a (d, f) mesh
         # reproduces the stream of a (d·f,)-device data-parallel mesh
@@ -464,7 +464,7 @@ def make_train_step(
 
     state_in_specs = state_specs if use_fsdp else P()
     sharded = jax.shard_map(
-        step_training,
+        step_scoped,
         mesh=mesh,
         in_specs=(state_in_specs, P(fsdp.batch_axes(mesh)), P(), P()),
         out_specs=(state_in_specs, P()),

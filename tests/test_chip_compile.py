@@ -385,7 +385,8 @@ def test_qwen3_next_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(to
     text = compiled.as_text()
     assert "dtpu_moe_gmm" in text and "dtpu_moe_tgmm" in text
     assert "dtpu_causal_attn_fwd" in text and "dtpu_causal_attn_bwd" in text  # the gated attention's core
-    for scope in ("dtpu.gdn_scan", "dtpu.moe_route", "dtpu.moe_experts", "dtpu.optimizer", "dtpu.loss"):
+    for scope in ("dtpu.gdn_scan", "dtpu.causal_attn", "dtpu.mixer_proj", "dtpu.dense_ffn", "dtpu.moe_route",
+                  "dtpu.moe_experts", "dtpu.lm_head", "dtpu.optimizer", "dtpu.loss"):
         assert scope in text, scope
 
 
@@ -438,12 +439,13 @@ def test_kanana2_30b_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(t
     assert peak + weights <= 15.75 * 2**30, f"{peak / 2**30:.2f} GiB beside a second copy of {weights / 2**30:.2f}"
     text = compiled.as_text()
     assert "dtpu_moe_gmm" in text and "dtpu_moe_tgmm" in text
-    for scope in ("dtpu.latent_attn", "dtpu.moe_route", "dtpu.moe_experts", "dtpu.optimizer", "dtpu.loss"):
+    for scope in ("dtpu.latent_attn", "dtpu.causal_attn", "dtpu.mixer_proj", "dtpu.dense_ffn", "dtpu.moe_route",
+                  "dtpu.moe_experts", "dtpu.lm_head", "dtpu.optimizer", "dtpu.loss"):
         assert scope in text, scope
     # the core's products stand under L0 (the leading layer, unrolled) and U0 (the unit, once) and under no other layer
     layers_of_the_core = set(re.findall(r"/(L\d+|U\d+)/dtpu\.latent_attn/", text))
     assert layers_of_the_core == {"L0", "U0"}
-    calls = [re.search(r'op_name="[^"]*/dtpu\.latent_attn/(dtpu_causal_attn_\w+)/pallas_call"', line)
+    calls = [re.search(r'op_name="[^"]*/dtpu\.latent_attn/(?:dtpu\.causal_attn/)+(dtpu_causal_attn_\w+)/pallas_call"', line)
              for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and "dtpu_causal" in line]
     assert sorted(c.group(1) for c in calls) == ["dtpu_causal_attn_bwd"] * 2 + ["dtpu_causal_attn_fwd"] * 2
     # five operands at the most: the benchmark's reader of kernel calls takes no list XLA marks /*index=5*/

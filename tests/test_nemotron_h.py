@@ -524,18 +524,32 @@ def _lm_run(cfg, pattern: str = "EM*", sizes: dict = SHARE, remat: bool = True):
     return mesh, state, trainer.make_train_step(model, tx, mesh, topk=5)
 
 
-def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compile_cache):
-    mesh, state, step = _lm_run(fresh_cfg)
-    batch = {"tokens": tokens_of(0, SHARE["vocab"])}
-    text = step.lower(state, batch, jnp.float32(0.1), jax.random.key(1)).compile().as_text()
-    names = re.findall(r'op_name="([^"]*)"', text)
-    scopes = ("ssm_scan", "moe_route", "moe_experts")  # this family's of `MODEL_SCOPES` (the delta rule's: test_qwen3_next.py)
-    assert set(scopes) <= set(obs_trace.MODEL_SCOPES)
-    for scope in scopes:
-        under = [n for n in names if f"/dtpu.{scope}/" in n]
-        assert any("transpose(" in n for n in under), f"no backward op under dtpu.{scope}"
-        assert any("transpose(" not in n for n in under), f"no forward op under dtpu.{scope}"
+_COMPILED_NAMES: list = []  # the compiled step's op_names, once for the file's cases
+
+
+def _compiled_names(cfg) -> list[str]:
+    if not _COMPILED_NAMES:
+        mesh, state, step = _lm_run(cfg)
+        batch = {"tokens": tokens_of(0, SHARE["vocab"])}
+        text = step.lower(state, batch, jnp.float32(0.1), jax.random.key(1)).compile().as_text()
+        _COMPILED_NAMES.extend(re.findall(r'op_name="([^"]*)"', text))
+    return _COMPILED_NAMES
+
+
+# this family's of `MODEL_SCOPES` (the delta rule's: test_qwen3_next.py; latent attention's: test_deepseek_v3.py)
+@pytest.mark.parametrize("scope", ["ssm_scan", "causal_attn", "mixer_proj", "dense_ffn", "moe_route", "moe_experts",
+                                   "lm_head"])
+def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compile_cache, scope):
+    assert scope in obs_trace.MODEL_SCOPES
+    under = [n for n in _compiled_names(fresh_cfg) if f"/dtpu.{scope}/" in n]
+    assert any("transpose(" in n for n in under), f"no backward op under dtpu.{scope}"
+    assert any("transpose(" not in n for n in under), f"no forward op under dtpu.{scope}"
+
+
+def test_compiled_step_names_the_loss_and_the_optimizer(fresh_cfg, no_compile_cache):
+    names = _compiled_names(fresh_cfg)
     assert any("jvp(dtpu.loss)" in n for n in names) and any("dtpu.optimizer" in n for n in names)
+    assert all("jvp(dtpu.loss)" in n for n in names if "/dtpu.lm_head/" in n)  # the head's product inside the loss
 
 
 def test_compiled_step_places_every_kernel_call_under_the_experts_scope(fresh_cfg, no_compile_cache, monkeypatch):

@@ -410,24 +410,31 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compile_cache):
+_COMPILED_NAMES: list = []  # the compiled step's op_names, once for the file's cases
+
+
+def _compiled_names(cfg) -> list[str]:
+    if not _COMPILED_NAMES:
+        cfg.TRAIN.TASK, cfg.OPTIM.OPTIMIZER, cfg.LM.LOSS_BLOCK = "lm", "adafactor", 16
+        model = model_of("GA", SHARE)
+        mesh = data_mesh(1)
+        state, tx = trainer.create_train_state(model, jax.random.key(0), mesh, 0)
+        step = trainer.make_train_step(model, tx, mesh, topk=5)
+        batch = {"tokens": tokens_of(0, SHARE["vocab"])}
+        text = step.lower(state, batch, jnp.float32(0.1), jax.random.key(1)).compile().as_text()
+        _COMPILED_NAMES.extend(re.findall(r'op_name="([^"]*)"', text))
+    return _COMPILED_NAMES
+
+
+@pytest.mark.parametrize("scope", ["gdn_scan", "causal_attn", "mixer_proj", "dense_ffn", "moe_route", "moe_experts",
+                                   "lm_head"])
+def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compile_cache, scope):
     from distribuuuu_tpu.obs import trace as obs_trace
 
-    cfg = fresh_cfg
-    cfg.TRAIN.TASK, cfg.OPTIM.OPTIMIZER, cfg.LM.LOSS_BLOCK = "lm", "adafactor", 16
-    model = model_of("GA", SHARE)
-    mesh = data_mesh(1)
-    state, tx = trainer.create_train_state(model, jax.random.key(0), mesh, 0)
-    step = trainer.make_train_step(model, tx, mesh, topk=5)
-    batch = {"tokens": tokens_of(0, SHARE["vocab"])}
-    text = step.lower(state, batch, jnp.float32(0.1), jax.random.key(1)).compile().as_text()
-    names = re.findall(r'op_name="([^"]*)"', text)
-    scopes = ("gdn_scan", "moe_route", "moe_experts")
-    assert set(scopes) <= set(obs_trace.MODEL_SCOPES)
-    for scope in scopes:
-        under = [n for n in names if f"/dtpu.{scope}/" in n]
-        assert any("transpose(" in n for n in under), f"no backward op under dtpu.{scope}"
-        assert any("transpose(" not in n for n in under), f"no forward op under dtpu.{scope}"
+    assert scope in obs_trace.MODEL_SCOPES
+    under = [n for n in _compiled_names(fresh_cfg) if f"/dtpu.{scope}/" in n]
+    assert any("transpose(" in n for n in under), f"no backward op under dtpu.{scope}"
+    assert any("transpose(" not in n for n in under), f"no forward op under dtpu.{scope}"
 
 
 @pytest.mark.parametrize("arch,module,leaf", [("qwen3_next", "distribuuuu_tpu.models.qwen3_next", "U0_in_qkvz"),
