@@ -58,6 +58,7 @@ from distribuuuu_tpu.models.token_lm import mm as _mm
 from distribuuuu_tpu.models.token_lm import mixer_proj, rms_norm
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import self_attention
+from distribuuuu_tpu.ops.short_conv import causal_conv_silu
 from distribuuuu_tpu.ops.ssm import ssd_scan
 from distribuuuu_tpu.parallel.moe import (
     ROUTE_IDX, held_experts, relu_squared, round_rows_for, sigmoid_topk_route,
@@ -164,10 +165,8 @@ def mamba_mixer(p: dict, u, s: Sizes):
     inner, bc = s.mamba_heads * s.mamba_head_dim, s.mamba_groups * s.ssm_state
     projected = checkpoint_name(mixer_proj(u, p["in_proj"]), "mamba_in_proj")
     z, xbc, dt = jnp.split(projected, (inner, 2 * inner + 2 * bc), axis=-1)
-    # causal depthwise convolution over time, then silu
-    padded = jnp.pad(xbc, ((0, 0), (s.conv_kernel - 1, 0), (0, 0)))
-    xbc = p["conv_b"] + sum(p["conv_w"][j] * padded[:, j:j + l] for j in range(s.conv_kernel))
-    x, bmat, cmat = jnp.split(jax.nn.silu(xbc).astype(u.dtype), (inner, inner + bc), axis=-1)
+    xbc = causal_conv_silu(xbc, p["conv_w"], p["conv_b"], out_dtype=u.dtype)  # depthwise over time, then silu
+    x, bmat, cmat = jnp.split(xbc, (inner, inner + bc), axis=-1)
     y = ssd_scan(
         x.reshape(b, l, s.mamba_heads, s.mamba_head_dim), jax.nn.softplus(dt + p["dt_bias"]), -jnp.exp(p["a_log"]),
         bmat.reshape(b, l, s.mamba_groups, s.ssm_state), cmat.reshape(b, l, s.mamba_groups, s.ssm_state),

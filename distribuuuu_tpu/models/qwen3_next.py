@@ -12,7 +12,7 @@ starting at 0. Token embedding in, final norm and an untied head out; no bias
 anywhere.
 
 - ``G``: ``[q | k | v | z] = u W_qkvz``, ``[b | a] = u W_ba``; a depthwise causal
-  convolution over time and `silu` on ``[q | k | v]``; per value head (a key
+  convolution over time and `silu` on ``[q | k | v]`` (`ops/short_conv.py`); per value head (a key
   head serves ``linear_value_heads / linear_key_heads`` of them) in float32
   ``q <- q/‖q‖ / sqrt(key dim)``, ``k <- k/‖k‖``, ``β = sigmoid(b)``,
   ``log α = −exp(A_log) · softplus(a + dt_bias)``; the gated delta rule
@@ -62,6 +62,7 @@ from distribuuuu_tpu.models.token_lm import mixer_proj, mm, rms_norm
 from distribuuuu_tpu.obs.trace import step_scope
 from distribuuuu_tpu.ops.attention import partial_rotary, self_attention
 from distribuuuu_tpu.ops.gdn import gated_delta_rule
+from distribuuuu_tpu.ops.short_conv import causal_conv_silu
 from distribuuuu_tpu.parallel.moe import ROUTE_IDX, held_experts, round_rows_for, silu_gated, softmax_topk_route
 
 F32 = jnp.float32
@@ -155,9 +156,7 @@ def delta_mixer(p: dict, u, s: Sizes):
     keys, values = hk * dk, hv * dv
     qkv, z = jnp.split(mixer_proj(u, p["in_qkvz"]).astype(u.dtype), (2 * keys + values,), axis=-1)
     beta, a = jnp.split(mixer_proj(u, p["in_ba"]), 2, axis=-1)             # float32 [B, L, Hv] each
-    # causal depthwise convolution over time (float32 sums), then silu
-    padded = jnp.pad(qkv, ((0, 0), (s.conv_kernel - 1, 0), (0, 0)))
-    qkv = jax.nn.silu(sum(p["conv_w"][j] * padded[:, j:j + l] for j in range(s.conv_kernel)))
+    qkv = causal_conv_silu(qkv, p["conv_w"], out_dtype=F32)  # float32: the unit norms below read it
     q, k, v = jnp.split(qkv, (keys, 2 * keys), axis=-1)
     unit = lambda t: t * lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
     q = unit(q.reshape(b, l, hk, dk)) * dk ** -0.5

@@ -88,9 +88,10 @@ HOST_PHASES = {
 #: an expert layer's routing (scores, top-k, weights, sorting tokens to
 #: experts) and ``moe_experts`` its grouped products over the experts held
 #: (parallel/moe.py); ``lm_head`` the head's product in the loss's blocks
-#: (`models.token_lm.TokenLM.head_logits`, inside ``loss``)
+#: (`models.token_lm.TokenLM.head_logits`, inside ``loss``); ``short_conv``
+#: a mixer's short causal convolution and its `silu` (ops/short_conv.py)
 MODEL_SCOPES = ("ssm_scan", "gdn_scan", "latent_attn", "causal_attn", "mixer_proj", "dense_ffn",
-                "moe_route", "moe_experts", "lm_head")
+                "moe_route", "moe_experts", "lm_head", "short_conv")
 #: what follows the gradient inside the jitted train step, the loss outside
 #: the module (trainer.make_train_step), and `MODEL_SCOPES`
 STEP_SCOPES = ("grad_sync", "optimizer", "guard", "metrics", "loss") + MODEL_SCOPES

@@ -342,6 +342,45 @@ def test_chunk_inverse_kernels_compile_for_v5e_at_the_widths_the_choice_admits(o
     assert "dtpu_gdn_inverse_bwd" in _compile(lambda t, d: gdn_inverse.inverse_bwd(t, d), [a, a], one_chip, grad=False)
 
 
+# -- the short convolution of both token families at the cells' widths: the kernel pair inside the described
+# chip's mesh, forward and backward, five operands a call at the most ----------------------------------------
+
+@pytest.mark.parametrize("case", ["qwen3_next", "nemotron3_super"])
+def test_short_conv_pair_compiles_for_v5e_at_the_cells_sizes(topo, case):
+    """qwen3_next's two rows of 8192 positions of 8192 channels (bfloat16 in, float32 out, no bias) and
+    nemotron3_super's one row of 1280 channels (float32 in, bfloat16 out, a bias), through the entry point inside
+    a mesh of the described chip as the trainer's steps trace it: the route takes the pair, forward and backward,
+    counts it, and every call stands under the op's scope."""
+    from distribuuuu_tpu.obs.monitors import MonitoringBridge
+    from distribuuuu_tpu.ops import short_conv
+    from distribuuuu_tpu.ops.interpret import set_pallas_interpret
+
+    (rows, channels, x_dtype, out_dtype), bias = {
+        "qwen3_next": ((2, 8192, jnp.bfloat16, jnp.float32), False),
+        "nemotron3_super": ((1, 1280, jnp.float32, jnp.bfloat16), True)}[case]
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, P()))
+    args = [shape((rows, 8192, channels), x_dtype), shape((4, channels), jnp.float32)] + [shape((channels,), jnp.float32)] * bias
+    loss = lambda *a: jnp.sum(jnp.sin(short_conv.causal_conv_silu(*a, out_dtype=out_dtype).astype(jnp.float32)))
+    fn = jax.shard_map(jax.grad(loss, argnums=tuple(range(len(args)))), mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False)
+    interpret = set_pallas_interpret(False)  # conftest asks for the interpreter; the chip's route does not
+    bridge = MonitoringBridge().install()
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        counters = bridge.snapshot()["counters"]
+    finally:
+        bridge.close()
+        set_pallas_interpret(interpret)
+    assert counters.get(short_conv.KERNEL_CALLS_EVENT, 0) >= 1 and short_conv.XLA_CALLS_EVENT not in counters
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(re.search(r"/(dtpu_short_conv_\w+)/pallas_call", c).group(1) for c in calls) == [
+        "dtpu_short_conv_bwd", "dtpu_short_conv_fwd"]
+    assert all("dtpu.short_conv" in c for c in calls)
+    # five operands at the most: the benchmark's reader of kernel calls takes no list XLA marks /*index=5*/
+    assert not any("/*index=" in re.search(r"custom-call\(([^)]*)\)", c).group(1) for c in calls)
+
+
 def test_qwen3_next_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(topo, one_chip, fresh_cfg):
     """The cell's own step (config/qwen3_next.yaml: two rows of 8192 tokens, 626 M parameters, Adafactor,
     the layer checkpoint) compiled for the described chip: the compiler accepts it, and by its own count the
@@ -385,8 +424,9 @@ def test_qwen3_next_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(to
     text = compiled.as_text()
     assert "dtpu_moe_gmm" in text and "dtpu_moe_tgmm" in text
     assert "dtpu_causal_attn_fwd" in text and "dtpu_causal_attn_bwd" in text  # the gated attention's core
+    assert "dtpu_short_conv_fwd" in text and "dtpu_short_conv_bwd" in text  # the delta-net mixers' convolution
     for scope in ("dtpu.gdn_scan", "dtpu.causal_attn", "dtpu.mixer_proj", "dtpu.dense_ffn", "dtpu.moe_route",
-                  "dtpu.moe_experts", "dtpu.lm_head", "dtpu.optimizer", "dtpu.loss"):
+                  "dtpu.moe_experts", "dtpu.lm_head", "dtpu.short_conv", "dtpu.optimizer", "dtpu.loss"):
         assert scope in text, scope
 
 

@@ -538,7 +538,7 @@ def _compiled_names(cfg) -> list[str]:
 
 # this family's of `MODEL_SCOPES` (the delta rule's: test_qwen3_next.py; latent attention's: test_deepseek_v3.py)
 @pytest.mark.parametrize("scope", ["ssm_scan", "causal_attn", "mixer_proj", "dense_ffn", "moe_route", "moe_experts",
-                                   "lm_head"])
+                                   "lm_head", "short_conv"])
 def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compile_cache, scope):
     assert scope in obs_trace.MODEL_SCOPES
     under = [n for n in _compiled_names(fresh_cfg) if f"/dtpu.{scope}/" in n]

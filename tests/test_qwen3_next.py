@@ -427,7 +427,7 @@ def _compiled_names(cfg) -> list[str]:
 
 
 @pytest.mark.parametrize("scope", ["gdn_scan", "causal_attn", "mixer_proj", "dense_ffn", "moe_route", "moe_experts",
-                                   "lm_head"])
+                                   "lm_head", "short_conv"])
 def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compile_cache, scope):
     from distribuuuu_tpu.obs import trace as obs_trace
 
@@ -435,6 +435,23 @@ def test_compiled_step_names_the_model_scopes_in_both_passes(fresh_cfg, no_compi
     under = [n for n in _compiled_names(fresh_cfg) if f"/dtpu.{scope}/" in n]
     assert any("transpose(" in n for n in under), f"no backward op under dtpu.{scope}"
     assert any("transpose(" not in n for n in under), f"no forward op under dtpu.{scope}"
+
+
+def test_lowered_step_holds_the_short_convolution_and_no_padded_copy_of_its_input(fresh_cfg):
+    """The delta-net mixer's convolution is `ops.short_conv.causal_conv_silu` under its scope, and nothing in the
+    step pads a ``[rows, length, q | k | v]`` tensor at its start: the taps read windows of the input itself
+    (a pad that moves a gradient earlier, in the op's backward pass, widens nothing at the start)."""
+    fresh_cfg.TRAIN.TASK, fresh_cfg.OPTIM.OPTIMIZER, fresh_cfg.LM.LOSS_BLOCK = "lm", "adafactor", 16
+    model = model_of("GA", SHARE)
+    mesh = data_mesh(1)
+    state, tx = trainer.create_train_state(model, jax.random.key(0), mesh, 0)
+    step = trainer.make_train_step(model, tx, mesh, topk=5)
+    text = step.lower(state, {"tokens": tokens_of(0, SHARE["vocab"])}, jnp.float32(0.1),
+                      jax.random.key(1)).as_text(debug_info=True)
+    assert "dtpu.short_conv" in text
+    width = 2 * SHARE["linear_key_heads"] * SHARE["linear_key_dim"] + SHARE["linear_value_heads"] * SHARE["linear_value_dim"]
+    starts = re.findall(rf"stablehlo\.pad .*low = \[0, (-?\d+), 0\].*\(tensor<{ROWS}x{LENGTH}x{width}x", text)
+    assert starts and all(int(s) <= 0 for s in starts), starts
 
 
 @pytest.mark.parametrize("arch,module,leaf", [("qwen3_next", "distribuuuu_tpu.models.qwen3_next", "U0_in_qkvz"),
