@@ -492,14 +492,16 @@ def test_kanana2_30b_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(t
     assert not any("/*index=" in re.search(r"custom-call\(([^)]*)\)", c.string).group(1) for c in calls)
 
 
-# -- the fourth token model at the widths of config/ling3_flash.yaml: the Kimi delta rule's chunk inverse is the
-# delta rule's kernel pair inside a mesh of TPUs; the cell's whole step fits the chip at one row ---------------------
+# -- the fourth token model at the widths of config/ling3_flash.yaml: the Kimi delta rule's within-chunk products
+# are `ops/kda_terms.py`'s kernel pair and its chunk inverse the delta rule's inside a mesh of TPUs; the cell's whole
+# step fits the chip at one row ---------------------------------------------------------------------------------
 
 @pytest.mark.parametrize("route", ["xla", "kernels"])
 def test_kimi_delta_rule_compiles_for_v5e_at_the_cells_sizes(topo, one_chip, route):
-    """One row of 8192 positions, 32 heads of 128 by 128, chunks of 64, the gradient: outside any mesh the chunks'
-    inverses are XLA's products, inside the described chip's mesh `ops/gdn_inverse.py`'s pair, counted under
-    ops/gdn.py's events; every call stands under the rule's scope."""
+    """One row of 8192 positions, 32 heads of 128 by 128, chunks of 64, the gradient: outside any mesh the
+    within-chunk products and the chunks' inverses are XLA's, inside the described chip's mesh `ops/kda_terms.py`'s
+    pair beside `ops/gdn_inverse.py`'s, each counted under its own events; every call stands under the rule's
+    scope and takes five operands at the most."""
     from distribuuuu_tpu.obs.monitors import MonitoringBridge
     from distribuuuu_tpu.ops import gdn, kda
     from distribuuuu_tpu.ops.interpret import set_pallas_interpret
@@ -525,12 +527,16 @@ def test_kimi_delta_rule_compiles_for_v5e_at_the_cells_sizes(topo, one_chip, rou
     assert "dtpu.kda_scan" in text
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     if route == "xla":
-        assert not calls and gdn.KERNEL_CALLS_EVENT not in counters  # no mesh: uncounted
+        assert not calls  # no mesh: uncounted
+        assert not {gdn.KERNEL_CALLS_EVENT, kda.KERNEL_CALLS_EVENT, kda.XLA_CALLS_EVENT} & set(counters)
         return
     assert counters.get(gdn.KERNEL_CALLS_EVENT, 0) >= 1 and gdn.XLA_CALLS_EVENT not in counters
+    assert counters.get(kda.KERNEL_CALLS_EVENT, 0) >= 1 and kda.XLA_CALLS_EVENT not in counters
     assert calls and all("dtpu.kda_scan" in line for line in calls)
-    assert {re.search(r"/(dtpu_gdn_inverse\w*)/pallas_call", line).group(1) for line in calls} == {
-        "dtpu_gdn_inverse", "dtpu_gdn_inverse_bwd"}
+    assert {re.search(r"/(dtpu_\w+)/pallas_call", line).group(1) for line in calls} == {
+        "dtpu_gdn_inverse", "dtpu_gdn_inverse_bwd", "dtpu_kda_terms", "dtpu_kda_terms_bwd"}
+    # five operands at the most: the benchmark's reader of kernel calls takes no list XLA marks /*index=5*/
+    assert not any("/*index=" in re.search(r"custom-call\(([^)]*)\)", line).group(1) for line in calls)
 
 
 def test_ling3_flash_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(topo, one_chip, fresh_cfg):
@@ -538,8 +544,8 @@ def test_ling3_flash_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(t
     layer checkpoint) compiled for the described chip: the compiler accepts it, and by its own count the step's
     peak (the state and what it holds beside it) leaves room on the chip's 15.75 GiB for the benchmark's second
     copy of the weights; the five delta-attention layers are one loop's body; the rule's inverse, the short
-    convolution, latent attention's core and the held experts' gated products are their kernel pairs; every model
-    scope stands in it."""
+    convolution, latent attention's core and the held experts' gated products are their kernel pairs, and so are the
+    rule's within-chunk products; every model scope stands in it."""
     from distribuuuu_tpu import optim, trainer
     from distribuuuu_tpu.ops.interpret import set_pallas_interpret
 
@@ -577,8 +583,9 @@ def test_ling3_flash_step_compiles_for_v5e_and_fits_beside_the_benchmarks_copy(t
     assert peak + weights <= 15.75 * 2**30, f"{peak / 2**30:.2f} GiB beside a second copy of {weights / 2**30:.2f}"
     text = compiled.as_text()
     kernels = set(re.findall(r"/(dtpu_\w+)/pallas_call", text))
-    assert kernels == {"dtpu_gdn_inverse", "dtpu_gdn_inverse_bwd", "dtpu_short_conv_fwd", "dtpu_short_conv_bwd",
-                       "dtpu_causal_attn_fwd", "dtpu_causal_attn_bwd", "dtpu_moe_gmm", "dtpu_moe_tgmm"}
+    assert kernels == {"dtpu_gdn_inverse", "dtpu_gdn_inverse_bwd", "dtpu_kda_terms", "dtpu_kda_terms_bwd",
+                       "dtpu_short_conv_fwd", "dtpu_short_conv_bwd", "dtpu_causal_attn_fwd", "dtpu_causal_attn_bwd",
+                       "dtpu_moe_gmm", "dtpu_moe_tgmm"}
     for scope in ("dtpu.kda_scan", "dtpu.short_conv", "dtpu.latent_attn", "dtpu.causal_attn", "dtpu.mixer_proj",
                   "dtpu.dense_ffn", "dtpu.moe_route", "dtpu.moe_experts", "dtpu.lm_head", "dtpu.optimizer", "dtpu.loss"):
         assert scope in text, scope
