@@ -174,20 +174,21 @@ _C.OPTIM.WEIGHT_DECAY = 5e-5
 # Token-sequence model (TRAIN.TASK "lm"): the section reaches the arch's
 # factory key by key in lower case; the factory lives in the module that
 # MODEL.MODULE names and takes the keys its family's ``Sizes`` names
-# (models/nemotron_h.py, models/qwen3_next.py, models/deepseek_v3.py, models/bailing_hybrid.py; a key that two
-# read is here once).
+# (models/nemotron_h.py, models/qwen3_next.py, models/deepseek_v3.py, models/bailing_hybrid.py,
+# models/laguna.py; a key that two read is here once).
 # Widths are the model's; the *_HELD counts, KV/group counts and VOCAB are
 # what this chip holds of a layer shared over chips (all of it by default:
 # the published counts of config/nemotron3_super.yaml's,
-# config/qwen3_next.yaml's, config/kanana2_30b.yaml's and
-# config/ling3_flash.yaml's sources are in those files' comments).
+# config/qwen3_next.yaml's, config/kanana2_30b.yaml's, config/ling3_flash.yaml's
+# and config/laguna_s21.yaml's sources are in those files' comments).
 _C.LM = CN()
 _C.LM.SEQ_LEN = 8192        # tokens a row (the batch ships SEQ_LEN + 1: inputs and labels are one leaf shifted)
 _C.LM.VOCAB = 16384         # rows of embedding and head held; ids are drawn from 0 ... VOCAB - 1
 # one letter a layer. nemotron_h: M Mamba-2, * attention, E latent experts; qwen3_next: G gated delta rule,
 # A gated attention, each followed by its expert block; deepseek_v3: every layer latent attention, then D a dense
 # feed-forward or E an expert block; bailing_hybrid: D Kimi delta attention and a dense feed-forward, K Kimi delta
-# attention and an expert block, A gated latent attention and an expert block
+# attention and an expert block, A gated latent attention and an expert block; laguna: D full attention and a dense
+# feed-forward, W sliding-window attention and F full attention, each followed by its expert block
 _C.LM.PATTERN = "EMEMEMEMEM*"
 _C.LM.LAYERS_TOTAL = 88     # depth of the whole model (scales the residual projections' init)
 _C.LM.DIM = 4096
@@ -226,6 +227,17 @@ _C.LM.N_GROUP = 1              # a sigmoid router's groups of experts (consecuti
 _C.LM.TOPK_GROUP = 1           # ... of which a token chooses in its TOPK_GROUP best: 1 of 1 is no limit
 # what bailing_hybrid reads beside the keys above (the LINEAR_* sizes, CONV_KERNEL, CHUNK, the latent ones)
 _C.LM.KDA_LOWER_BOUND = -5.0   # Kimi delta attention's log-decays lie in (KDA_LOWER_BOUND, 0): its gate's bound
+# what laguna reads beside the keys above (KV_HEADS, HEAD_DIM, ROPE_SHARE and ROPE_THETA of its full layers,
+# DENSE_WIDTH, ROUTED_SCALE, the experts')
+_C.LM.FULL_HEADS = 48          # query heads of a full-attention layer ...
+_C.LM.WINDOW_HEADS = 72        # ... and of a sliding-window one
+_C.LM.WINDOW = 512             # keys a sliding-window row sees, its own among them
+_C.LM.WINDOW_ROPE_THETA = 1.0e4  # the window layers' rotary embedding, on a head's every dimension
+_C.LM.YARN_FACTOR = 128.0      # the full layers' YaRN: positions stretched this many times ...
+_C.LM.YARN_ORIGINAL_POSITIONS = 8192  # ... over the length it was trained at, its ramp between the dimensions at
+_C.LM.YARN_BETA_FAST = 32.0    # which those positions turn BETA_FAST and BETA_SLOW times ...
+_C.LM.YARN_BETA_SLOW = 1.0
+_C.LM.YARN_ATTENTION_FACTOR = 1.4852030263919618  # ... and the rotated dimensions scaled by this
 # tokens a block of the loss: float32 logits exist for one block at a time
 _C.LM.LOSS_BLOCK = 2048
 

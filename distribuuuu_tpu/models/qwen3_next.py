@@ -182,15 +182,16 @@ def attention_mixer(p: dict, u, s: Sizes):
     return mixer_proj(out.astype(u.dtype), p["o"])
 
 
-def expert_block(p: dict, u32, s: Sizes, dtype):
+def expert_block(p: dict, u32, s: Sizes, dtype, routed_scale: float = 1.0):
     """``u32``: the normed stream in float32, which the router reads as it is. Returns the block's output
-    (the held experts' part of the mixture and the gated shared expert) and the held experts' loads."""
+    (the held experts' part of the mixture, its weights times ``routed_scale``, and the gated shared
+    expert) and the held experts' loads."""
     b, l, dim = u32.shape
     u32 = u32.reshape(b * l, dim)
     u = u32.astype(dtype)
     with step_scope("moe_route"):
         logits = checkpoint_name(jnp.dot(u32, p["router"], precision=lax.Precision.HIGHEST), "moe_router_logits")
-        idx, weights = softmax_topk_route(logits, s.top_k)  # names its `idx` itself
+        idx, weights = softmax_topk_route(logits, s.top_k, routed_scale)  # names its `idx` itself
     rows = round_rows_for(b * l, s.top_k, s.experts, s.experts_held)
     # between an expert's two products stands `silu(gate) ⊙ up`, as in the shared expert below
     mixed, counts = held_experts(u, idx, weights, p["w1"], p["w2"], s.expert_first, rows, between=silu_gated)

@@ -1,6 +1,7 @@
 """What the token-sequence models share: a stack of residual layers over token embeddings.
 
-A family (`models/nemotron_h.py`, `models/qwen3_next.py`, `models/deepseek_v3.py`) gives its sizes, the
+A family (`models/nemotron_h.py`, `models/qwen3_next.py`, `models/deepseek_v3.py`, `models/bailing_hybrid.py`,
+`models/laguna.py`) gives its sizes, the
 leaves of one layer of each kind of its pattern string, their initialisers
 and the pure function that runs one layer on its own leaves; `TokenLM` is the
 rest: token embedding in, the layers, a final RMSNorm and an untied head out.

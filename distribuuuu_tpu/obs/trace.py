@@ -90,9 +90,10 @@ HOST_PHASES = {
 #: (parallel/moe.py); ``lm_head`` the head's product in the loss's blocks
 #: (`models.token_lm.TokenLM.head_logits`, inside ``loss``); ``short_conv``
 #: a mixer's short causal convolution and its `silu` (ops/short_conv.py); ``kda_scan`` the recurrence proper of
-#: a Kimi-delta-attention mixer (ops/kda.py)
+#: a Kimi-delta-attention mixer (ops/kda.py); ``window_attn`` a sliding-window
+#: causal core, inside ``causal_attn`` (`ops.attention.causal_attention` with a window)
 MODEL_SCOPES = ("ssm_scan", "gdn_scan", "latent_attn", "causal_attn", "mixer_proj", "dense_ffn",
-                "moe_route", "moe_experts", "lm_head", "short_conv", "kda_scan")
+                "moe_route", "moe_experts", "lm_head", "short_conv", "kda_scan", "window_attn")
 #: what follows the gradient inside the jitted train step, the loss outside
 #: the module (trainer.make_train_step), and `MODEL_SCOPES`
 STEP_SCOPES = ("grad_sync", "optimizer", "guard", "metrics", "loss") + MODEL_SCOPES

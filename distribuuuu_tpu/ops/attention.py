@@ -7,7 +7,10 @@ one entry point of grouped-query heads (`self_attention(causal=True)`, packed) a
 heads (`latent_causal_attention`: keys wider than values, a key part all heads share). It takes the flash
 pair of `ops/causal_attention.py` (`dtpu_causal_attn_fwd`, `dtpu_causal_attn_bwd`: the scores in VMEM,
 the causal half only) where the step is traced for TPUs at shapes the pair tiles, and XLA's blocks
-(`xla_causal_core`) elsewhere; device and shape decide, nothing else.
+(`xla_causal_core`) elsewhere; device and shape decide, nothing else. With a static ``window`` it is
+sliding-window attention: the pair's band (``dtpu_window<window>_attn_fwd`` / ``_bwd``) or XLA's, each
+meeting only the key tiles the band reaches. `partial_rotary` turns a head's first dimensions, at one
+``theta`` or at YaRN's frequencies (`yarn_inv_freq`).
 
 **Bias-free self-attention from packed qkv** (ViT, MAE; bottom of this file):
 `self_attention` is what `models/vit.py` calls. It takes one fused forward
@@ -45,7 +48,9 @@ f32 196×196 ≈ 154 KB.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -742,6 +747,9 @@ CAUSAL_BLOCK = 1024
 #: journal's ``counters`` records carry them (obs/monitors.py)
 CAUSAL_FUSED_EVENT = "causal_attn_fused_calls"
 CAUSAL_XLA_EVENT = "causal_attn_xla_calls"
+#: the same of a windowed call, which counts under these alone
+WINDOW_FUSED_EVENT = "window_attn_fused_calls"
+WINDOW_XLA_EVENT = "window_attn_xla_calls"
 #: what `causal_attention` names for a layer checkpoint, on either route: its output, and on the kernels' route
 #: the rows' log-sum-exp, the one other value their backward reads that is not an input. A model whose
 #: checkpoint keeps both never runs the core's forward a second time (`models/deepseek_v3.KEPT`)
@@ -772,23 +780,49 @@ def _causal_blocks(q, k, v, block: int):
     return jnp.concatenate(out, axis=1)
 
 
-def xla_causal_core(q, k, v, k_shared=None, block: int = CAUSAL_BLOCK):
+def _band_block(q, k, v, start: int, first_key: int, window: int):
+    """One block of queries ``q [B, Q, G, R, hd]`` (rows ``start …``) against the keys and values of rows
+    ``first_key … start + Q - 1``, each row seeing the keys ``row - window < key <= row``."""
+    head_dim = q.shape[-1]
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k, preferred_element_type=jnp.float32)
+    gap = (start + jnp.arange(q.shape[1]))[:, None] - (first_key + jnp.arange(k.shape[1]))[None, :]
+    s = jnp.where((gap >= 0) & (gap < window), s * head_dim**-0.5, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)  # a row always sees itself: no empty row
+    return jnp.einsum("bgrqk,bkgd->bqgrd", w.astype(v.dtype), v)
+
+
+def _band_blocks(q, k, v, block: int, window: int):
+    """`_causal_blocks` of a windowed call: each block of query rows against the keys its band reaches."""
+    one_block = jax.checkpoint(_band_block, static_argnums=(3, 4, 5))
+    out = []
+    for start in range(0, q.shape[1], block):
+        first_key = max(0, start - window + 1)
+        out.append(one_block(q[:, start:start + block], k[:, first_key:start + block], v[:, first_key:start + block],
+                             start, first_key, window))
+    return jnp.concatenate(out, axis=1)
+
+
+def xla_causal_core(q, k, v, k_shared=None, block: int = CAUSAL_BLOCK, window: int | None = None):
     """`causal_attention` in XLA: blocks of `CAUSAL_BLOCK` query rows, each against the keys up to its own
     last row only, so the products above the diagonal are never formed (half of them at large L) and no
     ``L x L`` tensor exists; each block is rematerialised in the backward pass, so what is kept for it is q,
     k, v. A shared key part is copied to every key head, for the rows of one group at a time. The rows go
     through `ops.rows.rows_in_groups` by the last block's float32 scores (32 heads at 8192 keys: 1 GiB a
-    row, so a row at a time there). The route off the chip and the kernels' reference in the tests."""
+    row, so a row at a time there). The route off the chip and the kernels' reference in the tests.
+
+    With a ``window`` (no shared key part), row ``i`` sees the keys ``i - window < j <= i``, and a block
+    of query rows meets only the keys its band reaches."""
     _, l, heads, width = q.shape
     groups = k.shape[2]
-    score_bytes = 4 * heads * min(block, l) * l
+    score_bytes = 4 * heads * min(block, l) * (l if window is None else min(l, block + window - 1))
 
     def of_rows(q, k, v, *shared):
         if shared:
             k = jnp.concatenate([k, jnp.broadcast_to(shared[0][:, :, None, :], (*k.shape[:-1], shared[0].shape[-1]))],
                                 axis=-1)
         rows = q.shape[0]
-        out = _causal_blocks(q.reshape(rows, l, groups, heads // groups, width), k, v, block)
+        q = q.reshape(rows, l, groups, heads // groups, width)
+        out = _causal_blocks(q, k, v, block) if window is None else _band_blocks(q, k, v, block, window)
         return out.reshape(rows, l, heads, v.shape[-1])
 
     operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
@@ -816,22 +850,31 @@ def _heads_first(x):
     return jnp.swapaxes(x, 1, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _flash_causal(q, k, v, k_shared, interpret: bool):
-    return _flash_causal_fwd(q, k, v, k_shared, interpret)[0]
+def _core_scope(window):
+    """The scopes of a core: ``dtpu.causal_attn``, and inside it ``dtpu.window_attn`` for a windowed call."""
+    scopes = contextlib.ExitStack()
+    scopes.enter_context(step_scope("causal_attn"))
+    if window is not None:
+        scopes.enter_context(step_scope("window_attn"))
+    return scopes
 
 
-def _flash_causal_fwd(q, k, v, k_shared, interpret):
-    with step_scope("causal_attn"):  # the rules take the scope themselves, as ops/grouped.py's do
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_causal(q, k, v, k_shared, interpret: bool, window: int | None = None):
+    return _flash_causal_fwd(q, k, v, k_shared, interpret, window)[0]
+
+
+def _flash_causal_fwd(q, k, v, k_shared, interpret, window=None):
+    with _core_scope(window):  # the rules take the scope themselves, as ops/grouped.py's do
         kv = _heads_first(jnp.concatenate([k, v], axis=-1))  # a group's keys and values side by side: one operand
-        out, lse = causal_attention_kernels.forward(_heads_first(q), kv, k_shared, interpret=interpret)
+        out, lse = causal_attention_kernels.forward(_heads_first(q), kv, k_shared, interpret=interpret, window=window)
         # named here, where they are the residuals themselves: a checkpoint that keeps them keeps what the backward reads
         out, lse = checkpoint_name(_heads_first(out), CAUSAL_OUT), checkpoint_name(lse, CAUSAL_LSE)
     return out, (q, k, v, k_shared, out, lse)
 
 
-def _flash_causal_bwd(interpret, res, d_out):
-    with step_scope("causal_attn"):
+def _flash_causal_bwd(interpret, window, res, d_out):
+    with _core_scope(window):
         q, k, v, k_shared, out, lse = res
         b, l, heads, _ = q.shape
         groups, dk = k.shape[2], k.shape[-1]
@@ -839,7 +882,7 @@ def _flash_causal_bwd(interpret, res, d_out):
         stats = jnp.concatenate([lse, jnp.swapaxes(delta, 1, 2)[:, :, None, :]], axis=2)  # [B, H, 2, L]
         kv = _heads_first(jnp.concatenate([k, v], axis=-1))
         dq, dkv, dk_r = causal_attention_kernels.backward(_heads_first(q), kv, k_shared, _heads_first(d_out), stats,
-                                                          interpret=interpret)
+                                                          interpret=interpret, window=window)
         if groups != heads:  # a query head's share of its group's gradient, float32: summed here
             dkv = dkv.reshape(b, groups, heads // groups, l, dkv.shape[-1]).sum(axis=2)
         dk_, dv = jnp.split(_heads_first(dkv), (dk,), axis=-1)
@@ -850,7 +893,7 @@ def _flash_causal_bwd(interpret, res, d_out):
 _flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
 
 
-def causal_attention(q, k, v, k_shared=None):
+def causal_attention(q, k, v, k_shared=None, window: int | None = None):
     """Causal softmax attention, the one entry point of both causal callers: ``q [B, L, H, dk + dr]`` against
     keys ``k [B, L, G, dk]`` (query heads ``g·H/G …`` read key head ``g``) and, where all heads share one,
     a key part ``k_shared [B, L, dr]``; values ``v [B, L, G, dv]`` of a width of their own → ``[B, L, H,
@@ -862,19 +905,26 @@ def causal_attention(q, k, v, k_shared=None):
     describe), else `xla_causal_core`. Traced outside any mesh it is XLA's, uncounted:
     ``model.init``, shape inference, a test's plain call. The output is named `CAUSAL_OUT` on either route,
     and on the kernels' the rows' log-sum-exp `CAUSAL_LSE`, for a layer checkpoint to keep. Either route
-    stands under the step scope ``dtpu.causal_attn``, forward, recomputed and backward."""
-    with step_scope("causal_attn"):
+    stands under the step scope ``dtpu.causal_attn``, forward, recomputed and backward.
+
+    A static ``window`` makes it sliding-window attention: row ``i`` sees the keys ``i - window < j <= i``. It
+    takes the pair's band (``dtpu_window<window>_attn_fwd``, ``dtpu_window<window>_attn_bwd``; no shared key
+    part) where `fits` admits it, else `xla_causal_core`'s band, counts under
+    `WINDOW_FUSED_EVENT` / `WINDOW_XLA_EVENT` alone, and stands under ``dtpu.window_attn`` inside
+    ``dtpu.causal_attn``."""
+    with _core_scope(window):
         mesh = jax.sharding.get_abstract_mesh()
         fused = False
         if not mesh.empty:
             _, l, heads, width = q.shape
             dr = 0 if k_shared is None else k_shared.shape[-1]
             fused = causal_attention_kernels.fits(mesh.abstract_device.device_kind, l, heads, k.shape[2], width - dr, dr,
-                                                  v.shape[-1], np.dtype(q.dtype).itemsize)
-            jax.monitoring.record_event(CAUSAL_FUSED_EVENT if fused else CAUSAL_XLA_EVENT)
+                                                  v.shape[-1], np.dtype(q.dtype).itemsize, window)
+            events = (CAUSAL_FUSED_EVENT, CAUSAL_XLA_EVENT) if window is None else (WINDOW_FUSED_EVENT, WINDOW_XLA_EVENT)
+            jax.monitoring.record_event(events[0] if fused else events[1])
         if fused:
-            return _flash_causal(q, k, v, k_shared, pallas_interpret())
-        return checkpoint_name(xla_causal_core(q, k, v, k_shared), CAUSAL_OUT)
+            return _flash_causal(q, k, v, k_shared, pallas_interpret(), window)
+        return checkpoint_name(xla_causal_core(q, k, v, k_shared, window=window), CAUSAL_OUT)
 
 
 def latent_causal_attention(q, k_own, k_shared, v):
@@ -888,15 +938,40 @@ def latent_causal_attention(q, k_own, k_shared, v):
     return out.reshape(*out.shape[:2], -1)
 
 
-def partial_rotary(x, rotary_dim: int, theta: float, first_position: int = 0):
+def yarn_inv_freq(rotary_dim: int, theta: float, factor: float, original_max_positions: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN's frequencies of the ``rotary_dim / 2`` pairs (Peng et al. 2023, as ``transformers``'
+    ``_compute_yarn_parameters`` has them): pair ``i`` keeps its own ``theta^(-2i/rotary_dim)`` below the
+    correction range, turns ``factor`` times slower above it, and blends the two on a linear ramp between,
+    the range's ends the dimensions at which ``original_max_positions`` positions turn ``beta_fast`` and
+    ``beta_slow`` times, floored and ceiled, then clamped to ``[0, rotary_dim - 1]``. float32."""
+    half = rotary_dim // 2
+
+    def dim_of(turns):
+        return rotary_dim * math.log(original_max_positions / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), rotary_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / ((high - low) or 0.001), 0.0, 1.0)
+    own = 1.0 / theta ** (np.arange(0, rotary_dim, 2, dtype=np.float64) / rotary_dim)
+    return (own / factor * ramp + own * (1.0 - ramp)).astype(np.float32)
+
+
+def partial_rotary(x, rotary_dim: int, theta: float, first_position: int = 0, inv_freq=None,
+                   attention_factor: float | None = None):
     """Rotary position embedding on the first ``rotary_dim`` of each head of ``x [B, L, H, hd]``, the
     rest untouched. The rotated dimensions pair as ``(i, i + rotary_dim/2)`` ("rotate half"), pair
-    ``i`` turning by ``position · theta^(-2i/rotary_dim)``; positions count from ``first_position``.
-    Angles, sines and cosines in float32; returns ``x.dtype``."""
+    ``i`` turning by ``position · theta^(-2i/rotary_dim)``, or by ``position · inv_freq[i]`` where the
+    frequencies are given (`yarn_inv_freq`); positions count from ``first_position``. An
+    ``attention_factor`` scales the sines and cosines, so the rotated dimensions (YaRN's). Angles, sines
+    and cosines in float32; returns ``x.dtype``."""
     length, half = x.shape[1], rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = (first_position + jnp.arange(length, dtype=jnp.float32))[:, None] * inv_freq  # [L, half]
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    if attention_factor is not None:
+        cos, sin = cos * attention_factor, sin * attention_factor
     x1, x2, rest = jnp.split(x.astype(jnp.float32), (half, rotary_dim), axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1).astype(x.dtype)
 

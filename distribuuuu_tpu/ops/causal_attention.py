@@ -32,6 +32,17 @@ diagonal tiles alone. A call has at most five operands: the harness's HLO reader
   side, summed over a group by the caller where groups hold several heads, in float32 then; the
   shared key's gradient a head each in float32, summed by the caller.
 
+A static ``window`` (sliding-window attention: row ``i`` sees keys ``i - window < j <= i``) takes a band
+of its own, ``dtpu_window<window>_attn_fwd`` and ``dtpu_window<window>_attn_bwd`` (`window_names`: the
+benchmark prices a kernel from its name and its operands' shapes, and no shape carries the window), in
+tiles of `window_block_for` rows, no wider than the window. A query block meets `band_tiles` key tiles:
+its diagonal tile and those below it that hold a key one of its rows sees. The grid's last two axes are a
+block and the offset in its band, so a step finds its tiles by an addition alone; the few offsets of the
+first (forward) or last (backward) blocks that fall off the sequence are steps that do nothing and fetch
+the tile they had. The mask is applied on the tiles that the band's edges cross, the diagonal tile and
+the band's lower edge; an online softmax row that has met only masked keys stays empty. No shared key
+part: grouped-query heads alone.
+
 Precision is the configuration's: every product's operands in the compute dtype with float32
 accumulation; scores, softmax statistics, ``dP − δ`` and dS in float32; the weights and dS cast
 to the compute dtype as operands of a product and nowhere else.
@@ -57,14 +68,36 @@ VMEM_LIMIT_BYTES = 64 * 2**20
 WIDEST_SHARED = _LANES
 FWD_NAME = "dtpu_causal_attn_fwd"
 BWD_NAME = "dtpu_causal_attn_bwd"
+#: query (and key) rows a tile of a windowed call: the largest of these that divides the length and is no wider
+#: than the window
+WINDOW_BLOCKS = (512, 256, 128)
 
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+_BAND_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+                                    vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def block_for(length: int) -> int | None:
     """Query rows a tile for a length, or None where no tile divides it."""
     return next((b for b in BLOCKS if length % b == 0), None)
+
+
+def window_block_for(length: int, window: int) -> int | None:
+    """Query rows a tile of a windowed call, or None where no tile of `WINDOW_BLOCKS` divides the length and
+    fits in the window."""
+    return next((b for b in WINDOW_BLOCKS if b <= window and length % b == 0), None)
+
+
+def band_tiles(block: int, window: int) -> int:
+    """Key tiles a query block of ``block`` rows meets: its diagonal tile and the ``ceil((window - 1) / block)``
+    below it, down to the one that holds key ``q0 - window + 1``, the lowest its first row ``q0`` sees."""
+    return 1 + -(-(window - 1) // block)
+
+
+def window_names(window: int) -> tuple[str, str]:
+    """The names of a windowed call's forward and backward kernels."""
+    return f"dtpu_window{window}_attn_fwd", f"dtpu_window{window}_attn_bwd"
 
 
 def _lanes(width: int) -> int:
@@ -82,17 +115,19 @@ def bwd_vmem_bytes(length: int, block: int, dk: int, dr: int, dv: int, itemsize:
     return length * widths * 4 + 2 * (tiles + results) + block * (widths + _lanes(dv)) * 4 + 6 * block * block * 4
 
 
-def fits(device_kind: str, length: int, heads: int, kv_heads: int, dk: int, dr: int, dv: int, itemsize: int) -> bool:
+def fits(device_kind: str, length: int, heads: int, kv_heads: int, dk: int, dr: int, dv: int, itemsize: int,
+         window: int | None = None) -> bool:
     """Whether the pair takes a call: traced for TPUs, the query heads whole groups of the key
     heads, a length some tile of `BLOCKS` divides, the own key and value widths whole lane groups,
     a shared key part of one lane group at the most, and the backward's step inside
-    `VMEM_LIMIT_BYTES`."""
-    block = block_for(length)
+    `VMEM_LIMIT_BYTES`. A windowed call: a tile of `window_block_for`, no shared key part, and its own
+    backward's step inside the limit."""
+    block = block_for(length) if window is None else window_block_for(length, window)
     return (
         device_kind.upper().startswith("TPU")
         and heads % kv_heads == 0
         and block is not None
-        and dk % _LANES == 0 and dv % _LANES == 0 and 0 <= dr <= WIDEST_SHARED
+        and dk % _LANES == 0 and dv % _LANES == 0 and 0 <= dr <= (WIDEST_SHARED if window is None else 0)
         and bwd_vmem_bytes(length, block, dk, dr, dv, itemsize) <= VMEM_LIMIT_BYTES
     )
 
@@ -186,10 +221,13 @@ def _specs(block: int, blocks: int, per: int, by_key: bool):
     return queries, keys, shared, key_rows, stats
 
 
-def forward(q, kv, k_r=None, *, interpret: bool = False):
+def forward(q, kv, k_r=None, *, interpret: bool = False, window: int | None = None):
     """``dtpu_causal_attn_fwd``: ``q [B, H, L, dk + dr]``, keys and values of a group side by side
     ``kv [B, G, L, dk + dv]``, the shared key ``k_r [B, L, dr]`` or None -> the output ``[B, H, L, dv]`` in
-    q's dtype and the rows' log-sum-exp ``[B, H, 1, L]`` float32."""
+    q's dtype and the rows' log-sum-exp ``[B, H, 1, L]`` float32. With a ``window`` (and no shared key), the
+    band's kernel."""
+    if window is not None:
+        return _band_forward(q, kv, window, interpret)
     b, heads, length, dq = q.shape
     groups = kv.shape[1]
     dr = 0 if k_r is None else k_r.shape[-1]
@@ -266,11 +304,14 @@ def _bwd_kernel(q_ref, kv_ref, *refs, dk: int, scale: float, block: int, blocks:
             dkr_ref[...] = dkr_sc[...]
 
 
-def backward(q, kv, k_r, d_out, stats, *, interpret: bool = False):
+def backward(q, kv, k_r, d_out, stats, *, interpret: bool = False, window: int | None = None):
     """``dtpu_causal_attn_bwd``: ``(dq, dkv, dk_r)`` from the forward's operands, the output's gradient
     ``[B, H, L, dv]`` and the rows' statistics ``[B, H, 2, L]`` float32 (the log-sum-exp, then
     ``δ = rowsum(dO∘O)``). ``dkv [B, H, L, dk + dv]`` is a query head's share (float32 where a group holds
-    several heads); ``dk_r [B, H, L, dr]`` float32, a head's share, or None without a shared key."""
+    several heads); ``dk_r [B, H, L, dr]`` float32, a head's share, or None without a shared key. With a
+    ``window`` (and no shared key), the band's kernel."""
+    if window is not None:
+        return (*_band_backward(q, kv, d_out, stats, window, interpret), None)
     b, heads, length, dq = q.shape
     groups = kv.shape[1]
     per = heads // groups
@@ -304,3 +345,154 @@ def backward(q, kv, k_r, d_out, stats, *, interpret: bool = False):
         interpret=interpret,
     )(q, kv, *(() if k_r is None else (k_r,)), d_out, stats)
     return tuple(grads) if k_r is not None else (*grads, None)
+
+
+# ---------------------------------------------------------------------------
+# the band of a windowed call
+# ---------------------------------------------------------------------------
+
+def _band_geometry(length: int, window: int) -> tuple[int, int, int]:
+    block = window_block_for(length, window)
+    return block, length // block, band_tiles(block, window)
+
+
+def _band_mask(shape, offset, window: int, transposed: bool):
+    """A tile's mask, ``0 <= query - key < window``, from the tile's offset ``(query block - key block) · block``
+    (rows are keys where ``transposed``)."""
+    rows, cols = (lax.broadcasted_iota(jnp.int32, shape, d) for d in (0, 1))
+    gap = offset + (cols - rows if transposed else rows - cols)
+    return (gap >= 0) & (gap < window)
+
+
+def _inside(qi, ki, block: int, window: int):
+    """Whether every query of block ``qi`` sees every key of block ``ki``, a block of the sequence below the diagonal:
+    the tile needs no mask."""
+    return (ki >= 0) & (ki < qi) & (ki * block > qi * block + block - 1 - window)
+
+
+def _band_fwd_kernel(q_ref, kv_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, dk: int, scale: float, block: int,
+                     window: int, tiles: int):
+    qi, j = pl.program_id(2), pl.program_id(3)
+    ki = qi - (tiles - 1) + j  # the band's tiles in key order, the diagonal last; below 0 off the sequence
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, -jnp.inf, _F32)
+        l_sc[...] = jnp.zeros(l_sc.shape, _F32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, _F32)
+
+    def step(masked: bool):
+        s = nt(q_ref[:, :dk], kv_ref[:, :dk]) * scale
+        if masked:
+            s = jnp.where(_band_mask(s.shape, (qi - ki) * block, window, False), s, -jnp.inf)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row that has met only masked keys stays empty: exp(-inf - 0) = 0, where exp(-inf + inf) would be nan
+        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new) if masked else m_new
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.exp(m_prev - m_safe)
+        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = alpha * acc_sc[...] + nn(p.astype(kv_ref.dtype), kv_ref[:, dk:])
+        m_sc[...] = m_new
+
+    inside = _inside(qi, ki, block, window)
+    pl.when(inside)(lambda: step(False))
+    pl.when((ki >= 0) & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(j == tiles - 1)  # the diagonal: every row has met its own key
+    def _():
+        o_ref[...] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+        lse = m_sc[...] + jnp.log(l_sc[...])
+        lse_ref[...] = jnp.transpose(jnp.broadcast_to(lse, (lse.shape[0], _LANES)))[:1]
+
+
+def _band_forward(q, kv, window: int, interpret: bool):
+    b, heads, length, dk = q.shape
+    groups = kv.shape[1]
+    per, dv = heads // groups, kv.shape[-1] - dk
+    block, blocks, tiles = _band_geometry(length, window)
+    key_block = lambda qi, j: jnp.maximum(qi - (tiles - 1) + j, 0)
+    kernel = functools.partial(_band_fwd_kernel, dk=dk, scale=dk ** -0.5, block=block, window=window, tiles=tiles)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, heads, blocks, tiles),
+        in_specs=[pl.BlockSpec((None, None, block, dk), lambda bi, h, qi, j: (bi, h, qi, 0)),
+                  pl.BlockSpec((None, None, block, dk + dv), lambda bi, h, qi, j: (bi, h // per, key_block(qi, j), 0))],
+        out_specs=[pl.BlockSpec((None, None, block, dv), lambda bi, h, qi, j: (bi, h, qi, 0)),
+                   pl.BlockSpec((None, None, 1, block), lambda bi, h, qi, j: (bi, h, 0, qi))],
+        out_shape=[jax.ShapeDtypeStruct((b, heads, length, dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, heads, 1, length), _F32)],
+        scratch_shapes=[pltpu.VMEM((block, 1), _F32), pltpu.VMEM((block, 1), _F32), pltpu.VMEM((block, dv), _F32)],
+        compiler_params=_BAND_PARAMS,
+        name=window_names(window)[0],
+        interpret=interpret,
+    )(q, kv)
+
+
+def _band_bwd_kernel(q_ref, kv_ref, do_ref, stats_ref, dq_ref, dkv_ref, dq_sc, dk_sc, dv_sc, *, dk: int, scale: float,
+                     block: int, blocks: int, window: int, tiles: int):
+    ki, j = pl.program_id(2), pl.program_id(3)
+    qi = ki + j  # the band's query blocks of key block ki, the diagonal first; past the last off the sequence
+    live = qi < blocks
+    rows = pl.ds(pl.multiple_of(jnp.minimum(qi, blocks - 1) * block, block), block)
+
+    @pl.when(j == 0)
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, _F32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, _F32)
+
+    @pl.when(live & ((ki == 0) | (ki * block <= qi * block - window + 1)))  # the lowest key block query block qi meets
+    def _():
+        dq_sc[rows] = jnp.zeros((block, dq_sc.shape[1]), _F32)
+
+    def step(masked: bool):
+        q, k, do = q_ref[...], kv_ref[:, :dk], do_ref[...]
+        s = nt(k, q) * scale  # [keys, queries]
+        if masked:
+            s = jnp.where(_band_mask(s.shape, (qi - ki) * block, window, True), s, -jnp.inf)
+        p = jnp.exp(s - stats_ref[0:1, :])
+        dp = nt(kv_ref[:, dk:], do)
+        ds = (p * (dp - stats_ref[1:2, :]) * scale).astype(q.dtype)
+        dv_sc[...] += nn(p.astype(do.dtype), do)
+        dk_sc[...] += nn(ds, q)
+        dq_sc[rows] += tn(ds, k)
+
+    inside = _inside(qi, ki, block, window)
+    pl.when(live & inside)(lambda: step(False))
+    pl.when(live & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(j == 0)  # the diagonal, the last key block query block ki meets: its dQ is whole
+    def _():
+        dq_ref[...] = dq_sc[rows].astype(dq_ref.dtype)
+
+    @pl.when(j == tiles - 1)
+    def _():
+        dkv_ref[:, :dk] = dk_sc[...].astype(dkv_ref.dtype)
+        dkv_ref[:, dk:] = dv_sc[...].astype(dkv_ref.dtype)
+
+
+def _band_backward(q, kv, d_out, stats, window: int, interpret: bool):
+    b, heads, length, dk = q.shape
+    groups = kv.shape[1]
+    per, dv = heads // groups, kv.shape[-1] - dk
+    block, blocks, tiles = _band_geometry(length, window)
+    query_block = lambda ki, j: jnp.minimum(ki + j, blocks - 1)
+    queries = lambda width: pl.BlockSpec((None, None, block, width), lambda bi, h, ki, j: (bi, h, query_block(ki, j), 0))
+    key_rows = lambda width: pl.BlockSpec((None, None, block, width), lambda bi, h, ki, j: (bi, h, ki, 0))
+    kernel = functools.partial(_band_bwd_kernel, dk=dk, scale=dk ** -0.5, block=block, blocks=blocks, window=window,
+                               tiles=tiles)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, heads, blocks, tiles),
+        in_specs=[queries(dk),
+                  pl.BlockSpec((None, None, block, dk + dv), lambda bi, h, ki, j: (bi, h // per, ki, 0)),
+                  queries(dv),
+                  pl.BlockSpec((None, None, 2, block), lambda bi, h, ki, j: (bi, h, 0, query_block(ki, j)))],
+        out_specs=[key_rows(dk), key_rows(dk + dv)],
+        out_shape=[jax.ShapeDtypeStruct((b, heads, length, dk), q.dtype),
+                   jax.ShapeDtypeStruct((b, heads, length, dk + dv), q.dtype if per == 1 else _F32)],
+        scratch_shapes=[pltpu.VMEM((length, dk), _F32), pltpu.VMEM((block, dk), _F32), pltpu.VMEM((block, dv), _F32)],
+        compiler_params=_BAND_PARAMS,
+        name=window_names(window)[1],
+        interpret=interpret,
+    )(q, kv, d_out, stats)

@@ -247,20 +247,22 @@ def sigmoid_topk_route(logits, k: int, bias, scale: float, n_group: int = 1, top
     return idx, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
 
 
-def softmax_topk_route(logits, k: int):
+def softmax_topk_route(logits, k: int, scale: float = 1.0):
     """Top-``k`` of ``E`` by softmax probability, float32 throughout.
 
     ``logits [T, E]``; ``p = softmax(logits)`` over all ``E``, the choice its
     ``k`` largest (the lower id wins a tie, as `lax.top_k` orders them), the
-    mixture weights the chosen probabilities normalised over the choice:
-    ``w_i = p_i / Σ_choice p_j``. Returns ``(idx [T, k] int32, w [T, k])``;
-    the ids go under `ROUTE_IDX`, as `sigmoid_topk_route` names them.
+    mixture weights the chosen probabilities normalised over the choice and
+    times the routed ``scale``: ``w_i = scale · p_i / Σ_choice p_j``. Returns
+    ``(idx [T, k] int32, w [T, k])``; the ids go under `ROUTE_IDX`, as
+    `sigmoid_topk_route` names them.
     """
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     _, idx = lax.top_k(probs, k)
     idx = checkpoint_name(idx, ROUTE_IDX)  # before its first use: see `sigmoid_topk_route`
     chosen = _chosen(probs, idx)
-    return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx, weights if scale == 1.0 else weights * scale
 
 
 def round_rows_for(tokens: int, k: int, experts: int, held: int, room: float = 1.5) -> int:

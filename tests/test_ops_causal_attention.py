@@ -4,6 +4,9 @@ point takes.
 
 Interpret mode, at a length of 256 in tiles of 128 rows: three tiles on and below the diagonal a head, one
 of them off it. What the chip's compiler makes of the pair is `tests/test_chip_compile.py`'s.
+
+A windowed call (sliding-window attention, the pair's band and XLA's) against a dense masked softmax: lengths
+that cross the window and the tile, one under the window, and a window that is no multiple of the tile.
 """
 
 from __future__ import annotations
@@ -128,3 +131,75 @@ def test_entry_counts_the_route_it_takes_inside_a_mesh_and_nothing_outside(fused
     assert counters.get(attention.CAUSAL_FUSED_EVENT if fused else attention.CAUSAL_XLA_EVENT) == 1
     assert (attention.CAUSAL_XLA_EVENT if fused else attention.CAUSAL_FUSED_EVENT) not in counters
     assert ("dtpu_causal_attn_fwd" in traced[0]) is fused
+
+
+# (length, window, tile): a window no multiple of the tile that the rows cross; rows under the window; a window
+# of two tiles
+WINDOWS = {"cross": (64, 24, 16), "under": (48, 64, 16), "two_tiles": (64, 32, 16)}
+
+
+def _dense_window(q, k, v, window):
+    """Softmax attention over keys ``i - window < j <= i``, dense with a mask, float32 at ``highest``."""
+    heads, groups = q.shape[2], k.shape[2]
+    k, v = (jnp.repeat(t.astype(jnp.float32), heads // groups, axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k, precision="highest") * q.shape[-1] ** -0.5
+    gap = jnp.arange(q.shape[1])[:, None] - jnp.arange(q.shape[1])[None, :]
+    s = jnp.where((gap >= 0) & (gap < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, precision="highest")
+
+
+@pytest.mark.parametrize("case,dtype", [(case, jnp.float32) for case in WINDOWS] + [("cross", jnp.bfloat16)],
+                         ids=[f"{case}-f32" for case in WINDOWS] + ["cross-bf16"])
+def test_window_band_matches_a_dense_masked_softmax(case, dtype, monkeypatch):
+    """Output and every gradient of the pair's band (interpret mode) and of XLA's band, each against the dense
+    masked softmax in float32; a grouped key head's gradient is its query heads' sum."""
+    length, window, tile = WINDOWS[case]
+    monkeypatch.setattr(causal_attention, "WINDOW_BLOCKS", (tile,))
+    (q, k, v, _), weight = _inputs(2, 1, 16, 0, 16, dtype, length=length)
+    cores = {"kernels": lambda q, k, v: attention._flash_causal(q, k, v, None, True, window),
+             "xla": lambda q, k, v: attention.xla_causal_core(q, k, v, block=tile, window=window),
+             "dense": lambda q, k, v: _dense_window(q, k, v, window).astype(q.dtype)}
+    run = jax.jit(lambda args, weight: {name: _out_and_grads(core, args, weight) for name, core in cores.items()})
+    got = run((q, k, v), weight)
+    want, want_grads = got.pop("dense")
+    tol = {jnp.float32: 2e-5, jnp.bfloat16: 3e-2}[dtype]
+    for name, (out, grads) in got.items():
+        assert out.shape == (1, length, 2, 16) and out.dtype == dtype
+        assert _rel(out, want) <= tol, name
+        for grad, wanted, arg in zip(grads, want_grads, (q, k, v)):
+            assert grad.shape == arg.shape and grad.dtype == dtype
+            assert _rel(grad, wanted) <= 2 * tol, name
+
+
+@pytest.mark.parametrize(
+    "kind,l,heads,kv_heads,dk,dr,dv,window,fuses",
+    [
+        ("TPU v5 lite", 8192, 72, 8, 128, 0, 128, 512, True),    # laguna_s21.train's window layers
+        ("TPU v5 lite", 8192, 48, 8, 128, 0, 128, None, True),   # its full layers: the causal pair
+        ("cpu", 8192, 72, 8, 128, 0, 128, 512, False),           # no TPU: XLA's band
+        ("TPU v5 lite", 8192, 72, 8, 128, 64, 128, 512, False),  # a shared key part: not in the band
+        ("TPU v5 lite", 8192, 72, 8, 128, 0, 128, 96, False),    # a window narrower than every tile
+        ("TPU v5 lite", 8200, 72, 8, 128, 0, 128, 512, False),   # no tile divides the length
+    ],
+)
+def test_window_route_follows_device_shape_and_window(kind, l, heads, kv_heads, dk, dr, dv, window, fuses):
+    assert causal_attention.fits(kind, l, heads, kv_heads, dk, dr, dv, 2, window=window) is fuses
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["cpu_mesh", "kernels"])
+def test_a_windowed_call_counts_under_the_window_events_alone(fused, monkeypatch):
+    """Inside a mesh a windowed call counts its route under the window's events and none of the causal ones, and
+    its kernels carry the window in their names; outside any mesh it is XLA's band, uncounted."""
+    (q, k, v, _), _ = _inputs(4, 2, 16, 0, 16, jnp.float32, length=64)
+    monkeypatch.setattr(causal_attention, "fits", lambda *a: fused)
+    monkeypatch.setattr(causal_attention, "WINDOW_BLOCKS", (16,))
+    entry = lambda *a: attention.causal_attention(*a, window=32)
+    outside = _counted(lambda: jax.jit(entry).lower(q, k, v))
+    assert not {attention.WINDOW_FUSED_EVENT, attention.WINDOW_XLA_EVENT} & set(outside)
+    traced = []
+    with jax.set_mesh(data_mesh(1)):
+        counters = _counted(lambda: traced.append(str(jax.make_jaxpr(entry)(q, k, v))))
+    assert counters.get(attention.WINDOW_FUSED_EVENT if fused else attention.WINDOW_XLA_EVENT) == 1
+    assert not {attention.CAUSAL_FUSED_EVENT, attention.CAUSAL_XLA_EVENT,
+                attention.WINDOW_XLA_EVENT if fused else attention.WINDOW_FUSED_EVENT} & set(counters)
+    assert ("dtpu_window32_attn_fwd" in traced[0]) is fused and "dtpu_causal_attn_fwd" not in traced[0]
